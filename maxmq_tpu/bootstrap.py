@@ -13,6 +13,7 @@ import asyncio
 import os
 import signal
 
+from .accel import DEVICE_MATCHERS, place_compile_cache, require_accelerator
 from .broker import Broker, BrokerOptions, Capabilities, TCPListener
 from .broker.listeners import HTTPStatsListener, UnixListener, WSListener
 from .hooks import AllowHook
@@ -107,48 +108,53 @@ def install_event_loop(policy: str, logger: Logger | None = None) -> str:
     return "asyncio"
 
 
-def build_matcher(conf: Config, broker: Broker):
-    """Attach the configured matcher engine to the broker.
-
-    ``trie`` is the CPU reference path (broker default, no attach needed);
-    ``nfa``/``dense`` are the device paths; a ``matcher_mesh`` like "2x4"
-    shards the NFA over a device mesh (cluster mode); ``service``
-    connects to an external chip-owning matcher service at
-    ``matcher_socket`` (attached in run_server — it needs the loop)."""
-    if conf.matcher in ("", "trie", "service"):
-        return None
+def build_engine(conf: Config, index):
+    """The configured device engine over ``index``: ``nfa``/``dense``/
+    ``sig``, sharded over a device mesh when ``matcher_mesh`` names one
+    (e.g. "2x4", cluster mode). Shared by the in-process matcher build
+    and the worker pool's sidecar (broker/workers.py)."""
+    require_accelerator(f"matcher = {conf.matcher!r}")
     if conf.matcher_mesh:
         from .parallel.sharded import (ShardedNFAEngine, ShardedSigEngine,
                                        make_mesh)
         rows, _, cols = conf.matcher_mesh.partition("x")
         mesh = make_mesh(shape=(int(rows), int(cols or 1)))
         if conf.matcher == "nfa":
-            engine = ShardedNFAEngine(broker.topics, mesh=mesh,
-                                      max_levels=conf.matcher_max_levels)
-        else:
-            # the sharded sig engine derives its depth window from the
-            # corpus (DEPTH_CAP-bounded); matcher_max_levels is a
-            # word-path/nfa/dense knob
-            engine = ShardedSigEngine(broker.topics, mesh=mesh)
-            engine.emit_intents = conf.matcher_intents   # ADR 007
-    elif conf.matcher == "nfa":
+            return ShardedNFAEngine(index, mesh=mesh,
+                                    max_levels=conf.matcher_max_levels)
+        # the sharded sig engine derives its depth window from the
+        # corpus (DEPTH_CAP-bounded); matcher_max_levels is a
+        # word-path/nfa/dense knob
+        engine = ShardedSigEngine(index, mesh=mesh)
+        engine.emit_intents = conf.matcher_intents   # ADR 007
+        return engine
+    if conf.matcher == "nfa":
         from .matching.engine import NFAEngine
-        engine = NFAEngine(broker.topics,
-                           max_levels=conf.matcher_max_levels)
-    elif conf.matcher == "dense":
+        return NFAEngine(index, max_levels=conf.matcher_max_levels)
+    if conf.matcher == "dense":
         from .matching.dense import DenseEngine
-        engine = DenseEngine(broker.topics,
-                             max_levels=conf.matcher_max_levels)
-    elif conf.matcher == "sig":
+        return DenseEngine(index, max_levels=conf.matcher_max_levels)
+    if conf.matcher == "sig":
         from .matching.sig import SigEngine
-        engine = SigEngine(broker.topics,
-                           max_levels=conf.matcher_max_levels)
+        engine = SigEngine(index, max_levels=conf.matcher_max_levels)
         # fan-out-ready DeliveryIntents from the native decode (ADR 007)
         # — the broker handles both result shapes, so this is safe to
         # default on; matcher_intents = false restores merged sets
         engine.emit_intents = conf.matcher_intents
-    else:
-        raise ValueError(f"unknown matcher {conf.matcher!r}")
+        return engine
+    raise ValueError(f"unknown matcher {conf.matcher!r}")
+
+
+def build_matcher(conf: Config, broker: Broker):
+    """Attach the configured matcher engine to the broker.
+
+    ``trie`` is the CPU reference path (broker default, no attach needed);
+    the device engines come from ``build_engine``; ``service`` connects
+    to an external chip-owning matcher service at ``matcher_socket``
+    (attached in run_server — it needs the loop)."""
+    if conf.matcher in ("", "trie", "service"):
+        return None
+    engine = build_engine(conf, broker.topics)
     from .matching.batcher import MicroBatcher
     batcher = MicroBatcher(engine,
                            window_us=conf.matcher_batch_window_us,
@@ -169,7 +175,10 @@ def build_matcher(conf: Config, broker: Broker):
     broker.attach_matcher(attach)
     warm = getattr(engine, "warm_buckets", None)
     if warm is not None:
-        warm(conf.matcher_max_batch)    # background bucket precompile
+        # the bucket ladder this broker serves with: compiled now when
+        # there is a table, and after every table compile from here on
+        # (the boot compile in Broker.serve, each background rotation)
+        warm(conf.matcher_max_batch)
     prewarm = getattr(engine, "prewarm_decode_bases", None)
     if prewarm is not None:
         prewarm()    # chained-decode anchors at the boot quiescent point
@@ -353,6 +362,12 @@ async def run_server(conf: Config, logger: Logger,
     boot = logger.with_prefix("bootstrap")
     boot.debug("effective configuration", **config_as_dict(conf))
 
+    # a pool worker never builds a device engine (its matcher is
+    # rewritten to the parent's sidecar) and stays off JAX altogether
+    if (conf.matcher in DEVICE_MATCHERS
+            and os.environ.get("MAXMQ_WORKER_ID") is None):
+        boot.info("compile cache", path=place_compile_cache())
+
     if await _maybe_run_pool(conf, logger, ready, stop):
         return
 
@@ -371,7 +386,15 @@ async def run_server(conf: Config, logger: Logger,
 
     if metrics is not None:
         metrics.start()
-    await broker.serve()
+    try:
+        await broker.serve()
+    except BaseException:
+        # e.g. a boot-time table compile the device refused: leave no
+        # metrics thread, journal writer or open store behind
+        await broker.close()
+        if metrics is not None:
+            metrics.stop()
+        raise
     boot.info("server started", tcp=conf.mqtt_tcp_address,
               matcher=conf.matcher or "trie")
     if ready is not None:

@@ -204,6 +204,7 @@ class Broker:
         self._storage_hook = None
         self._journal = None
         self.boot_epoch = 0             # persisted monotonic boot counter
+        self.boot_seconds: dict[str, float] = {}   # serve(): set-up phases
         self.storage_barrier_waits = 0  # acks that waited on a barrier
         # publish-path tracer (ADR 015): always constructed — the
         # stage-error counters are fed even with sampling off; span
@@ -272,8 +273,12 @@ class Broker:
             # ADR 015: the writer thread feeds the journal_commit stage
             # histogram + commit-failure stage errors through the tracer
             self._journal.tracer = self.tracer
+        t0 = time.perf_counter()
         await self._restore_from_storage()
+        t1 = time.perf_counter()
         await self._compile_matcher_tables()
+        self.boot_seconds = {"restore": t1 - t0,
+                             "matcher_compile": time.perf_counter() - t1}
         if self.capabilities.connect_rate > 0:
             # per-listener CONNECT token bucket (ADR 012): armed before
             # accepting so the very first storm is already gated
@@ -300,11 +305,19 @@ class Broker:
         the tables. Off the event loop: the compile can take seconds at
         1M subscriptions, and nothing is being served yet.
 
-        Prewarm rides the same executor call: a synchronous refresh()
-        alone never populates the chained-decode anchors (only
-        _bg_refresh does), so a broker restored with a large
-        subscription set would pay the anchor-population ramp across
-        its first few hundred thousand publishes (ADVICE r5 #1)."""
+        The bucket warm and the decode prewarm ride the same executor
+        call: a synchronous refresh() alone swaps in a program no batch
+        shape of which is compiled (the first batch of every bucket
+        would compile on the publish path, against the ADR-011
+        deadline) and never populates the chained-decode anchors (a
+        ramp across the first few hundred thousand publishes, ADVICE
+        r5 #1).
+
+        A compile that fails here fails serve(): there is no last-good
+        table to degrade to at boot, and the ADR-011 ladder is for a
+        device that was healthy once. (``matcher = "service"`` has no
+        engine in this process, hence no ``refresh``, and boots on its
+        trie whatever the sidecar does.)"""
         if self.matcher is None or self.topics.subscription_count == 0:
             return
         engine = getattr(self.matcher, "engine", self.matcher)
@@ -314,6 +327,9 @@ class Broker:
 
         def compile_and_prewarm():
             refresh()
+            rewarm = getattr(engine, "rewarm", None)
+            if rewarm is not None:
+                rewarm()
             prewarm = getattr(engine, "prewarm_decode_bases", None)
             if prewarm is None:
                 return
@@ -326,13 +342,7 @@ class Broker:
                 if self.log is not None:
                     self.log.warn("boot-time decode prewarm failed",
                                   error=repr(exc)[:200])
-        try:
-            await self.loop.run_in_executor(None, compile_and_prewarm)
-        except Exception as exc:
-            # lazy refresh on first batch remains the fallback
-            if self.log is not None:
-                self.log.warn("boot-time matcher compile failed",
-                              error=repr(exc)[:200])
+        await self.loop.run_in_executor(None, compile_and_prewarm)
 
     async def close(self) -> None:
         if not self._running:

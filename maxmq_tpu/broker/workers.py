@@ -61,13 +61,14 @@ import sys
 import time
 
 from .. import faults
+from ..accel import DEVICE_MATCHERS
 
 POOL_DIR_ENV = "MAXMQ_POOL_DIR"
 
 # matcher engines the pool parent hoists into the shared sidecar; a
 # box already on ``service`` points at an external sidecar, and the
 # CPU trie stays per-worker (no chip to share)
-_SIDECAR_MATCHERS = ("sig", "nfa", "dense")
+_SIDECAR_MATCHERS = DEVICE_MATCHERS
 
 
 def worker_sock(pool_dir: str, worker_id: int) -> str:
@@ -317,33 +318,13 @@ async def inprocess_pool(n: int = 2, link_dir: str | None = None,
 
 
 def _engine_factory(conf):
-    """The sidecar's engine build, mirroring bootstrap.build_matcher's
-    device branches (sig/nfa/dense, mesh-sharded when configured) —
-    the ONE table compile per box the workers share."""
+    """The sidecar's engine build: bootstrap.build_engine (sig/nfa/
+    dense, mesh-sharded when configured) behind a MicroBatcher — the
+    ONE table compile per box the workers share."""
     def factory(index):
+        from ..bootstrap import build_engine
         from ..matching.batcher import MicroBatcher
-        if conf.matcher_mesh:
-            from ..parallel.sharded import (ShardedNFAEngine,
-                                            ShardedSigEngine, make_mesh)
-            rows, _, cols = conf.matcher_mesh.partition("x")
-            mesh = make_mesh(shape=(int(rows), int(cols or 1)))
-            if conf.matcher == "nfa":
-                engine = ShardedNFAEngine(index, mesh=mesh,
-                                          max_levels=conf.matcher_max_levels)
-            else:
-                engine = ShardedSigEngine(index, mesh=mesh)
-                engine.emit_intents = conf.matcher_intents
-        elif conf.matcher == "nfa":
-            from ..matching.engine import NFAEngine
-            engine = NFAEngine(index, max_levels=conf.matcher_max_levels)
-        elif conf.matcher == "dense":
-            from ..matching.dense import DenseEngine
-            engine = DenseEngine(index, max_levels=conf.matcher_max_levels)
-        else:
-            from ..matching.sig import SigEngine
-            engine = SigEngine(index, max_levels=conf.matcher_max_levels)
-            engine.emit_intents = conf.matcher_intents
-        return MicroBatcher(engine,
+        return MicroBatcher(build_engine(conf, index),
                             window_us=conf.matcher_batch_window_us,
                             max_batch=conf.matcher_max_batch)
     return factory

@@ -82,8 +82,10 @@ def cmd_start(args: argparse.Namespace) -> int:
 
 def cmd_matcher_service(args: argparse.Namespace) -> int:
     async def run() -> None:
+        from .accel import place_compile_cache
         from .matching.service import MatcherService
 
+        place_compile_cache()
         svc = MatcherService(args.socket)
         await svc.start()
         print(f"matcher service on {args.socket}", file=sys.stderr,

@@ -326,6 +326,14 @@ class MicroBatcher:
         walk the CPU trie."""
         n = len(topics)
         host = self._pick_bypass_host(n)
+        kick = getattr(self.engine, "refresh_soon", None)
+        if (host is None and kick is not None
+                and getattr(self.engine, "auto_refresh", True)):
+            # the trie answers from the live index without touching the
+            # engine, which is where staleness is otherwise noticed: a
+            # broker whose every batch is this cheap would never
+            # recompile its tables after a subscription change
+            kick()
         t0 = time.perf_counter()
         try:
             results = (host(topics) if host is not None else
@@ -398,7 +406,13 @@ class MicroBatcher:
 
     def _note_rtt(self, sample: float) -> None:
         """Record one device round-trip sample (dispatch->collect).
-        The first sample carries the XLA compile and is discarded."""
+        The first sample carries the XLA compile and is discarded; so
+        is one taken while the engine compiles in the background (a
+        table rotation holds the interpreter for seconds at a time: on
+        a v5e at 1M filters one such sample read 3.1 s, and the bypass
+        it talked into winning kept the chip idle long after)."""
+        if getattr(self.engine, "compiling", False):
+            return
         self._rtt_samples += 1
         self._since_probe = 0
         if self._rtt_samples <= 1:

@@ -58,11 +58,10 @@ def match_batch_body(hash_node, hash_tok, hash_val, plus_child, node_mask,
 
     active0 = jnp.full((batch, width), -1, dtype=jnp.int32).at[:, 0].set(0)
     overflow0 = lengths < 0
-    if mesh_axes and hasattr(jax, "typeof"):
+    if mesh_axes:
         # Under shard_map the scan carry must be typed as device-varying
         # over the mesh axes from step 0 (the step fn mixes in sharded
-        # inputs), or the vma checker rejects the scan. (jax 0.4.x has
-        # neither jax.typeof nor the vma checker — skip both there.)
+        # inputs), or the vma checker rejects the scan.
         def vary(x):
             need = tuple(a for a in mesh_axes if a not in jax.typeof(x).vma)
             return jax.lax.pcast(x, need, to="varying") if need else x

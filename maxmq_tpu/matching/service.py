@@ -102,8 +102,10 @@ class MatcherService:
         self._gen = 0
         if engine_factory is None:
             def engine_factory(index):
+                from ..accel import require_accelerator
                 from .batcher import MicroBatcher
                 from .sig import SigEngine
+                require_accelerator("matcher service")
                 return MicroBatcher(SigEngine(index))
         self._factory = engine_factory
         self.matcher = None               # built lazily on first serve

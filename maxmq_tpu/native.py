@@ -9,14 +9,17 @@ use if a compiler is available), and exposes:
   buffer of concatenated control packets into frames without per-byte
   Python work (same framing rules as protocol/codec.py).
 
-Everything degrades gracefully: ``available()`` is False when the library
-can't be built/loaded (or MAXMQ_NO_NATIVE is set) and callers fall back to
-the pure-Python paths.
+Everything degrades to the pure-Python paths: ``available()`` is False when
+the library can't be built/loaded (logged once, with the error) or
+MAXMQ_NO_NATIVE is set. The build runs only when the ``.so`` is absent, so
+concurrent processes never race on one file; ``make -C native`` rebuilds a
+stale one (chip_smoke.py runs it first).
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -27,9 +30,25 @@ _NATIVE_DIR = os.environ.get("MAXMQ_NATIVE_DIR") or os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native")
 _SO_PATH = os.path.join(_NATIVE_DIR, "libmaxmq_native.so")
 
+_log = logging.getLogger("maxmq.native")
 _lib = None
 _load_lock = threading.Lock()
 _load_attempted = False
+
+
+def _build(target: str | None = None) -> bool:
+    """``make -C native [target]``; a failure is logged with the
+    compiler's own words (each library is attempted once per process,
+    so once) and the caller serves from the Python path."""
+    cmd = ["make", "-C", _NATIVE_DIR, "-s"] + ([target] if target else [])
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+    except (OSError, subprocess.SubprocessError) as exc:
+        detail = getattr(exc, "stderr", b"") or b""
+        _log.error("native build failed (%s): %r %s", " ".join(cmd), exc,
+                   detail.decode("utf-8", "replace")[-2000:])
+        return False
+    return True
 
 
 def _try_load():
@@ -43,15 +62,13 @@ def _try_load():
         # on-demand build only where a Makefile exists — an override dir
         # (MAXMQ_NATIVE_DIR, e.g. native/asan) holds prebuilt .so only
         if (not os.path.exists(_SO_PATH)
-                and os.path.exists(os.path.join(_NATIVE_DIR, "Makefile"))):
-            try:
-                subprocess.run(["make", "-C", _NATIVE_DIR, "-s"],
-                               check=True, capture_output=True, timeout=120)
-            except Exception:
-                return None
+                and os.path.exists(os.path.join(_NATIVE_DIR, "Makefile"))
+                and not _build()):
+            return None
         try:
             lib = ctypes.CDLL(_SO_PATH)
-        except OSError:
+        except OSError as exc:
+            _log.error("native library %s did not load: %r", _SO_PATH, exc)
             return None
         lib.mq_vocab_new.restype = ctypes.c_void_p
         lib.mq_vocab_free.argtypes = [ctypes.c_void_p]
@@ -156,11 +173,7 @@ def decode_module(build: bool = True):
             if (not build or not os.path.exists(
                     os.path.join(_NATIVE_DIR, "Makefile"))):
                 return None            # stay retriable for build=True
-            try:
-                subprocess.run(["make", "-C", _NATIVE_DIR, "-s",
-                                "maxmq_decode.so"],
-                               check=True, capture_output=True, timeout=120)
-            except Exception:
+            if not _build("maxmq_decode.so"):
                 _decode_attempted = True
                 return None
         _decode_attempted = True
@@ -171,7 +184,8 @@ def decode_module(build: bool = True):
             mod = importlib.util.module_from_spec(spec)
             spec.loader.exec_module(mod)
             _decode_mod = mod
-        except Exception:
+        except Exception as exc:
+            _log.error("native decode %s did not load: %r", path, exc)
             _decode_mod = None
         return _decode_mod
 

@@ -35,13 +35,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                   # newer jax spells it jax.shard_map
-    _shard_map = jax.shard_map
-except AttributeError:                 # 0.4.x: the experimental module
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from ..matching.engine import NFAEngine, match_batch_body
 from ..matching.nfa import NFATables, TableFull, compile_subscriptions
+from ..matching.topics import batch_bucket
 from ..matching.trie import SubscriberSet, TopicIndex, subs_version
 
 
@@ -244,6 +240,21 @@ def _sharded_sig_match(tables_dev, toks, lens_enc, *, sel_blocks, max_rows):
 from ..matching.sig import OverlayedEngine
 
 
+def sharded_sig_program(mesh: Mesh, subs_axes: tuple, sel_blocks: int,
+                        max_rows: int):
+    """The jitted cluster-mode step: ``fn(tables, toks, lens_enc)`` with
+    the seven stacked shard tables partitioned over ``subs_axes`` and
+    the batch over 'data'; out [sp, B, 1 + max_rows] stays sharded."""
+    return jax.jit(jax.shard_map(
+        partial(_sharded_sig_match, sel_blocks=sel_blocks,
+                max_rows=max_rows),
+        mesh=mesh,
+        in_specs=(tuple(P(subs_axes) for _ in range(7)),
+                  P("data"), P("data")),
+        out_specs=P(subs_axes, "data", None),
+    ))
+
+
 def _shard_pairs(out_s, hr, batch, col, fall):
     """One shard's UNVERIFIED candidate (topic, row) pairs: device slots
     + host-probe rows, with overflowed (trie-served) topics' pairs
@@ -405,14 +416,8 @@ class ShardedSigEngine(OverlayedEngine):
             by_shard = NamedSharding(mesh, P(subs_axes))
             dev = tuple(jax.device_put(a, by_shard) for a in stacked)
 
-            fn = jax.jit(_shard_map(
-                partial(_sharded_sig_match, sel_blocks=self.sel_blocks,
-                        max_rows=self.max_rows),
-                mesh=mesh,
-                in_specs=(tuple(P(subs_axes) for _ in range(7)),
-                          P("data"), P("data")),
-                out_specs=P(subs_axes, "data", None),
-            ))
+            fn = sharded_sig_program(mesh, subs_axes, self.sel_blocks,
+                                     self.max_rows)
             # exact-group coefficients are deterministic by shape, so the
             # union over shards gives ONE esig per topic valid everywhere
             union_exact = {}
@@ -447,6 +452,13 @@ class ShardedSigEngine(OverlayedEngine):
 
     # ------------------------------------------------------------------
 
+    def _has_program(self) -> bool:
+        return (self._state[3] is not None
+                and self.index.subscription_count > 0)
+
+    def _warm_one(self, size: int) -> None:
+        self.match_raw(["$maxmq/warm"] * size)      # fetches: blocks
+
     def prewarm_decode_bases(self, chunk: int = 2048) -> int:
         """Cluster form of SigEngine.prewarm_decode_bases: populate the
         chained-decode anchors for every SHARD's table at a quiescent
@@ -479,7 +491,10 @@ class ShardedSigEngine(OverlayedEngine):
                 "wildcard shapes in a shard); use subscribers_*, which "
                 "fall back to the CPU trie")
         batch = len(topics)
-        padded = -(-batch // dp) * dp
+        # the shared bucket ladder (ADR 006), as SigEngine.dispatch_fixed:
+        # the program is jitted, so every DISTINCT batch shape is a full
+        # XLA compile, and micro-batch sizes vary per window
+        padded = -(-batch_bucket(batch) // dp) * dp
         padded_topics = topics + ["\x01pad"] * (padded - batch)
         # shared intern pool => identical tokens for every shard; one host
         # tokenize pass serves every shard's exact + '+'-shape probes
@@ -730,7 +745,7 @@ class ShardedNFAEngine:
         """jit(shard_map) of the match step over the mesh."""
         mesh = self.mesh
         table_specs = tuple(P("subs") for _ in range(6))
-        fn = _shard_map(
+        fn = jax.shard_map(
             partial(_sharded_match, width=self.width, table_mask=table_mask,
                     max_rows=self.max_rows),
             mesh=mesh,

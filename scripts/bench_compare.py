@@ -2,15 +2,14 @@
 """Bench-trajectory comparison / regression gate (ADR 017).
 
 The repo accumulates one ``BENCH_r<NN>.json`` per round (the driver's
-capture: ``{n, cmd, rc, tail, parsed}``) plus ``BENCH_TPU_LAST_GOOD``
-— and until this script, nothing read them, which is why the perf
-trajectory handed to each round was empty. This tool:
+capture: ``{n, cmd, rc, tail, parsed}``) — and until this script,
+nothing read them, which is why the perf trajectory handed to each
+round was empty. This tool:
 
-1. loads the newest two rounds (and the last-good reference when
-   present), tolerating every historical shape: a structured
-   ``parsed`` object, a raw bench row list, or a truncated ``tail``
-   from which the largest complete JSON object is recovered via
-   ``raw_decode`` brace-scanning;
+1. loads the newest two rounds, tolerating every historical shape: a
+   structured ``parsed`` object, a raw bench row list, or a truncated
+   ``tail`` from which the largest complete JSON object is recovered
+   via ``raw_decode`` brace-scanning;
 2. flattens every ``{"config": ...}`` row into ``config/metric``
    numeric leaves (nested dicts dot-joined, so the ADR-015 ``trace``
    stanza's ``p99_ms`` tails participate);
@@ -83,8 +82,6 @@ def load_round(path: str):
     if isinstance(doc, dict):
         if doc.get("parsed") is not None:
             return doc["parsed"]
-        if isinstance(doc.get("result"), (dict, list)):
-            return doc["result"]           # BENCH_TPU_LAST_GOOD shape
         if isinstance(doc.get("tail"), str):
             return _recover_from_tail(doc["tail"])
     return doc
@@ -281,17 +278,6 @@ def main(argv=None) -> int:
                                  args.abs_floor_ms)
     print(render(table, os.path.basename(old_path),
                  os.path.basename(new_path)))
-
-    good_path = os.path.join(args.root, "BENCH_TPU_LAST_GOOD.json")
-    if os.path.isfile(good_path):
-        good_doc = load_round(good_path)
-        good_rows = extract_rows(good_doc) if good_doc else {}
-        if good_rows:
-            ref_table, _ = compare(good_rows, new_rows, args.threshold,
-                                   args.abs_floor_ms)
-            print()
-            print(render(ref_table, "BENCH_TPU_LAST_GOOD.json",
-                         os.path.basename(new_path)))
 
     if regressions:
         print(f"\n{len(regressions)} regression(s) past "

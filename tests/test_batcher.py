@@ -250,6 +250,48 @@ async def test_enqueue_cache_hits_and_version_invalidation():
         await batcher.close()
 
 
+async def test_trie_bypass_still_notices_stale_tables():
+    """A batch the bypass serves from the trie never reaches the engine,
+    where staleness is otherwise noticed: the batcher must kick the
+    background recompile itself, or a lightly loaded broker serves from
+    stale tables (overlay and all) until heavy traffic arrives."""
+    from maxmq_tpu.matching.sig import SigEngine
+
+    index = TopicIndex()
+    for i in range(300):
+        index.subscribe(f"cl-{i}", Subscription(filter=f"by/{i}/+", qos=1))
+    eng = SigEngine(index)
+    batcher = MicroBatcher(eng, window_us=0, max_batch=64)
+    try:
+        batcher._device_rtt, batcher._rtt_samples = 0.05, 2
+        batcher._trie_cost = 1e-9           # the trie wins every batch
+        index.subscribe("late", Subscription(filter="by/9/+", qos=0))
+        assert eng._stale()
+        r = await batcher.subscribers_async("by/9/x")
+        assert batcher.bypasses == 1 and eng.host_matches == 0
+        assert "late" in r.subscriptions
+        eng.close()
+        assert not eng._stale() and eng.bg_refresh_errors == 0
+    finally:
+        await batcher.close()
+
+
+async def test_rtt_sample_during_background_compile_is_discarded():
+    """A round trip timed while a table rotation shares the interpreter
+    measures the rotation; folded into the estimate it talks the bypass
+    into winning every batch long after the rotation has ended."""
+    batcher = MicroBatcher(FakeEngine(), window_us=0)
+    batcher._note_rtt(9.9)                  # first: carries the compile
+    batcher._note_rtt(0.002)
+    assert batcher.device_rtt == 0.002
+    batcher.engine.compiling = True
+    batcher._note_rtt(3.1)
+    assert batcher.device_rtt == 0.002
+    batcher.engine.compiling = False
+    batcher._note_rtt(0.004)
+    assert 0.002 < batcher.device_rtt < 0.004
+
+
 async def test_adaptive_cpu_bypass_serves_small_batches():
     """VERDICT r04 #2: with a measured device RTT on record, a small
     batch is served inline from the CPU trie (trie-class latency) with
@@ -268,7 +310,7 @@ async def test_adaptive_cpu_bypass_serves_small_batches():
         r = await batcher.subscribers_async("by/7/x")
         assert "cl-7" in (r.to_set() if hasattr(r, "to_set") else r).subscriptions
         assert batcher.bypasses == 0
-        # seed a slow measured round trip (the tunnel regime)
+        # seed a slow measured round trip (a remote-link regime)
         batcher._device_rtt = 0.05
         batcher._rtt_samples = 2
         r = await batcher.subscribers_async("by/9/x")

@@ -80,6 +80,65 @@ class TestConfigMapping:
         assert isinstance(broker.matcher, MicroBatcher)
 
 
+class TestAccelPolicy:
+    def test_cpu_fallback_refused_unless_asked_for(self):
+        """JAX on a CPU nobody named is a chip that failed to come up:
+        the device engines refuse it. Naming the CPU (JAX_PLATFORMS, or
+        the pin in conftest.py) is how tests and harnesses run."""
+        import jax
+        import pytest
+
+        from maxmq_tpu.accel import require_accelerator
+        from maxmq_tpu.matching.service import MatcherService
+
+        require_accelerator("test")             # conftest pinned the CPU
+        conf = Config(mqtt_tcp_address="", metrics_enabled=False,
+                      matcher="sig")
+        pinned = jax.config.jax_platforms
+        jax.config.update("jax_platforms", "")  # as if nobody had asked
+        try:
+            with pytest.raises(RuntimeError, match="no accelerator"):
+                build_broker(conf, quiet_logger())
+            with pytest.raises(RuntimeError, match="no accelerator"):
+                MatcherService("/unused")._factory(None)
+            conf.matcher = "trie"               # needs no device at all
+            assert build_broker(conf, quiet_logger()).matcher is None
+        finally:
+            jax.config.update("jax_platforms", pinned)
+
+    def test_compile_cache_placed_from_outside_or_in_checkout(
+            self, monkeypatch):
+        import os
+
+        import jax
+
+        from maxmq_tpu import accel
+
+        before = (jax.config.jax_compilation_cache_dir,
+                  jax.config.jax_persistent_cache_min_compile_time_secs,
+                  jax.config.jax_include_full_tracebacks_in_locations)
+        try:
+            monkeypatch.setenv(accel.CACHE_ENV, "/some/dir")
+            assert accel.place_compile_cache() == "/some/dir"
+            # JAX reads the variable itself: no directory is set in code
+            assert jax.config.jax_compilation_cache_dir == before[0]
+            # a kernel program's key must not move with callers' lines
+            assert not jax.config.jax_include_full_tracebacks_in_locations
+            monkeypatch.delenv(accel.CACHE_ENV)
+            repo = os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)))
+            assert accel.place_compile_cache() == os.path.join(
+                repo, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == os.path.join(
+                repo, ".jax_cache")
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before[0])
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs", before[1])
+            jax.config.update(
+                "jax_include_full_tracebacks_in_locations", before[2])
+
+
 async def test_run_server_end_to_end(tmp_path, monkeypatch):
     """Full boot: config → broker + metrics; a real client connects and does
     a QoS0 roundtrip; metrics scrape sees it; clean shutdown; profiles

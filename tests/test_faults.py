@@ -207,6 +207,56 @@ def test_recompile_failure_keeps_last_good_tables():
     assert eng.tables.version > v0
 
 
+async def test_boot_compile_failure_fails_serve_with_device_matcher():
+    """No last-good table exists at boot: a compile that fails there
+    must fail serve(), not leave a broker serving from its trie behind
+    a device matcher that never came up. The bucket warm rides the
+    boot compile, so the first publish meets a compiled program."""
+    from maxmq_tpu.bootstrap import build_broker
+    from maxmq_tpu.utils.config import Config
+
+    def restored_broker():
+        conf = Config(mqtt_tcp_address="127.0.0.1:0", metrics_enabled=False,
+                      mqtt_sys_topic_interval=0, matcher="sig")
+        broker = build_broker(conf, Logger(out=io.StringIO(), fmt="json"))
+        for i in range(400):        # past the ADR-008 trie-routed size
+            broker.topics.subscribe(
+                f"c{i}", Subscription(filter=f"f/{i}/#", qos=1))
+        return broker
+
+    faults.arm(faults.DEVICE_RECOMPILE, "raise", count=1)
+    with pytest.raises(faults.InjectedFault):
+        await restored_broker().serve()
+
+    broker = restored_broker()      # the fault is spent: a healthy boot
+    await broker.serve()
+    try:
+        engine = broker.matcher.engine
+        assert engine.tables.version == broker.topics.sub_version
+        assert engine.warm_seconds > 0          # buckets compiled at boot
+        assert engine.bg_refresh_errors == 0
+    finally:
+        await broker.close()
+        await broker.matcher.close()
+
+
+def test_bucket_warm_surfaces_a_compile_error(caplog):
+    """A warm that cannot compile raises on the caller's thread and is
+    counted and logged from a background one; a trie-routed corpus has
+    no program to warm and is not an error."""
+    eng = make_engine(small_corpus())
+    faults.arm(faults.DEVICE_MATCH, "raise", count=-1)
+    with pytest.raises(faults.InjectedFault):
+        eng.warm_buckets(64, background=False)
+    eng.warm_buckets(64)
+    eng.close()
+    assert eng.bg_refresh_errors == 1
+    assert "bucket warm failed" in caplog.text
+    routed = SigEngine(TopicIndex())        # empty: ADR-008 routes it
+    routed.warm_buckets(64, background=False)
+    assert routed.bg_refresh_errors == 0 and routed.warm_seconds == 0
+
+
 # -- matcher-service socket drop --------------------------------------
 
 
