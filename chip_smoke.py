@@ -638,8 +638,13 @@ async def serve_and_check(args, report: Report, watch: CompileWatch,
         batcher.largest_batch = 0
         live = await driver.ask("subscribe")
         hits = live.pop("hits")
-        say(f"over TCP from pid {driver.proc.pid}: {live}; measured "
-            f"device round trip {batcher.device_rtt * 1e3:.2f} ms")
+        on_thread = (f"{batcher.device_round_trip * 1e3:.3f} ms"
+                     if batcher.device_round_trip
+                     else "not measured (tracing is off)")
+        say(f"over TCP from pid {driver.proc.pid}: {live}; device round "
+            f"trip as the loop sees it, executor hops included (drives "
+            f"the bypass) {batcher.device_rtt * 1e3:.2f} ms; on the "
+            f"executor thread {on_thread}")
 
         waves = report.proof.setdefault("waves", {})
         for name in "AB":

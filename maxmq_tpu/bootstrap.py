@@ -161,8 +161,9 @@ def build_matcher(conf: Config, broker: Broker):
                            max_batch=conf.matcher_max_batch)
     # ADR 015: the batcher stamps dispatch/result marks on match
     # futures when the broker's tracer is sampling, so per-publish
-    # traces split coalescing wait from device time
-    batcher.tracer = broker.tracer
+    # traces split coalescing wait from device time; the engine
+    # annotates a rotation's host work for a profiler capture
+    batcher.tracer = engine.tracer = broker.tracer
     attach = batcher
     if conf.matcher_supervised:
         # ADR 011: per-batch deadline + trie hedge + circuit breaker

@@ -17,6 +17,7 @@ from .. import faults
 from ..matching.trie import TopicAliases
 from ..protocol.codec import PacketType as PT
 from ..protocol.packets import Packet, ProtocolError, Subscription, Will, parse_stream
+from ..trace import annotated, host_span
 from .inflight import Inflight
 
 
@@ -382,21 +383,17 @@ class Client:
         maxsize = self.server.capabilities.maximum_packet_size
         tracer = self.server.tracer
         while not self.closed:
-            for fh, body in parse_stream(buf, maxsize):
-                self.server.info.packets_received += 1
-                if tracer.sample_n and fh.type == PT.PUBLISH:
-                    # ADR 015: time the decode; process_publish folds
-                    # it into the trace when this publish is sampled
-                    t0 = tracer.clock()
-                    packet = Packet.decode(
-                        fh, body, self.properties.protocol_version)
-                    packet._decode_ns = tracer.clock() - t0
-                else:
-                    packet = Packet.decode(
-                        fh, body, self.properties.protocol_version)
-                await on_packet(self, packet)
-                if self.closed:
-                    return
+            if tracer.sample_n:
+                # ADR 015: the chunk's synchronous work (decode,
+                # admission, enqueue of every packet in it) as one host
+                # span of a profiler capture, closed wherever a handler
+                # really waits
+                await annotated("maxmq.read", self._dispatch_buffered(
+                    buf, maxsize, on_packet))
+            else:
+                await self._dispatch_buffered(buf, maxsize, on_packet)
+            if self.closed:
+                return
             try:
                 chunk = await self.reader.read(
                     self.server.capabilities.buffer_size)
@@ -407,6 +404,26 @@ class Client:
             self.server.info.bytes_received += len(chunk)
             self.last_received = time.monotonic()
             buf.extend(chunk)
+
+    async def _dispatch_buffered(self, buf: bytearray, maxsize: int,
+                                 on_packet) -> None:
+        """Decode and dispatch every whole packet in ``buf``."""
+        tracer = self.server.tracer
+        for fh, body in parse_stream(buf, maxsize):
+            self.server.info.packets_received += 1
+            if tracer.sample_n and fh.type == PT.PUBLISH:
+                # ADR 015: time the decode; process_publish folds
+                # it into the trace when this publish is sampled
+                t0 = tracer.clock()
+                packet = Packet.decode(
+                    fh, body, self.properties.protocol_version)
+                packet._decode_ns = tracer.clock() - t0
+            else:
+                packet = Packet.decode(
+                    fh, body, self.properties.protocol_version)
+            await on_packet(self, packet)
+            if self.closed:
+                return
 
     def _write_fault_delay(self) -> float:
         """0.0 unless a client.write fault applies to this client —
@@ -433,10 +450,15 @@ class Client:
         (WS / embedder stream shims expose only write) get the burst
         as one joined write — same bytes, one frame."""
         writelines = getattr(self.writer, "writelines", None)
-        if writelines is not None:
-            writelines(bufs)
-        else:
+        tracer = getattr(self.server, "tracer", None)
+        if writelines is None:
             self.writer.write(b"".join(bufs))
+        elif tracer is not None and tracer.sample_n:
+            # ADR 015: the burst's one writev, for a profiler capture
+            with host_span("maxmq.flush", bufs=len(bufs)):
+                writelines(bufs)
+        else:
+            writelines(bufs)
         overload = getattr(self.server, "overload", None)
         if overload is not None:
             overload.writev_batches += 1

@@ -22,7 +22,7 @@ from ..filtering.expr import ExprError, decode_payload
 from ..filtering.plane import (ContentPlane, ContentQuota,
                                USER_PROP_KEY as FILTER_PROP_KEY)
 from ..hooks.base import Hook, Hooks, RejectPacket
-from ..trace import MAX_DRAIN_SPANS, PipelineTracer
+from ..trace import MAX_DRAIN_SPANS, PipelineTracer, host_span
 from ..matching.topics import valid_filter, valid_topic_name
 from ..matching.trie import (SubscriberSet, TopicIndex,
                              VersionedTopicCache)
@@ -796,6 +796,13 @@ class Broker:
             tr.span("decode", now - dec, now)
         tr.t_admit = now
         packet._trace = tr
+        # how long a ready callback waits for the loop right now: what
+        # every executor hop, drain and in-order wait is made of
+        asyncio.get_running_loop().call_soon(self._trace_loop_lag, tr, now)
+
+    def _trace_loop_lag(self, tr, scheduled_ns: int) -> None:
+        self.tracer.attach(tr, "loop_lag", scheduled_ns,
+                           self.tracer.clock())
 
     def _packet_trace(self, packet: Packet):
         # the gate opens for local sampling OR while an ADOPTED
@@ -1192,10 +1199,11 @@ class Broker:
             self._pub_queue = asyncio.Queue(maxsize=self.PUB_PIPELINE_BOUND)
             self._pub_consumer = self.loop.create_task(
                 self._pub_pipeline_loop(), name="publish-pipeline")
-        fut = self._dispatch_match(packet.topic)
         tr = self._packet_trace(packet)
         if tr is not None:
+            # before the dispatch: a cache hit is answered inside it
             tr.t_match = self.tracer.clock()
+        fut = self._dispatch_match(packet.topic)
         await self._pub_queue.put((fut, client, packet, durable_ack))
 
     def _dispatch_match(self, topic: str) -> asyncio.Future:
@@ -1231,8 +1239,11 @@ class Broker:
             try:
                 subscribers = await self._await_match(fut, packet)
                 if self.tracer.sample_n or self.tracer.adopted_open:
-                    self._trace_match_spans(fut, packet)
-                self._pub_deliver(subscribers, client, packet, durable_ack)
+                    self._pub_deliver_traced(fut, subscribers, client,
+                                             packet, durable_ack)
+                else:
+                    self._pub_deliver(subscribers, client, packet,
+                                      durable_ack)
             finally:
                 self._pub_queue.task_done()
 
@@ -1282,33 +1293,55 @@ class Broker:
                           for _f, subscribers, _c, packet, _d in resolved])
             for fut, subscribers, client, packet, durable_ack in resolved:
                 if self.tracer.sample_n or self.tracer.adopted_open:
-                    self._trace_match_spans(fut, packet)
-                self._pub_deliver(subscribers, client, packet, durable_ack)
+                    self._pub_deliver_traced(fut, subscribers, client,
+                                             packet, durable_ack)
+                else:
+                    self._pub_deliver(subscribers, client, packet,
+                                      durable_ack)
         finally:
             for _ in batch:
                 self._pub_queue.task_done()
 
+    def _pub_deliver_traced(self, fut, subscribers, client,
+                            packet: Packet, durable_ack: bool) -> None:
+        """``_pub_deliver`` while tracing is on: the matcher leg's spans
+        first, then the delivery under its profiler annotation."""
+        self._trace_match_spans(fut, packet)
+        if not self.tracer.sample_n:    # an adopted trace alone (ADR 017)
+            self._pub_deliver(subscribers, client, packet, durable_ack)
+            return
+        with host_span("maxmq.deliver"):
+            self._pub_deliver(subscribers, client, packet, durable_ack)
+
     def _trace_match_spans(self, fut, packet: Packet) -> None:
         """ADR 015: decompose the matcher leg of one sampled publish.
-        The batcher stamps ``_t_dispatch``/``_t_done`` on the match
-        future (the supervisor forwards them), splitting coalescing
-        wait from device/trie time; whatever the consumer waited past
-        the result — in-order fan-out behind earlier publishes — is
-        the pipeline_wait segment."""
+        Whoever answered stamped ``_t_done`` on the match future where
+        the answer was given: the batcher's settle (with
+        ``_t_dispatch`` and the batch's record, ``_t_batch``), its
+        topic cache, the supervisor's trie (both with ``_t_via``); the
+        supervisor forwards the batcher's marks. ``match_queue`` is the
+        coalescing wait, ``match_device`` ends at the answer and says
+        by whom, and whatever the consumer waited past it — in-order
+        fan-out behind earlier publishes — is ``pipeline_wait``. The
+        batch's phases become child spans of ``match_device``."""
         tr = packet.__dict__.get("_trace")
         if tr is None or not tr.t_match:
             return
         tracer = self.tracer
         now = tracer.clock()
         td = getattr(fut, "_t_dispatch", 0)
-        tdone = getattr(fut, "_t_done", 0)
+        tdone = getattr(fut, "_t_done", 0) or now
         if td:
             tr.span("match_queue", tr.t_match, td)
-            tr.span("match_device", td, tdone or now)
-        else:
-            tr.span("match_device", tr.t_match, tdone or now)
-        if tdone and now > tdone:
+        tr.span("match_device", td or tr.t_match, tdone)
+        if now > tdone:
             tr.span("pipeline_wait", tdone, now)
+        rec = getattr(fut, "_t_batch", None)
+        if rec is not None:
+            tr.via = "device" if rec.via == "whole" else rec.via
+            tracer.batch_spans(tr, rec)
+        else:
+            tr.via = getattr(fut, "_t_via", "")
         rung = getattr(self.matcher, "breaker_state_name", None)
         if rung and rung != "closed":
             tr.degraded = rung      # ADR-011 supervisor rung label

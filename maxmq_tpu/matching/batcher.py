@@ -27,6 +27,7 @@ import threading
 import time
 from typing import TYPE_CHECKING
 
+from ..trace import host_span
 from .trie import VersionedTopicCache, subs_version
 
 if TYPE_CHECKING:
@@ -108,15 +109,28 @@ class MicroBatcher:
         # ADR 015: when the broker's PipelineTracer is attached (see
         # bootstrap.build_matcher) and sampling is on, match futures
         # are stamped with dispatch/done clock marks so the tracer can
-        # split coalescing wait from device time; off = zero cost
+        # split coalescing wait from device time, and every micro-batch
+        # gets a record (tracer.open_batch) that the engine's host half
+        # writes its phases into; off = one attribute check a site
         self.tracer = None
+        # the newest dispatch -> fetched result taken on ONE executor
+        # thread (the record's device_rtt: whole-batch calls and shadow
+        # probes while sampling is on; 0 until then). It contains that
+        # thread's waits for the interpreter lock and no loop hop.
+        self.device_round_trip = 0.0
 
     @property
     def device_rtt(self) -> float:
-        """Measured device round-trip EWMA (seconds; 0 until the first
-        post-warm sample) — the public face of the bypass estimate,
-        scraped by the metrics bridge."""
+        """EWMA of dispatch -> result as the event loop sees it, executor
+        hops included (seconds; 0 until the first post-warm sample): the
+        estimate that drives the bypass, scraped by the metrics bridge.
+        ``device_round_trip`` is the time taken on the executor thread."""
         return self._device_rtt or 0.0
+
+    def _tracing(self):
+        """The tracer while sampling is on, else None."""
+        tracer = self.tracer
+        return tracer if tracer is not None and tracer.sample_n else None
 
     # Delegate the sync surface so the batcher is a drop-in matcher.
     def subscribers(self, topic: str) -> "SubscriberSet":
@@ -162,6 +176,10 @@ class MicroBatcher:
         hit = self._cache.get(topic, self._subs_version())
         if hit is not None:
             self.cache_hits += 1
+            tracer = self.tracer
+            if tracer is not None and tracer.sample_n:
+                fut._t_done = tracer.clock()    # answered here, no batch
+                fut._t_via = "cache"
             fut.set_result(hit)
             return fut
         self._pending.append((topic, fut))
@@ -179,10 +197,15 @@ class MicroBatcher:
         """Cache + resolve one batch's futures, stamping the ADR-015
         result-ready mark when tracing is on (the tracer's device span
         ends at result-ready, not at the consumer's in-order await)."""
+        tracer = self._tracing()
+        if tracer is None:
+            self._resolve(version, batch, results, 0)
+        else:
+            with host_span("maxmq.settle", n=len(batch)):
+                self._resolve(version, batch, results, tracer.clock())
+
+    def _resolve(self, version: int, batch, results, done_ns: int) -> None:
         self._fill_cache(version, batch, results)
-        tracer = self.tracer
-        done_ns = (tracer.clock()
-                   if tracer is not None and tracer.sample_n else 0)
         for (_, fut), result in zip(batch, results):
             if not fut.done():
                 if done_ns:
@@ -251,26 +274,32 @@ class MicroBatcher:
             if self._pending:
                 self._wakeup.set()  # leftovers form the next batch
             topics = [t for t, _ in batch]
-            self._note_batch(batch)
+            rec = self._note_batch(batch)
             ver = self._subs_version()   # results valid as-of dispatch
             if self._should_bypass(len(batch)):
-                self._run_bypass(batch, topics, ver)
+                self._run_bypass(batch, topics, ver, rec)
             elif split and not self._engine_routes():
-                await self._dispatch_pipelined(loop, batch, topics, ver)
+                await self._dispatch_pipelined(loop, batch, topics, ver,
+                                               rec)
             else:
-                await self._run_whole_batch(loop, batch, topics, ver)
+                await self._run_whole_batch(loop, batch, topics, ver, rec)
 
-    def _note_batch(self, batch) -> None:
+    def _note_batch(self, batch):
         """Batch-size counters + the ADR-015 dispatch marks (the
-        coalescing-wait span ends for every future in the batch)."""
+        coalescing-wait span ends for every future in the batch) and
+        the batch's record, one object every future points at. Returns
+        the record, None with sampling off."""
         self.batches += 1
         self.batched_topics += len(batch)
         self.largest_batch = max(self.largest_batch, len(batch))
-        tracer = self.tracer
-        if tracer is not None and tracer.sample_n:
-            now = tracer.clock()
-            for _, fut in batch:
-                fut._t_dispatch = now
+        tracer = self._tracing()
+        if tracer is None:
+            return None
+        rec = tracer.open_batch(len(batch))
+        for _, fut in batch:
+            fut._t_dispatch = rec.t0_ns
+            fut._t_batch = rec
+        return rec
 
     async def _maybe_window(self) -> None:
         """Adaptive coalescing window: waiting only pays when the device
@@ -315,7 +344,7 @@ class MicroBatcher:
             return False
         return self._bypass_cost(n) < 0.5 * self._device_rtt
 
-    def _run_bypass(self, batch, topics, ver) -> None:
+    def _run_bypass(self, batch, topics, ver, rec=None) -> None:
         """Serve one small batch on the host, inline on the loop
         (bounded by BYPASS_CAP x per-topic cost), updating whichever
         cost model served it. Engines exposing the device-free probe
@@ -334,10 +363,12 @@ class MicroBatcher:
             # broker whose every batch is this cheap would never
             # recompile its tables after a subscription change
             kick()
+        answer = host if host is not None else self._trie_walk
         t0 = time.perf_counter()
         try:
-            results = (host(topics) if host is not None else
-                       [self.engine.index.subscribers(t) for t in topics])
+            results = (answer(topics) if rec is None else
+                       self._traced_inline(rec, host is not None,
+                                           answer, topics))
         except Exception as exc:
             self.errors += 1
             for _, fut in batch:
@@ -350,7 +381,25 @@ class MicroBatcher:
         self.bypasses += len(topics)
         self._settle(ver, batch, results)
         if self._since_probe >= self.BYPASS_PROBE_EVERY:
-            self._shadow_probe(topics)
+            self._shadow_probe(topics, rec)
+
+    def _trie_walk(self, topics):
+        return [self.engine.index.subscribers(t) for t in topics]
+
+    @staticmethod
+    def _traced_inline(rec, via_host: bool, answer, topics):
+        """The inline answer of one bypassed batch under its record:
+        ``match_host`` is how long it held the loop thread, and the
+        ``maxmq.batch`` annotation the same interval in a profiler
+        capture (``t0_ns`` carries any ring span over to its clock)."""
+        rec.via = "host" if via_host else "trie"
+        t0 = rec.tracer.clock()
+        try:
+            with host_span("maxmq.batch", batch=rec.id, n=rec.n,
+                           via=rec.via, t0_ns=t0):
+                return rec.run(answer, topics)
+        finally:
+            rec.phase("match_host", t0, rec.tracer.clock())
 
     def _pick_bypass_host(self, n: int):
         """The engine's device-free probe path when its fixed+per-topic
@@ -385,24 +434,50 @@ class MicroBatcher:
             self._trie_cost += 0.3 * (took / max(1, n) - self._trie_cost)
             self._trie_stale = 0
 
-    def _shadow_probe(self, topics) -> None:
+    def _shadow_probe(self, topics, of=None) -> None:
         """Duplicate one bypassed batch to the device in the background
-        purely to refresh the RTT estimate — no caller waits on it."""
+        purely to refresh the RTT estimate — no caller waits on it.
+        Under tracing it is a batch record of its own (``of`` the batch
+        it duplicates), whose phases reach that batch's sampled
+        publishes."""
         if self._probe_task is not None and not self._probe_task.done():
             return
         self._since_probe = 0
+        rec = None
+        if of is not None:
+            rec = of.tracer.open_batch(len(topics), of=of)
+            rec.via = "device"
 
         async def probe() -> None:
             loop = asyncio.get_running_loop()
             t0 = time.perf_counter()
             try:
-                await loop.run_in_executor(None, self._batch_fn,
-                                           list(topics))
+                await loop.run_in_executor(
+                    None, *self._call(rec, self._batch_fn, list(topics)))
             except Exception:
                 return                     # estimate keeps its last value
-            self._note_rtt(time.perf_counter() - t0)
+            took = time.perf_counter() - t0
+            if rec is not None:
+                self._note_hop(rec)
+                rec.tracer.close_shadow(rec)
+            self._note_rtt(took)
 
         self._probe_task = self._loop.create_task(probe())
+
+    @staticmethod
+    def _call(rec, fn, *args) -> tuple:
+        """What ``run_in_executor`` is to run: ``fn(*args)``, under the
+        batch's record when there is one."""
+        return (fn, *args) if rec is None else (rec.run, fn, *args)
+
+    def _note_hop(self, rec) -> None:
+        """On the loop, first thing after an executor call under ``rec``
+        came back: the hop it waited, and the round trip if that call
+        took one on its thread."""
+        rec.hop()
+        rtt_ns = rec.last("device_rtt")
+        if rtt_ns:
+            self.device_round_trip = rtt_ns / 1e9
 
     def _note_rtt(self, sample: float) -> None:
         """Record one device round-trip sample (dispatch->collect).
@@ -422,26 +497,35 @@ class MicroBatcher:
         else:
             self._device_rtt += 0.3 * (sample - self._device_rtt)
 
-    async def _run_whole_batch(self, loop, batch, topics, ver) -> None:
+    async def _run_whole_batch(self, loop, batch, topics, ver,
+                               rec=None) -> None:
+        if rec is not None:
+            rec.via = "whole"
         t0 = time.perf_counter()
         try:
             # worker thread: overlap device time with the event loop
             results = await loop.run_in_executor(
-                None, self._batch_fn, topics)
+                None, *self._call(rec, self._batch_fn, topics))
         except Exception as exc:  # engine failure → fail the callers
             self.errors += 1      # (the ADR-011 supervisor above us
             for _, fut in batch:  # answers them from the CPU trie)
                 if not fut.done():
                     fut.set_exception(exc)
             return
-        self._note_rtt(time.perf_counter() - t0)
+        took = time.perf_counter() - t0
+        if rec is not None:
+            self._note_hop(rec)
+        self._note_rtt(took)
         self._settle(ver, batch, results)
 
-    async def _dispatch_pipelined(self, loop, batch, topics, ver) -> None:
+    async def _dispatch_pipelined(self, loop, batch, topics, ver,
+                                  rec=None) -> None:
         """Dispatch now, collect in a bounded background task: up to
         ``pipeline_depth`` batches ride the device/link concurrently, so
         a queued request no longer waits out the FULL round trip of the
         batch ahead of it."""
+        if rec is not None:
+            rec.via = "device"
         await self._inflight.acquire()
         # timestamp AFTER the semaphore: under saturation the wait for a
         # pipeline slot is queueing, not round-trip, and folding it into
@@ -449,7 +533,7 @@ class MicroBatcher:
         t0 = time.perf_counter()
         try:
             ctx = await loop.run_in_executor(
-                None, self.engine.dispatch_fixed, topics)
+                None, *self._call(rec, self.engine.dispatch_fixed, topics))
         except asyncio.CancelledError:
             self._inflight.release()
             self._cancel_futures(batch)
@@ -460,17 +544,19 @@ class MicroBatcher:
             # its CPU-trie fallback semantics — never fail the callers
             # for a condition the engine degrades through
             self._inflight.release()
-            await self._run_whole_batch(loop, batch, topics, ver)
+            await self._run_whole_batch(loop, batch, topics, ver, rec)
             return
         task = loop.create_task(
-            self._collect(loop, batch, topics, ctx, ver, t0))
+            self._collect(loop, batch, topics, ctx, ver, t0, rec))
         self._collects.add(task)
         task.add_done_callback(self._collects.discard)
 
-    async def _collect(self, loop, batch, topics, ctx, ver, t0) -> None:
+    async def _collect(self, loop, batch, topics, ctx, ver, t0,
+                       rec=None) -> None:
         try:
             results = await loop.run_in_executor(
-                None, self.engine.collect_fixed, topics, ctx)
+                None, *self._call(rec, self.engine.collect_fixed, topics,
+                                  ctx))
         except asyncio.CancelledError:
             self._cancel_futures(batch)
             raise
@@ -481,9 +567,12 @@ class MicroBatcher:
         finally:
             self._inflight.release()
         if results is None:
-            await self._run_whole_batch(loop, batch, topics, ver)
+            await self._run_whole_batch(loop, batch, topics, ver, rec)
             return
-        self._note_rtt(time.perf_counter() - t0)
+        took = time.perf_counter() - t0
+        if rec is not None:
+            self._note_hop(rec)
+        self._note_rtt(took)
         self._settle(ver, batch, results)
 
     @staticmethod

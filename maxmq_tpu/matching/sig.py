@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import faults
+from ..trace import NO_SPAN, active_batch, host_span
 from .dense import extract_nonzero_words
 from .nfa import Entry, EntryBuilder
 from .topics import (batch_bucket as _batch_bucket, filter_matches_topic,
@@ -1353,6 +1354,17 @@ class OverlayedEngine:
         # background compiles that failed: table rotations and bucket
         # warms, each logged when counted
         self.bg_refresh_errors = 0
+        # the broker's PipelineTracer (bootstrap.build_matcher): while
+        # it samples, a rotation's host work is annotated for a
+        # profiler capture (ADR 015)
+        self.tracer = None
+
+    def _span(self, name: str):
+        """A profiler annotation for host work on this thread while
+        the broker's tracer samples, else nothing."""
+        tracer = self.tracer
+        return (host_span(name) if tracer is not None and tracer.sample_n
+                else NO_SPAN)
 
     def refresh_soon(self) -> None:
         """Kick a background recompile if the tables are stale and none is
@@ -1429,8 +1441,9 @@ class OverlayedEngine:
             b = _batch_bucket(b + 1)    # the exact dispatch ladder
         sizes.append(_batch_bucket(max_batch))
         t0 = time.perf_counter()
-        for size in sizes:
-            self._warm_one(size)
+        with self._span("maxmq.bucket_warm"):
+            for size in sizes:
+                self._warm_one(size)
         self.warm_seconds = time.perf_counter() - t0
 
     def _has_program(self) -> bool:
@@ -1597,7 +1610,9 @@ class SigEngine(OverlayedEngine):
                 return False
             faults.fire(faults.DEVICE_RECOMPILE)
             t0 = time.perf_counter()
-            tables = compile_sig(self.index, max_levels=self.max_levels)
+            with self._span("maxmq.compile_sig"):
+                tables = compile_sig(self.index,
+                                     max_levels=self.max_levels)
             t1 = time.perf_counter()
             if len(tables.groups) > MAX_GROUPS:
                 # pathological corpus (thousands of distinct wildcard
@@ -1949,6 +1964,11 @@ class SigEngine(OverlayedEngine):
                 "APIs, which fall back to the CPU trie")
         faults.fire(faults.DEVICE_MATCH)
         tables, fn_fixed, fmt = state[0], state[6], state[7]
+        # ADR 015: the phases go into the micro-batch's record when a
+        # tracing batcher handed one to this thread, else nothing
+        rec = active_batch()
+        if rec is not None:
+            rec.begin("match_prep")
         toks8, lens_enc, hostrows = prepare_batch(tables, topics)
         # Bucket the batch axis to powers of two: fn_fixed is jitted, so
         # every DISTINCT batch shape costs a full XLA compile (seconds) —
@@ -1967,6 +1987,9 @@ class SigEngine(OverlayedEngine):
             lp = np.full(bucket, -1, dtype=lens_enc.dtype)
             lp[:b] = lens_enc
             toks8, lens_enc = tp, lp
+        if rec is not None:
+            rec.end()
+            rec.begin("match_dispatch")
         # both fixed-path programs are jitted and device_put numpy inputs
         out = fn_fixed(toks8, lens_enc)
         if fmt["kind"] == "stream":
@@ -1990,6 +2013,8 @@ class SigEngine(OverlayedEngine):
                 slices.append(s)
                 c0 += n
             out = (counts_dev, stream_dev, slices)
+        if rec is not None:
+            rec.end()
         return out, hostrows, tables, fmt, toks8, lens_enc
 
     # Auto-route (ADR 008): serve TINY corpora from the CPU trie — a
@@ -2063,12 +2088,18 @@ class SigEngine(OverlayedEngine):
             return cpu
         tables = self._state[0]
         batch = len(topics)
+        rec = active_batch()            # ADR 015, as in dispatch_fixed
+        if rec is not None:
+            rec.begin("match_prep")
         toks, lens_enc, hostrows = prepare_batch(tables, topics)
         lengths = np.abs(lens_enc.astype(np.int32))
         fall = lengths >= 127
         # overflow topics are served by the trie fallback pass and
         # counted under fallbacks — not host matches
         self.host_matches += batch - int(fall.sum())
+        if rec is not None:
+            rec.end()
+            rec.begin("match_probe")
         # the '#' hits ride _pairs_with_host's device-pair slot
         # (hostrows may be the fused path's CSR, which _scatter_hits
         # cannot append into). The C probe keeps the per-call cost in
@@ -2084,10 +2115,16 @@ class SigEngine(OverlayedEngine):
             rw_h = (np.concatenate([np.asarray(h) for h in hh])
                     .astype(np.int64) if len(ti_h)
                     else np.empty(0, dtype=np.int64))
+        if rec is not None:
+            rec.end()
+            rec.begin("match_decode")
         ti, rw = _pairs_with_host(batch, ti_h, rw_h, hostrows,
                                   fall, tables)
-        return self.decode_pairs(topics, fall, ti, rw, tables, toks,
-                                 lens_enc)
+        out = self.decode_pairs(topics, fall, ti, rw, tables, toks,
+                                lens_enc)
+        if rec is not None:
+            rec.end()
+        return out
 
     def collect_fixed(self, topics: list[str], ctx) -> list[SubscriberSet]:
         """Decode half of the fixed-slot path: fetch + batch-verify +
@@ -2096,14 +2133,27 @@ class SigEngine(OverlayedEngine):
         fetched stream already IS the topic-sorted device pair list."""
         out, hostrows, tables, fmt = ctx[:4]
         toks8, lens_enc = ctx[4], ctx[5]
-        if fmt["kind"] == "stream":
-            if self.overlay_for(tables.version) == "resync":
-                return self._resync_batch(topics)   # skip the flatten
+        stream = fmt["kind"] == "stream"
+        if stream and self.overlay_for(tables.version) == "resync":
+            return self._resync_batch(topics)   # skip the flatten
+        rec = active_batch()            # ADR 015, as in dispatch_fixed
+        if rec is not None:
+            rec.begin("match_fetch")    # ends with the result on the host
+        if stream:
             fetched = self._fetch_stream(out)
-            return self._decode_stream(topics, ctx, *fetched)
-        cnt, rows, hostrows, tables = self.match_fixed([], out=ctx)
-        return self.decode_fixed(topics, cnt, rows, hostrows, tables,
-                                 toks8, lens_enc)
+        else:
+            cnt, rows, hostrows, tables = self.match_fixed([], out=ctx)
+        if rec is not None:
+            rec.end()
+            rec.begin("match_decode")
+        if stream:
+            results = self._decode_stream(topics, ctx, *fetched)
+        else:
+            results = self.decode_fixed(topics, cnt, rows, hostrows,
+                                        tables, toks8, lens_enc)
+        if rec is not None:
+            rec.end()
+        return results
 
     def _decode_stream(self, topics: list[str], ctx, cnt, real, flat):
         """Host half of the stream wire format after the fetch: pair

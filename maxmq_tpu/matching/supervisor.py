@@ -381,10 +381,12 @@ class SupervisedMatcher:
                 self._settle_from_trie(out, topic, exc)
             else:
                 self._record_success(probe)
-                # forward the ADR-015 dispatch/done clock marks the
-                # batcher stamped on ITS future, so the tracer's
-                # queue/device split survives the supervisor wrapper
-                for attr in ("_t_dispatch", "_t_done"):
+                # forward the ADR-015 dispatch/done clock marks, the
+                # batch record and the answerer the batcher stamped on
+                # ITS future, so the tracer's queue/device split and the
+                # batch's phases survive the supervisor wrapper
+                for attr in ("_t_dispatch", "_t_done", "_t_batch",
+                             "_t_via"):
                     v = getattr(f, attr, 0)
                     if v:
                         setattr(out, attr, v)
@@ -403,8 +405,15 @@ class SupervisedMatcher:
 
     def _settle_from_trie(self, out: asyncio.Future, topic: str,
                           cause: Exception | None) -> None:
+        tracer = getattr(self.inner, "tracer", None)
         try:
-            out.set_result(self._trie(topic))
+            answer = self._trie(topic)
+            if tracer is not None and tracer.sample_n:
+                # ADR 015: answered here, by the trie, whatever the
+                # inner future goes on to do
+                out._t_done = tracer.clock()
+                out._t_via = "fallback"
+            out.set_result(answer)
         except Exception:
             out.set_exception(cause if cause is not None else
                               RuntimeError("matcher degraded and no "

@@ -439,6 +439,12 @@ def _register_trace_metrics(registry: Registry, broker) -> None:
         lambda: [({"stage": s}, h)
                  for s, h in sorted(tracer.stage_hist.items())])
     registry.histogram_func(
+        "maxmq_matcher_batch_phase_seconds",
+        "Per-phase time of traced micro-batches, once a batch (ADR 015 "
+        "batch records; buckets from 10us)",
+        lambda: [({"phase": p}, h)
+                 for p, h in sorted(tracer.batch_hist.items())])
+    registry.histogram_func(
         "maxmq_broker_publish_e2e_seconds",
         "End-to-end latency of sampled publishes (decode to terminal "
         "stage) by inbound QoS",
@@ -1022,8 +1028,16 @@ def _register_matcher_metrics(registry: Registry, broker) -> None:
                 lambda: matcher.bypasses)
             registry.gauge_func(
                 "maxmq_matcher_device_rtt_seconds",
-                "Measured device round-trip EWMA driving the bypass",
+                "EWMA of dispatch -> result as the event loop sees it, "
+                "executor hops included; drives the bypass",
                 lambda: matcher.device_rtt)
+            registry.gauge_func(
+                "maxmq_matcher_device_round_trip_seconds",
+                "Last dispatch -> fetched result taken on one executor "
+                "thread (its waits for the interpreter lock included, "
+                "no loop hop); 0 until a traced whole-batch call or "
+                "shadow probe (trace_sample_n > 0)",
+                lambda: getattr(matcher, "device_round_trip", 0.0))
         eng = getattr(matcher, "engine", matcher)
         if hasattr(eng, "host_matches"):
             registry.counter_func(

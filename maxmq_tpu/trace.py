@@ -42,6 +42,38 @@ Stage model (see docs/adr/015-publish-tracing.md for the contract):
 ``aggregate``      windowed-aggregate close + synthesized emission
                    (ADR 023; histogram-only like journal_commit — a
                    housekeeping-tick span, not a publish-path one)
+``loop_lag``       a stamp scheduled with ``call_soon`` when the publish
+                   was sampled -> the stamp running: how long a ready
+                   callback waits for the loop at that moment (top
+                   level, not critical; lands like a drain when the
+                   publish finished first)
+
+``match_device`` ends where the answer was given and says by whom
+(``via``: cache | host | trie | device | fallback); what the in-order
+consumer waited past the answer is ``pipeline_wait``. While sampling is
+on the batcher also keeps one :class:`BatchRecord` per micro-batch: its
+phases are copied onto the batch's sampled publishes as CHILD spans of
+``match_device`` (``parent``, ``batch``), outside ``CRITICAL_STAGES``:
+
+``match_host``     the whole inline answer of a bypassed batch, as the
+                   loop thread lived it (how long it blocked the loop)
+``match_prep``     tokenize + exact/'+' probes + padding to the bucket
+``match_probe``    the '#'-group host probe (bypass path)
+``match_dispatch`` the jitted call + starting the async copies
+``match_fetch``    until the result is on the host (the path's
+                   ``block_until_ready``)
+``match_decode``   pair assembly + batch verify + entry union
+``device_rtt``     just before the jitted call -> fetched arrays on the
+                   host, read on ONE executor thread (whole-batch calls
+                   and shadow probes only: the pipelined path has a loop
+                   hop between dispatch and fetch and records no sum);
+                   contains that thread's waits for the interpreter lock
+``match_hop``      result ready on the executor thread -> the loop
+                   running its continuation
+
+Host work is also wrapped in ``jax.profiler.TraceAnnotation``
+(``maxmq.*``, :func:`host_span`), so a profiler capture holds it on the
+device trace's clock; ``tools/trace_gaps.py`` reads such a capture.
 
 Cross-node model (ADR 017): a node receiving a forwarded publish whose
 envelope carries trace context **adopts** the origin's trace — same
@@ -54,10 +86,11 @@ per-hop-count ``cross_hist`` e2e histograms.
 
 Cost contract: with ``sample_n == 0`` every instrumented site reduces
 to one attribute check/branch and **zero allocations** (asserted by
-``tests/test_trace.py`` via the ``allocations`` counter) — and with
-sampling off at the origin no trace context crosses the wire, so the
-propagation path adds zero allocations cluster-wide (asserted by
-``tests/test_cluster_trace.py``). Sampling is deterministic — a stride
+``tests/test_trace.py`` via the ``allocations`` counter, which counts
+batch records too; no ``TraceAnnotation`` is built and no ``call_soon``
+scheduled) — and with sampling off at the origin no trace context
+crosses the wire, so the propagation path adds zero allocations
+cluster-wide (asserted by ``tests/test_cluster_trace.py``). Sampling is deterministic — a stride
 counter, not a PRNG — and every timestamp is read through the fault
 registry's swappable ``clock_ns`` (faults.py), so tests drive spans
 with a scripted clock.
@@ -65,25 +98,38 @@ with a scripted clock.
 
 from __future__ import annotations
 
+import sys
 import threading
+import types
 from collections import deque
 
 from . import faults
 from .metrics import Histogram
 
+# a micro-batch's phases (BatchRecord): children of match_device on a
+# sampled publish, never critical (a child must not be summed twice)
+BATCH_PHASES = ("match_host", "match_prep", "match_probe",
+                "match_dispatch", "match_fetch", "match_decode",
+                "device_rtt", "match_hop")
 # canonical pipeline stages; CRITICAL_STAGES are the contiguous
 # publisher-path segments whose durations sum to ~e2e (drain happens
 # after the publisher's terminal stage; journal_commit/takeover/release
 # are not tied to one publish's critical path; bridge_in is critical
-# only on ADOPTED traces, where it IS the path's first local segment)
+# only on ADOPTED traces, where it IS the path's first local segment;
+# loop_lag is a probe of the loop beside the path)
 STAGES = ("decode", "admission", "match_queue", "match_device",
           "pipeline_wait", "filter", "fanout", "bridge", "bridge_in",
           "journal_commit", "barrier", "ack", "drain", "takeover",
-          "release", "aggregate")
+          "release", "aggregate", "loop_lag") + BATCH_PHASES
 CRITICAL_STAGES = frozenset(
     s for s in STAGES
     if s not in ("drain", "journal_commit", "takeover", "release",
-                 "aggregate"))
+                 "aggregate", "loop_lag") + BATCH_PHASES)
+# 10us .. 1s: a phase of one micro-batch is tens of microseconds to a
+# few milliseconds, under the default ladder's first bound
+BATCH_PHASE_BUCKETS = (
+    0.00001, 0.000025, 0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025,
+    0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 1.0)
 
 MAX_DRAIN_SPANS = 8     # per-trace cap on recorded subscriber drains
 SLOWEST_KEEP = 8        # slowest-ever publishes kept beside the ring
@@ -91,12 +137,181 @@ MAX_REMOTE_REPORTS = 8  # per-entry cap on attached remote span reports
 MAX_JOURNAL_BUCKETS = 16  # journal-attribution histogram families kept
 
 
+# -- host spans in the profiler's own trace ------------------------------
+
+
+class _NoSpan:
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+NO_SPAN = _NoSpan()
+_annotation = None      # jax.profiler.TraceAnnotation, on first use
+
+
+def host_span(name: str, **stats):
+    """A ``jax.profiler.TraceAnnotation`` around synchronous work on the
+    calling thread: a profiler capture then holds it on plane
+    ``/host:CPU``, on the clock of the device's events, with ``stats``
+    beside it. Callers gate on ``tracer.sample_n`` and never hold one
+    across an ``await`` (:func:`annotated` is for coroutines). A process
+    that has not imported JAX can have no capture open, and stays off
+    JAX."""
+    global _annotation
+    if _annotation is None:
+        if "jax" not in sys.modules:
+            return NO_SPAN
+        from jax.profiler import TraceAnnotation as _annotation
+    return _annotation(name, **stats)
+
+
+@types.coroutine
+def annotated(name: str, coro):
+    """Await ``coro`` under :func:`host_span` ``name``, open only while
+    the coroutine runs: closed at every real suspension and opened again
+    on resume, so the span never covers another callback's work."""
+    value, exc = None, None
+    while True:
+        with host_span(name):
+            try:
+                waited = (coro.send(value) if exc is None
+                          else coro.throw(exc))
+            except StopIteration as stop:
+                return stop.value
+        try:
+            value, exc = (yield waited), None
+        except BaseException as thrown:     # cancellation: the coroutine's
+            value, exc = None, thrown
+
+
+# -- micro-batch records -------------------------------------------------
+
+_active = threading.local()
+
+
+def active_batch():
+    """The record of the micro-batch the calling thread is answering
+    (``BatchRecord.run`` put it there), else None: how the engine's
+    host half learns where to write its phases."""
+    return getattr(_active, "rec", None)
+
+
+class BatchRecord:
+    """One micro-batch while sampling is on: id, topics, who answered
+    (``via``: host | trie | device | whole), and its phases ``(name,
+    t0_ns, t1_ns, on_loop)`` on the tracer's clock. ``on_loop`` says the
+    loop thread recorded the phase (for the engine's phases: ran it).
+    Every future of the batch points at the one record; a shadow probe
+    is a record of its own (``of`` = the batch it duplicates)."""
+
+    __slots__ = ("id", "n", "via", "of", "t0_ns", "phases", "traces",
+                 "probe", "ready_ns", "closed", "tracer", "_loop_tid",
+                 "_open")
+
+    def __init__(self, tracer: "PipelineTracer", batch_id: int, n: int,
+                 of: "BatchRecord | None" = None) -> None:
+        self.id = batch_id
+        self.n = n
+        self.via = ""
+        self.of = of
+        self.t0_ns = tracer.clock()
+        self.phases: list[tuple[str, int, int, bool]] = []
+        self.traces: list[PublishTrace] = []    # its sampled publishes
+        self.probe: BatchRecord | None = None   # the shadow probe of it
+        self.ready_ns = 0       # result ready on the thread that ran it
+        self.closed = False     # a shadow probe: every phase is in
+        self.tracer = tracer
+        self._loop_tid = threading.get_ident()
+        self._open = None
+
+    def begin(self, name: str) -> None:
+        """Open phase ``name`` on the calling thread (one at a time)."""
+        t0 = self.tracer.clock()
+        span = host_span("maxmq.batch." + name.removeprefix("match_"),
+                         batch=self.id, t0_ns=t0)
+        span.__enter__()
+        self._open = (name, t0, span)
+
+    def end(self) -> None:
+        name, t0, span = self._open
+        self._open = None
+        span.__exit__(None, None, None)
+        self.phase(name, t0, self.tracer.clock())
+
+    def phase(self, name: str, t0_ns: int, t1_ns: int) -> None:
+        self.phases.append((name, t0_ns, t1_ns,
+                            threading.get_ident() == self._loop_tid))
+        self.tracer.batch_hist[name].observe(
+            max(t1_ns - t0_ns, 0) / 1e9)
+
+    def run(self, fn, *args):
+        """``fn(*args)`` with this record active on the calling thread,
+        then the result-ready stamp. A call that ran dispatch and fetch
+        both did so back to back on this thread with no loop hop
+        between: only then is ``device_rtt`` recorded."""
+        first = len(self.phases)
+        _active.rec = self
+        try:
+            return fn(*args)
+        finally:
+            _active.rec = None
+            if self._open is not None:      # the call raised mid-phase
+                self.end()
+            self.ready_ns = self.tracer.clock()
+            mine = {p[0]: p for p in self.phases[first:]}
+            if "match_dispatch" in mine and "match_fetch" in mine:
+                self.phase("device_rtt", mine["match_dispatch"][1],
+                           mine["match_fetch"][2])
+
+    def hop(self) -> None:
+        """On the loop, as the continuation of an executor call runs."""
+        self.phase("match_hop", self.ready_ns, self.tracer.clock())
+
+    def last(self, name: str) -> int:
+        """Nanoseconds of the newest phase ``name``, 0 where none."""
+        for phase, t0, t1, _on_loop in reversed(self.phases):
+            if phase == name:
+                return max(t1 - t0, 0)
+        return 0
+
+    def as_dict(self) -> dict:
+        out = {"id": self.id, "n": self.n, "via": self.via,
+               "t0_us": self.t0_ns // 1000,
+               "phases": [{"name": name,
+                           "off_us": (t0 - self.t0_ns) // 1000,
+                           "dur_us": max(t1 - t0, 0) // 1000,
+                           "on_loop": on_loop}
+                          for name, t0, t1, on_loop in self.phases]}
+        if self.of is not None:
+            out["shadow"] = True
+            out["of"] = self.of.id
+        return out
+
+
+def _span_dict(start_ns: int, stage: str, t0_ns: int, dur_ns: int,
+               parent: str = "", batch: int = 0, via: str = "",
+               shadow: bool = False) -> dict:
+    out = {"stage": stage, "off_us": (t0_ns - start_ns) // 1000,
+           "dur_us": dur_ns // 1000, "parent": parent}
+    if batch:
+        out["batch"] = batch
+    if via:
+        out["via"] = via
+    if shadow:
+        out["shadow"] = True
+    return out
+
+
 class PublishTrace:
     """One sampled publish: correlation id + completed spans. Span
     endpoints are raw ``clock_ns`` stamps; nothing here allocates past
-    the object itself and its two lists."""
+    the object itself and its lists."""
 
     __slots__ = ("id", "topic", "qos", "client", "start_ns", "spans",
+                 "children", "via", "batch",
                  "drains", "degraded", "done", "n_drain", "entry",
                  "t_admit", "t_match", "t_barrier", "origin", "hops")
 
@@ -108,6 +323,11 @@ class PublishTrace:
         self.client = client
         self.start_ns = start_ns
         self.spans: list[tuple[str, int, int]] = []   # (stage, t0, dur)
+        # its micro-batch's phases, children of match_device:
+        # (stage, t0, dur, batch id, from a shadow probe)
+        self.children: list[tuple[str, int, int, int, bool]] = []
+        self.via = ""           # who gave the match_device answer
+        self.batch = 0          # id of the micro-batch that did
         self.drains: list[tuple[str, int, int]] = []  # (client, t0, dur)
         self.degraded = ""      # ADR-011 rung label when not healthy
         self.done = False
@@ -160,6 +380,12 @@ class PipelineTracer:
         self.stage_errors: dict[tuple[str, str], int] = {}
         self._ring: deque = deque(maxlen=max(int(ring), 1))
         self._slowest: list[dict] = []  # ascending by e2e, bounded
+        # the newest micro-batch records, bounded like the ring; their
+        # phases once a batch, not once a sampled publish
+        self._batches: deque = deque(maxlen=max(int(ring), 1))
+        self._next_batch = 0
+        self.batch_hist: dict[str, Histogram] = {
+            p: Histogram(BATCH_PHASE_BUCKETS) for p in BATCH_PHASES}
         self._lock = threading.Lock()
         self._buckets = buckets
         # -- cross-node plane (ADR 017) --------------------------------
@@ -283,6 +509,64 @@ class PipelineTracer:
         with self._lock:
             return list(self.stage_errors.items())
 
+    def open_batch(self, n: int,
+                   of: BatchRecord | None = None) -> BatchRecord:
+        """A record for one micro-batch of ``n`` topics (callers gate on
+        ``sample_n``); ``of`` makes it the shadow probe of that batch.
+        Runs on the loop: the deque's append is all the scrape thread
+        can race."""
+        self.allocations += 1
+        self._next_batch += 1
+        rec = BatchRecord(self, self._next_batch, n, of)
+        if of is not None:
+            of.probe = rec
+        self._batches.append(rec)
+        return rec
+
+    def batch_spans(self, trace: PublishTrace, rec: BatchRecord) -> None:
+        """Copy the phases of the batch that answered ``trace`` onto it
+        as child spans, with those of its shadow probe when that has
+        ended; one that ends later reaches the trace through
+        ``close_shadow``."""
+        trace.batch = rec.id
+        rec.traces.append(trace)
+        for owner in (rec, rec.probe):
+            if owner is None or (owner is not rec and not owner.closed):
+                continue
+            for name, t0, t1, _on_loop in owner.phases:
+                trace.children.append((name, t0, max(t1 - t0, 0),
+                                       owner.id, owner is not rec))
+
+    def close_shadow(self, probe: BatchRecord) -> None:
+        """A shadow probe's last phase is in: its phases go to the
+        sampled publishes of the batch it duplicated that were traced
+        already, finished or not."""
+        probe.closed = True
+        for trace in probe.of.traces:
+            for name, t0, t1, _on_loop in probe.phases:
+                self.attach(trace, name, t0, t1, probe.id, True)
+
+    def attach(self, trace: PublishTrace, stage: str, start_ns: int,
+               end_ns: int, batch: int = 0, shadow: bool = False) -> None:
+        """One span for a publish that may have finished already:
+        ``loop_lag`` (top level) or, with ``batch``, a phase of that
+        micro-batch (child of ``match_device``). After the finish it
+        feeds the histogram and is appended to the live flight-recorder
+        entry, the way ``drain_span`` appends drains."""
+        dur = max(end_ns - start_ns, 0)
+        if not trace.done:
+            if batch:
+                trace.children.append((stage, start_ns, dur, batch, shadow))
+            else:
+                trace.spans.append((stage, start_ns, dur))
+            return
+        self.stage_hist[stage].observe(dur / 1e9)
+        entry = trace.entry
+        if entry is not None:
+            entry["spans"].append(_span_dict(
+                trace.start_ns, stage, start_ns, dur,
+                "match_device" if batch else "", batch, "", shadow))
+
     def drain_span(self, trace: PublishTrace, client: str,
                    start_ns: int, end_ns: int) -> None:
         """One subscriber's outbound enqueue->writer-flush span; lands
@@ -318,6 +602,8 @@ class PipelineTracer:
         e2e_ns = max(end - trace.start_ns, 0)
         hist = self.stage_hist
         for stage, _t0, dur in trace.spans:
+            hist[stage].observe(dur / 1e9)
+        for stage, _t0, dur, _batch, _shadow in trace.children:
             hist[stage].observe(dur / 1e9)
         if not adopted:
             # adopted e2e is origin-publish -> local-terminal across
@@ -356,8 +642,12 @@ class PipelineTracer:
     @staticmethod
     def _entry(trace: PublishTrace, e2e_ns: int, slow: bool) -> dict:
         start = trace.start_ns
-        spans = [{"stage": s, "off_us": (t0 - start) // 1000,
-                  "dur_us": dur // 1000} for s, t0, dur in trace.spans]
+        spans = [_span_dict(start, s, t0, dur, "", trace.batch, trace.via)
+                 if s == "match_device" else _span_dict(start, s, t0, dur)
+                 for s, t0, dur in trace.spans]
+        spans += [_span_dict(start, s, t0, dur, "match_device", batch,
+                             "", shadow)
+                  for s, t0, dur, batch, shadow in trace.children]
         critical_ns = sum(dur for s, _t0, dur in trace.spans
                           if s in CRITICAL_STAGES)
         entry = {"id": trace.id, "topic": trace.topic, "qos": trace.qos,
@@ -495,6 +785,7 @@ class PipelineTracer:
         with self._lock:
             entries = list(self._ring)
             slowest = list(self._slowest)
+        batches = [rec.as_dict() for rec in list(self._batches)]
         return {"sample_n": self.sample_n, "slow_ms": self.slow_ms,
                 "node": self.node_id,
                 "sampled": self.sampled,
@@ -505,7 +796,8 @@ class PipelineTracer:
                 "stage_quantiles": self.stage_quantiles(),
                 "e2e_quantiles": self.e2e_quantiles(),
                 "cross_node": self.cross_quantiles(),
-                "entries": entries, "slowest": slowest}
+                "entries": entries, "slowest": slowest,
+                "batches": batches}
 
     def chrome_events(self) -> dict:
         """The ``/traces/chrome`` endpoint body: flight-recorder

@@ -831,67 +831,6 @@ Py_ssize_t g_chain_min_base = 64;
 Py_ssize_t g_chain_tail_num = 1;
 Py_ssize_t g_chain_tail_den = 1;
 
-// opt-in section timing for the decode hot path (profiling builds of
-// the bench drive it via _timing_reset/_timing_get; zero cost when off)
-struct DecodeTiming {
-  int64_t pass1_ns = 0, pass2_ns = 0, construct_ns = 0;
-  int64_t constructs = 0, shared_ns = 0;
-  // chain-decision census over timed constructions
-  int64_t chained = 0, single_row = 0, decl_minbase = 0, decl_ratio = 0;
-  int64_t decl_budget = 0;     // slot-map budget exhausted
-  int64_t resolve_ns = 0;      // candidate->base resolution time
-  int64_t multi_base = 0;      // chains composing >= 2 row bases
-  int64_t entries_built = 0;   // plain entries allocated (tail or full)
-};
-DecodeTiming g_timing;
-bool g_timing_on = false;
-int g_timing_depth = 0;   // recursion guard: only depth-0 accumulates
-
-static inline int64_t now_ns() {
-  timespec ts;
-  clock_gettime(CLOCK_MONOTONIC, &ts);
-  return ts.tv_sec * 1000000000LL + ts.tv_nsec;
-}
-
-struct TimeAcc {
-  int64_t *dst;
-  int64_t t0;
-  bool armed;
-  explicit TimeAcc(int64_t *d)
-      : dst(d), t0(0), armed(g_timing_on && g_timing_depth == 0) {
-    if (armed) t0 = now_ns();
-  }
-  ~TimeAcc() {
-    if (armed) *dst += now_ns() - t0;
-  }
-};
-
-PyObject *timing_reset(PyObject *, PyObject *arg) {
-  const int v = PyObject_IsTrue(arg);
-  if (v < 0) return nullptr;
-  g_timing = DecodeTiming{};
-  g_timing_on = v != 0;
-  Py_RETURN_NONE;
-}
-
-PyObject *timing_get(PyObject *, PyObject *) {
-  return Py_BuildValue(
-      "{s:L,s:L,s:L,s:L,s:L,s:L,s:L,s:L,s:L,s:L,s:L,s:L,s:L}",
-      "pass1_ns", (long long)g_timing.pass1_ns,
-      "pass2_ns", (long long)g_timing.pass2_ns,
-      "construct_ns", (long long)g_timing.construct_ns,
-      "constructs", (long long)g_timing.constructs,
-      "shared_ns", (long long)g_timing.shared_ns,
-      "chained", (long long)g_timing.chained,
-      "multi_base", (long long)g_timing.multi_base,
-      "single_row", (long long)g_timing.single_row,
-      "decl_minbase", (long long)g_timing.decl_minbase,
-      "decl_budget", (long long)g_timing.decl_budget,
-      "resolve_ns", (long long)g_timing.resolve_ns,
-      "decl_ratio", (long long)g_timing.decl_ratio,
-      "entries_built", (long long)g_timing.entries_built);
-}
-
 PyObject *set_chain_enabled(PyObject *, PyObject *arg) {
   const int v = PyObject_IsTrue(arg);
   if (v < 0) return nullptr;
@@ -1479,7 +1418,6 @@ PyObject *cached_rowset_result(DecodeTable *t, const int32_t *rows,
 // re-enter this builder on another thread's behalf — publish-once
 // keeps the cached map single and complete.
 PyObject *row_shared(DecodeTable *t, Py_ssize_t r) {
-  TimeAcc time_shared(&g_timing.shared_ns);
   if (t->rshared[r]) return t->rshared[r];
   const auto *off = static_cast<const int64_t *>(t->offsets.buf);
   const auto *kind = static_cast<const uint8_t *>(t->kinds.buf);
@@ -1555,10 +1493,8 @@ ensure_row_base(DecodeTable *t, PyObject *cap, int32_t r, Py_ssize_t p,
   if (fb != t->row_base.end()) {
     b = Py_NewRef(fb->second);
   } else {
-    g_timing_depth++;            // nested build: outer TimeAcc owns it
     int32_t one = r;
     b = cached_intents_result(t, cap, &one, 1, true);
-    g_timing_depth--;
     if (!b) return m;            // PyErr set; *base_out stays null
     // the recursive build can run Python (merge callbacks, GC
     // finalizers) and re-enter this builder; only the emplace WINNER
@@ -1592,8 +1528,6 @@ PyObject *cached_intents_result(DecodeTable *t, PyObject *cap,
     Py_DECREF(key);
     return nullptr;
   }
-  TimeAcc time_construct(&g_timing.construct_ns);
-  if (time_construct.armed) g_timing.constructs++;
   const auto *off = static_cast<const int64_t *>(t->offsets.buf);
   const auto *kind = static_cast<const uint8_t *>(t->kinds.buf);
   Py_ssize_t total = 0;
@@ -1659,16 +1593,8 @@ PyObject *cached_intents_result(DecodeTable *t, PyObject *cap,
     if (sum_base < g_chain_min_base ||
         (total_plain - sum_base) * g_chain_tail_den >
             sum_base * g_chain_tail_num) {
-      if (time_construct.armed) {
-        if (sum_base < g_chain_min_base)
-          g_timing.decl_minbase++;
-        else
-          g_timing.decl_ratio++;
-      }
       n_cand = 0;
     }
-  } else if (time_construct.armed && n_rows == 1) {
-    g_timing.single_row++;
   }
 
   // resolve candidates (ascending row order) into accepted bases:
@@ -1693,7 +1619,6 @@ PyObject *cached_intents_result(DecodeTable *t, PyObject *cap,
       std::swap(cand[b2], cand[b2 - 1]);
       std::swap(cand_p[b2], cand_p[b2 - 1]);
     }
-  TimeAcc time_resolve(&g_timing.resolve_ns);
   for (int ci = 0; ci < n_cand; ci++) {
     const int32_t r = rows[cand[ci]];
     const Py_ssize_t p = cand_p[ci];
@@ -1703,10 +1628,7 @@ PyObject *cached_intents_result(DecodeTable *t, PyObject *cap,
       continue;                 // could overlap a kept base: tail it
     PyObject *b = nullptr;
     auto *m = ensure_row_base(t, cap, r, p, &b);
-    if (!m) {
-      if (time_construct.armed) g_timing.decl_budget++;
-      continue;                 // budget: this row unions in the tail
-    }
+    if (!m) continue;           // budget: this row unions in the tail
     if (!b) {
       drop_bases();
       Py_DECREF(key);
@@ -1720,20 +1642,10 @@ PyObject *cached_intents_result(DecodeTable *t, PyObject *cap,
     kept_mass += p;
     k++;
   }
-  if (time_resolve.armed) {
-    g_timing.resolve_ns += now_ns() - time_resolve.t0;
-    time_resolve.armed = false;
-  }
   // dropped candidates grew the tail: the chain must still win
   if (k && (kept_mass < g_chain_min_base ||
             (total_plain - kept_mass) * g_chain_tail_den >
                 kept_mass * g_chain_tail_num)) {
-    if (time_construct.armed) {
-      if (kept_mass < g_chain_min_base)
-        g_timing.decl_minbase++;
-      else
-        g_timing.decl_ratio++;
-    }
     drop_bases();
   }
 
@@ -1745,11 +1657,6 @@ PyObject *cached_intents_result(DecodeTable *t, PyObject *cap,
     drop_bases();
     Py_DECREF(key);
     return nullptr;
-  }
-  if (time_construct.armed) {
-    if (chained) g_timing.chained++;
-    if (k > 1) g_timing.multi_base++;
-    g_timing.entries_built += chained ? tail_n : total - sh_pairs;
   }
   std::vector<char> is_base_i;
   if (chained) {
@@ -2191,9 +2098,7 @@ PyObject *decode_batch_impl(PyObject *args, const bool intents) {
   std::vector<int32_t> v_rw;
   v_tp.reserve(N);
   v_rw.reserve(N);
-  {
-    TimeAcc time_pass1(&g_timing.pass1_ns);
-    for (Py_ssize_t k = 0; k < N; k++) {
+  for (Py_ssize_t k = 0; k < N; k++) {
     const int64_t tp = ti[k], r = rw[k];
     if (tp < 0 || tp >= B || r < 0 || r >= t->R) continue;
     const uint8_t f = fl[r];
@@ -2222,10 +2127,8 @@ PyObject *decode_batch_impl(PyObject *args, const bool intents) {
     if (!ok) continue;
     v_tp.push_back(tp);
     v_rw.push_back(static_cast<int32_t>(r));
-    }
   }
 
-  TimeAcc time_pass2(&g_timing.pass2_ns);
   // pass 2 — counting-sort the survivors by topic (pairs may interleave
   // device and host-probe streams), then resolve each topic's row SET
   // through the table's result cache: topics overwhelmingly repeat a
@@ -2447,10 +2350,6 @@ PyMethodDef methods[] = {
     {"prewarm_bases", prewarm_bases, METH_VARARGS,
      "Build chained-decode row anchors in bounded chunks "
      "(capsule, start_row, max_builds) -> next_row."},
-    {"_timing_reset", timing_reset, METH_O,
-     "PROFILING: reset and enable(1)/disable(0) decode section timers."},
-    {"_timing_get", timing_get, METH_NOARGS,
-     "PROFILING: accumulated decode section times (ns) since reset."},
     {"_set_multi_base", set_multi_base, METH_O,
      "TEST/TUNING: enable/disable multi-row base composition (off = "
      "legacy single-fattest-row chaining)."},

@@ -332,3 +332,196 @@ async def test_adaptive_cpu_bypass_serves_small_batches():
         assert batcher._since_probe <= 1
     finally:
         await batcher.close()
+
+
+# -- ADR 015: a record per micro-batch, the engine's phases in it --------
+
+
+def _traced_sig_batcher(tracer, **kw):
+    """A MicroBatcher over a real SigEngine (device path, not the
+    small-corpus router), with the tracer attached as bootstrap does."""
+    from maxmq_tpu.matching.sig import SigEngine
+
+    index = TopicIndex()
+    for i in range(150):
+        index.subscribe(f"cl-{i}", Subscription(filter=f"tr/{i}/+", qos=1))
+        index.subscribe(f"cl-{i}", Subscription(filter=f"tr/{i}/#", qos=0))
+    eng = SigEngine(index)
+    eng.route_small = False
+    batcher = MicroBatcher(eng, window_us=0, max_batch=64, **kw)
+    batcher.tracer = eng.tracer = tracer
+    return batcher
+
+
+def _phases(rec) -> dict:
+    return {name: (t0, t1, on_loop) for name, t0, t1, on_loop in rec.phases}
+
+
+async def _one_batch(batcher, n=12, tag="x"):
+    futs = [batcher.enqueue(f"tr/{i}/{tag}") for i in range(n)]
+    await asyncio.gather(*futs)
+    rec = futs[0]._t_batch
+    assert all(f._t_batch is rec for f in futs)     # one object a batch
+    assert rec.n == n
+    return rec
+
+
+async def test_bypassed_batch_records_the_host_phases_on_the_loop():
+    from maxmq_tpu.trace import PipelineTracer
+
+    tracer = PipelineTracer(sample_n=1)
+    batcher = _traced_sig_batcher(tracer)
+    try:
+        batcher._device_rtt, batcher._rtt_samples = 10.0, 2    # bypass wins
+        rec = await _one_batch(batcher)
+        assert rec.via == "host" and batcher.bypasses == 12
+        ph = _phases(rec)
+        assert set(ph) == {"match_host", "match_prep", "match_probe",
+                           "match_decode"}
+        assert all(on_loop for _t0, _t1, on_loop in ph.values())
+        h0, h1, _ = ph["match_host"]
+        inner = [ph[k] for k in ("match_prep", "match_probe",
+                                 "match_decode")]
+        assert h0 <= min(t0 for t0, _t1, _ in inner)
+        assert h1 >= max(t1 for _t0, t1, _ in inner)
+        assert sum(t1 - t0 for t0, t1, _ in inner) <= h1 - h0
+        # the trie walk, when the cost model prefers it, says so
+        batcher._trie_cost = 1e-9
+        rec = await _one_batch(batcher, tag="y")
+        assert rec.via == "trie" and set(_phases(rec)) == {"match_host"}
+        assert tracer.batch_hist["match_host"].count == 2
+        assert tracer.batch_hist["match_prep"].count == 1
+    finally:
+        await batcher.close()
+
+
+async def test_whole_batch_device_call_records_round_trip_and_hop():
+    from maxmq_tpu.trace import PipelineTracer
+
+    tracer = PipelineTracer(sample_n=1)
+    batcher = _traced_sig_batcher(tracer, pipeline_depth=1,
+                                  cpu_bypass=False)
+    try:
+        rec = await _one_batch(batcher)
+        assert rec.via == "whole"
+        ph = _phases(rec)
+        assert {"match_prep", "match_dispatch", "match_fetch",
+                "match_decode", "device_rtt", "match_hop"} <= set(ph)
+        dur = {k: t1 - t0 for k, (t0, t1, _) in ph.items()}
+        assert dur["device_rtt"] >= dur["match_dispatch"] + dur["match_fetch"]
+        assert ph["device_rtt"][0] == ph["match_dispatch"][0]
+        assert ph["device_rtt"][1] == ph["match_fetch"][1]
+        # the engine ran off the loop; the hop was recorded on it, from
+        # result-ready on the worker thread
+        assert not ph["match_dispatch"][2] and not ph["match_fetch"][2]
+        assert ph["match_hop"][2] and ph["match_hop"][0] == rec.ready_ns
+        assert ph["match_hop"][0] >= ph["match_decode"][1]
+        assert batcher.device_round_trip == dur["device_rtt"] / 1e9 > 0
+    finally:
+        await batcher.close()
+
+
+async def test_pipelined_path_records_no_device_rtt():
+    """Dispatch and collect lie across a loop hop there: only the two
+    phases are recorded, never a sum."""
+    from maxmq_tpu.trace import PipelineTracer
+
+    tracer = PipelineTracer(sample_n=1)
+    batcher = _traced_sig_batcher(tracer, cpu_bypass=False)    # depth 3
+    try:
+        rec = await _one_batch(batcher)
+        assert rec.via == "device"
+        ph = _phases(rec)
+        assert {"match_prep", "match_dispatch", "match_fetch",
+                "match_decode", "match_hop"} <= set(ph)
+        assert "device_rtt" not in ph
+        assert batcher.device_round_trip == 0.0
+        assert tracer.batch_hist["device_rtt"].count == 0
+    finally:
+        await batcher.close()
+
+
+async def test_shadow_probe_is_a_record_of_its_own():
+    from maxmq_tpu.trace import PipelineTracer
+
+    tracer = PipelineTracer(sample_n=1)
+    batcher = _traced_sig_batcher(tracer)
+    try:
+        batcher._device_rtt, batcher._rtt_samples = 10.0, 2
+        batcher._since_probe = batcher.BYPASS_PROBE_EVERY - 1
+        rec = await _one_batch(batcher)
+        await batcher._probe_task
+        probe = rec.probe
+        assert probe is not None and probe.of is rec and probe.closed
+        assert probe.id != rec.id and probe.via == "device"
+        assert {"match_dispatch", "match_fetch", "device_rtt",
+                "match_hop"} <= set(_phases(probe))
+        shown = tracer.report()["batches"]
+        assert [b["id"] for b in shown] == [rec.id, probe.id]
+        assert shown[1]["shadow"] is True and shown[1]["of"] == rec.id
+        assert "shadow" not in shown[0] and shown[0]["via"] == "host"
+        assert {p["name"] for p in shown[0]["phases"]} >= {"match_host"}
+        assert all(p["on_loop"] for p in shown[0]["phases"])
+    finally:
+        await batcher.close()
+
+
+async def test_supervisor_forwards_the_batch_record():
+    from maxmq_tpu.matching.supervisor import SupervisedMatcher
+    from maxmq_tpu.trace import PipelineTracer
+
+    tracer = PipelineTracer(sample_n=1)
+    batcher = MicroBatcher(FakeEngine(), window_us=0, cpu_bypass=False)
+    batcher.tracer = tracer
+    sup = SupervisedMatcher(batcher, deadline_ms=2000)
+    try:
+        out = sup.enqueue("a/b")
+        assert await out == "result:a/b"
+        rec = out._t_batch
+        assert rec.id == 1 and rec.n == 1 and rec.via == "whole"
+        assert out._t_dispatch == rec.t0_ns and out._t_done >= rec.t0_ns
+        # a cache hit has no batch: the answerer's name is forwarded
+        hit = sup.enqueue("a/b")
+        await hit
+        assert hit._t_via == "cache" and hit._t_done
+        assert not hasattr(hit, "_t_batch")
+        assert not hasattr(hit, "_t_dispatch")
+    finally:
+        await batcher.close()
+
+
+async def test_batch_ring_is_bounded_by_trace_ring():
+    from maxmq_tpu.trace import PipelineTracer
+
+    tracer = PipelineTracer(sample_n=1, ring=4)
+    batcher = MicroBatcher(FakeEngine(), window_us=0, max_batch=1,
+                           cpu_bypass=False)
+    batcher.tracer = tracer
+    try:
+        await asyncio.gather(
+            *[batcher.subscribers_async(f"r/{i}") for i in range(10)])
+        shown = tracer.report()["batches"]
+        assert [b["id"] for b in shown] == [7, 8, 9, 10]
+        assert tracer.allocations == 10     # records count as allocations
+    finally:
+        await batcher.close()
+
+
+async def test_no_marks_no_records_with_sampling_off():
+    from maxmq_tpu.trace import PipelineTracer
+
+    tracer = PipelineTracer(sample_n=0)
+    batcher = _traced_sig_batcher(tracer)
+    try:
+        batcher._device_rtt, batcher._rtt_samples = 10.0, 2
+        futs = [batcher.enqueue(f"tr/{i}/x") for i in range(4)]
+        await asyncio.gather(*futs)
+        futs.append(batcher.enqueue("tr/1/x"))          # a cache hit
+        for fut in futs:
+            assert not [a for a in ("_t_dispatch", "_t_done", "_t_batch",
+                                    "_t_via") if hasattr(fut, a)]
+        assert tracer.allocations == 0
+        assert tracer.report()["batches"] == []
+        assert all(h.count == 0 for h in tracer.batch_hist.values())
+    finally:
+        await batcher.close()

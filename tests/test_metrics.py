@@ -218,6 +218,44 @@ def test_matcher_metrics_series_render():
     assert "maxmq_matcher_trie_routed_total 5" in text
 
 
+def test_round_trip_gauges_are_named_apart_and_phases_exposed():
+    """The loop-side estimate keeps its name and says what it is; the
+    time taken on the executor thread has a gauge of its own; a traced
+    batch's phases feed a histogram family whose ladder starts at
+    10 us. The exposition stays conformant."""
+    import importlib.util
+    import os
+    from maxmq_tpu.matching.batcher import MicroBatcher
+    from maxmq_tpu.matching.sig import SigEngine
+    from maxmq_tpu.matching.supervisor import SupervisedMatcher
+
+    broker = Broker(BrokerOptions(capabilities=Capabilities(
+        sys_topic_interval=0, trace_sample_n=1)))
+    mb = MicroBatcher(SigEngine(broker.topics))
+    mb.tracer = broker.tracer
+    broker.attach_matcher(SupervisedMatcher(mb, index=broker.topics))
+    mb._device_rtt, mb.device_round_trip = 0.011, 0.0004
+    rec = broker.tracer.open_batch(3)
+    rec.phase("match_prep", 0, 30_000)          # 30 us
+    reg = Registry()
+    register_broker_metrics(reg, broker)
+    text = reg.expose()
+    assert "maxmq_matcher_device_rtt_seconds 0.011" in text
+    assert "maxmq_matcher_device_round_trip_seconds 0.0004" in text
+    assert "executor hops included; drives the bypass" in text
+    fam = "maxmq_matcher_batch_phase_seconds"
+    assert f'{fam}_bucket{{phase="match_prep",le="1e-05"}} 0' in text
+    assert f'{fam}_bucket{{phase="match_prep",le="5e-05"}} 1' in text
+    assert f'{fam}_count{{phase="match_hop"}} 0' in text
+    spec = importlib.util.spec_from_file_location(
+        "_expo_check", os.path.join(os.path.dirname(__file__), os.pardir,
+                                    "scripts",
+                                    "check_metrics_exposition.py"))
+    checker = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(checker)
+    assert checker.validate(text) == []
+
+
 def test_kernel_width_metrics_render():
     """The ADR-010 dual-width kernel series reflect the LIVE plan at
     scrape time (groups/words by width, plane passes saved)."""

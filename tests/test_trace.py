@@ -506,3 +506,380 @@ def test_drain_span_cap():
     assert MAX_DRAIN_SPANS < 20
     assert tracer.stage_hist["drain"].count == 20
     assert CRITICAL_STAGES.isdisjoint({"drain", "journal_commit"})
+
+
+# -- the matcher leg: who answered, the batch's phases, the loop --------
+
+
+def _unserved_broker(**caps):
+    caps.setdefault("trace_sample_n", 1)
+    return Broker(BrokerOptions(capabilities=Capabilities(
+        sys_topic_interval=0, **caps)))
+
+
+def _sampled_publish(broker, topic):
+    """What process_publish does to open a sampled publish's trace,
+    without a socket: needs a running loop (the loop_lag stamp)."""
+    from types import SimpleNamespace
+    packet = SimpleNamespace(topic=topic, fixed=SimpleNamespace(qos=0))
+    broker._trace_begin(SimpleNamespace(id="p1"), packet)
+    return packet
+
+
+async def _publish_through(broker, matcher, topics, before_spans=None):
+    """_enqueue_publish and the in-order consumer's tracing steps for a
+    run of publishes over ``matcher``; returns their ring entries."""
+    tracer, items = broker.tracer, []
+    for topic in topics:
+        packet = _sampled_publish(broker, topic)
+        packet._trace.t_match = tracer.clock()
+        items.append((matcher.enqueue(topic), packet))
+    for fut, packet in items:
+        await fut
+        if before_spans is not None:
+            await before_spans()
+        broker._trace_match_spans(fut, packet)
+        tracer.finish(packet._trace)
+    return [packet._trace.entry for _f, packet in items]
+
+
+def _spans(entry, stage):
+    return [s for s in entry["spans"] if s["stage"] == stage]
+
+
+class _Clock:
+    """A clock the test moves by hand, behind the fault registry."""
+
+    def __init__(self):
+        self.ns = 1_000_000_000
+        faults.REGISTRY.clock_ns = lambda: self.ns
+
+    def advance(self, ms):
+        self.ns += int(ms * 1e6)
+
+
+async def test_cache_hit_ends_match_device_at_the_answer():
+    """A topic-cache hit is answered inside enqueue: match_device ends
+    there, and the 5 ms the in-order consumer took to reach the publish
+    are pipeline_wait (they used to be booked as match_device)."""
+    from maxmq_tpu.matching.batcher import MicroBatcher
+    from test_batcher import FakeEngine
+
+    clock = _Clock()
+    broker = _unserved_broker()
+    batcher = MicroBatcher(FakeEngine(), window_us=0, cpu_bypass=False)
+    batcher.tracer = broker.tracer
+    try:
+        await batcher.subscribers_async("hot/a")        # fills the cache
+        (entry,) = await _publish_through(
+            broker, batcher, ["hot/a"],
+            before_spans=lambda: asyncio.sleep(0, clock.advance(5)))
+        (dev,) = _spans(entry, "match_device")
+        assert dev["via"] == "cache" and dev["dur_us"] == 0
+        assert "batch" not in dev and dev["parent"] == ""
+        (wait,) = _spans(entry, "pipeline_wait")
+        assert wait["dur_us"] == 5000
+        assert not _spans(entry, "match_queue")
+        assert batcher.cache_hits == 1
+    finally:
+        await batcher.close()
+
+
+async def test_supervisor_trie_answer_ends_match_device_at_the_answer():
+    from maxmq_tpu.matching.batcher import MicroBatcher
+    from maxmq_tpu.matching.supervisor import (BREAKER_OPEN,
+                                               SupervisedMatcher)
+    from test_batcher import FakeEngine
+
+    clock = _Clock()
+    broker = _unserved_broker()
+    batcher = MicroBatcher(FakeEngine(), window_us=0, cpu_bypass=False)
+    batcher.tracer = broker.tracer
+    sup = SupervisedMatcher(batcher, index=broker.topics)
+    sup._state, sup._open_until = BREAKER_OPEN, float("inf")
+    try:
+        (entry,) = await _publish_through(
+            broker, sup, ["t/x"],
+            before_spans=lambda: asyncio.sleep(0, clock.advance(7)))
+        (dev,) = _spans(entry, "match_device")
+        assert dev["via"] == "fallback" and dev["dur_us"] == 0
+        (wait,) = _spans(entry, "pipeline_wait")
+        assert wait["dur_us"] == 7000
+        assert sup.breaker_fallbacks == 1 and batcher.batches == 0
+    finally:
+        await batcher.close()
+
+
+async def test_bypassed_batch_children_share_one_batch_id():
+    from test_batcher import _traced_sig_batcher
+
+    broker = _unserved_broker()
+    batcher = _traced_sig_batcher(broker.tracer)
+    try:
+        batcher._device_rtt, batcher._rtt_samples = 10.0, 2
+        entries = await _publish_through(
+            broker, batcher, [f"tr/{i}/x" for i in range(12)])
+        ids = set()
+        for entry in entries:
+            (dev,) = _spans(entry, "match_device")
+            assert dev["via"] == "host" and dev["parent"] == ""
+            ids.add(dev["batch"])
+            kids = [s for s in entry["spans"] if s["parent"]]
+            assert {s["stage"] for s in kids} == {
+                "match_host", "match_prep", "match_probe", "match_decode"}
+            assert all(s["parent"] == "match_device"
+                       and s["batch"] == dev["batch"] for s in kids)
+            assert all(s["parent"] == "" for s in entry["spans"]
+                       if s not in kids)
+            # children are not summed twice
+            critical = sum(s["dur_us"] for s in entry["spans"]
+                           if s["stage"] in CRITICAL_STAGES)
+            assert entry["critical_sum_ms"] == pytest.approx(
+                critical / 1e3, abs=0.02)
+            assert not CRITICAL_STAGES & {s["stage"] for s in kids}
+            (host,) = _spans(entry, "match_host")
+            assert host["dur_us"] <= dev["dur_us"] + 1
+        assert len(ids) == 1
+        # every sampled publish of the batch fed the stage histogram
+        assert broker.tracer.stage_hist["match_host"].count == 12
+        assert broker.tracer.batch_hist["match_host"].count == 1
+    finally:
+        await batcher.close()
+
+
+@pytest.mark.parametrize("probe_first", [False, True])
+async def test_shadow_probe_phases_reach_the_duplicated_batchs_entries(
+        probe_first):
+    """The probe ends after the publishes finished (its spans are
+    appended to the live ring entries, like drains) or before the
+    consumer reached them (copied with the batch's own phases)."""
+    from test_batcher import _traced_sig_batcher
+
+    broker = _unserved_broker()
+    batcher = _traced_sig_batcher(broker.tracer)
+
+    async def wait_for_probe():
+        if probe_first:
+            await batcher._probe_task
+
+    try:
+        batcher._device_rtt, batcher._rtt_samples = 10.0, 2
+        batcher._since_probe = batcher.BYPASS_PROBE_EVERY - 1
+        entries = await _publish_through(
+            broker, batcher, [f"tr/{i}/x" for i in range(6)],
+            before_spans=wait_for_probe)
+        await batcher._probe_task
+        shown = broker.tracer.report()["batches"]
+        assert [b.get("of") for b in shown] == [None, shown[0]["id"]]
+        for entry in entries:
+            (dev,) = _spans(entry, "match_device")
+            assert dev["batch"] == shown[0]["id"]
+            late = [s for s in entry["spans"] if s.get("shadow")]
+            assert {s["stage"] for s in late} >= {
+                "match_dispatch", "match_fetch", "device_rtt", "match_hop"}
+            assert all(s["batch"] == shown[1]["id"]
+                       and s["parent"] == "match_device" for s in late)
+            assert len(_spans(entry, "device_rtt")) == 1
+            (host,) = _spans(entry, "match_host")
+            assert "shadow" not in host
+        hist = broker.tracer.stage_hist
+        assert hist["device_rtt"].count == 6 == hist["match_hop"].count
+        assert batcher.device_round_trip > 0
+    finally:
+        await batcher.close()
+
+
+async def test_loop_lag_is_how_long_a_ready_callback_waited():
+    clock = _Clock()
+    broker = _unserved_broker()
+    loop = asyncio.get_running_loop()
+    # a callback ahead in the ready queue holds the loop for 50 ms
+    loop.call_soon(clock.advance, 50)
+    tr = _sampled_publish(broker, "t/x")._trace
+    await asyncio.sleep(0)
+    assert [(s, dur) for s, _t0, dur in tr.spans] == \
+        [("loop_lag", 50_000_000)]
+    broker.tracer.finish(tr)
+    # a publish that finished first gets it on its live ring entry
+    loop.call_soon(clock.advance, 20)
+    tr = _sampled_publish(broker, "t/y")._trace
+    broker.tracer.finish(tr)
+    assert not _spans(tr.entry, "loop_lag")
+    await asyncio.sleep(0)
+    (lag,) = _spans(tr.entry, "loop_lag")
+    assert lag["dur_us"] == 20_000 and lag["parent"] == ""
+    assert broker.tracer.stage_hist["loop_lag"].count == 2
+    assert "loop_lag" in STAGES and "loop_lag" not in CRITICAL_STAGES
+
+
+async def test_new_sites_cost_nothing_with_sampling_off(monkeypatch):
+    """ADR 015's cost contract at the sites ISSUE 25 added: with
+    sample_n = 0 no TraceAnnotation is built, no call_soon stamp is
+    scheduled, no batch record opened, no mark put on a future."""
+    from maxmq_tpu import trace
+    from maxmq_tpu.matching.supervisor import SupervisedMatcher
+    from test_batcher import _traced_sig_batcher
+
+    built = []
+
+    class Annotation:
+        def __init__(self, name, **stats):
+            built.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(trace, "_annotation", Annotation)
+    async with running_broker() as broker:          # tracing off
+        lags = []
+        broker._trace_loop_lag = lambda *a: lags.append(a)
+        batcher = _traced_sig_batcher(broker.tracer)
+        batcher.engine.index = broker.topics
+        batcher._device_rtt, batcher._rtt_samples = 10.0, 2
+        broker.attach_matcher(SupervisedMatcher(
+            batcher, index=broker.topics, deadline_ms=5000))
+        try:
+            sub = await connect(broker, "s1")
+            await sub.subscribe("t/#")
+            batcher.engine.refresh()
+            pub = await connect(broker, "p1")
+            for i in range(10):
+                await pub.publish(f"t/{i % 3}", b"m", qos=1)
+            await sub.next_message(timeout=3)
+            assert batcher.batches >= 1
+            assert built == [] and lags == []
+            assert broker.tracer.allocations == 0
+            assert broker.tracer.report()["batches"] == []
+            # the same traffic with sampling on builds them
+            broker.tracer.sample_n = 1
+            await pub.publish("t/9", b"m", qos=1)
+            await sub.next_message(timeout=3)
+            await poll(lambda: {"maxmq.read", "maxmq.deliver",
+                                "maxmq.settle", "maxmq.flush"}
+                       <= set(built), what="annotations")
+            await poll(lambda: lags, what="loop_lag stamp")
+            await pub.disconnect()
+            await sub.disconnect()
+        finally:
+            await batcher.close()
+
+
+async def test_annotated_closes_its_span_across_a_suspension(monkeypatch):
+    """A host span is scoped to a thread: annotated() leaves it open
+    only while the coroutine runs, never while it waits."""
+    from maxmq_tpu import trace
+
+    log = []
+
+    class Annotation:
+        def __init__(self, name, **stats):
+            self.name = name
+
+        def __enter__(self):
+            log.append("open")
+
+        def __exit__(self, *exc):
+            log.append("close")
+
+    monkeypatch.setattr(trace, "_annotation", Annotation)
+    gate = asyncio.get_running_loop().create_future()
+
+    async def work():
+        log.append("a")
+        got = await gate
+        log.append(got)
+        return "done"
+
+    task = asyncio.ensure_future(trace.annotated("maxmq.read", work()))
+    await asyncio.sleep(0)
+    assert log == ["open", "a", "close"]
+    gate.set_result("b")
+    assert await task == "done"
+    assert log == ["open", "a", "close", "open", "b", "close"]
+    # an exception thrown in at the suspension reaches the coroutine
+
+    async def waits():
+        try:
+            await asyncio.sleep(30)
+        except asyncio.CancelledError:
+            log.append("cancelled")
+            raise
+
+    del log[:]
+    task = asyncio.ensure_future(trace.annotated("maxmq.read", waits()))
+    await asyncio.sleep(0)
+    task.cancel()
+    with pytest.raises(asyncio.CancelledError):
+        await task
+    assert log == ["open", "close", "open", "cancelled", "close"]
+
+
+def _layer_files(reader):
+    root = os.path.join(os.path.dirname(__file__), os.pardir, "perfbench",
+                        "layers")
+    out = {}
+    for name in sorted(os.listdir(root)):
+        with open(os.path.join(root, name)) as fh:
+            layer = json.load(fh)
+        if layer["reader"] == reader:
+            out[name[:-len(".json")]] = layer
+    return out
+
+
+def test_every_ring_stage_the_benchmark_reads_is_a_stage():
+    """A renamed stage must fail here, not go absent from the ledger."""
+    layers = _layer_files("ring_stage_median")
+    assert len(layers) >= 13
+    for name, layer in layers.items():
+        assert layer["args"]["stage"] in STAGES, name
+
+
+ISSUE_25_METRICS = {
+    "loop_lag_ms.flood": "loop_lag",
+    "engine_host_answer_ms.flood": "match_host",
+    "engine_prep_us.flood": "match_prep",
+    "engine_probe_us.flood": "match_probe",
+    "engine_decode_us.flood": "match_decode",
+    "device_rtt_us.flood": "device_rtt",
+    "settle_hop_ms.flood": "match_hop",
+}
+
+
+async def test_the_benchmarks_reader_reads_each_new_metric_from_the_ring():
+    """The flood's regime in small: bypassed batches and one shadow
+    probe. perfbench's own reader then finds every metric ISSUE 25
+    added in the entries the tracer made, through the metric's file."""
+    import sys
+    from test_batcher import _traced_sig_batcher
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
+                                    "perfbench"))
+    try:
+        import readers
+    finally:
+        sys.path.pop(0)
+
+    broker = _unserved_broker()
+    batcher = _traced_sig_batcher(broker.tracer)
+    try:
+        batcher._device_rtt, batcher._rtt_samples = 10.0, 2
+        batcher._since_probe = batcher.BYPASS_PROBE_EVERY - 1
+        await _publish_through(broker, batcher,
+                               [f"tr/{i}/x" for i in range(8)])
+        await batcher._probe_task
+        await asyncio.sleep(0)
+    finally:
+        await batcher.close()
+    run = {"ring": broker.tracer.report()["entries"]}
+    layers = _layer_files("ring_stage_median")
+    for name, stage in ISSUE_25_METRICS.items():
+        layer = layers[name]
+        assert layer["args"]["stage"] == stage
+        value = readers.ring_stage_median(run, **layer["args"])
+        assert value is not None and value >= 0, name
+    # a ring of the parent's shape (no such span) reads as absent
+    old = {"ring": [{"spans": [{"stage": "match_device", "dur_us": 9}],
+                     "drains": []}]}
+    assert readers.ring_stage_median(old, stage="match_host") is None

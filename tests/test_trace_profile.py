@@ -1,0 +1,195 @@
+"""Host spans in the profiler's own trace (ADR 015 addendum): every test
+that opens a ``jax.profiler`` session lives here, behind ONE
+module-scoped capture on the CPU. A process holds one session at a
+time and tier-1 runs ``--dist loadfile``, so one file means one worker
+and one session.
+
+The capture is taken the way ``perfbench/run.py`` ``trace_slice`` takes
+it (``host_tracer_level = 1``, ``python_tracer_level = 0``) over a
+traced MicroBatcher on a real SigEngine: one bypassed batch whose inline
+host answer is made to block the loop for 50 ms, then one whole-batch
+device call on an executor thread.
+"""
+
+import asyncio
+import importlib.util
+import os
+import time
+import warnings
+
+import pytest
+
+from maxmq_tpu.trace import PipelineTracer
+
+ROOT = os.path.join(os.path.dirname(__file__), os.pardir)
+BLOCK_S = 0.05
+
+
+def _tool():
+    spec = importlib.util.spec_from_file_location(
+        "_trace_gaps", os.path.join(ROOT, "tools", "trace_gaps.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def tool():
+    return _tool()
+
+
+async def _traffic(inline, whole) -> dict:
+    from test_batcher import _one_batch
+
+    host = inline.engine.subscribers_host_batch
+
+    def blocking_host(topics):
+        time.sleep(BLOCK_S)         # the loop thread, held
+        return host(topics)
+
+    inline.engine.subscribers_host_batch = blocking_host
+    try:
+        inline._device_rtt, inline._rtt_samples = 10.0, 2
+        bypassed = await _one_batch(inline)
+        device = await _one_batch(whole, tag="w")
+    finally:
+        await inline.close()
+        await whole.close()
+    assert bypassed.via == "host" and device.via == "whole"
+    return {"bypassed": bypassed, "device": device}
+
+
+@pytest.fixture(scope="module")
+def capture(tmp_path_factory, tool):
+    import jax
+    trace_dir = str(tmp_path_factory.mktemp("profile"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    from test_batcher import _traced_sig_batcher
+    tracer = PipelineTracer(sample_n=1)
+    inline = _traced_sig_batcher(tracer)
+    whole = _traced_sig_batcher(tracer, pipeline_depth=1,
+                                cpu_bypass=False)
+    # the bucket's XLA compile stays out of the capture: a cold dispatch
+    # would outlast the scripted block
+    whole.engine.subscribers_fixed_batch([f"tr/{i}/w" for i in range(12)])
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        records = asyncio.run(_traffic(inline, whole))
+    finally:
+        jax.profiler.stop_trace()
+    path = tool.xplane.find(trace_dir)
+    assert path is not None, "the profiler wrote no trace"
+    data = tool.xplane.load(path)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", DeprecationWarning)
+        threads = tool.host_threads(data)
+    return {"path": path, "data": data, "threads": threads, **records}
+
+
+def _named(threads, name):
+    return [(k, ev) for k, events in enumerate(threads)
+            for ev in events if ev[0] == name]
+
+
+def test_batch_annotations_are_on_the_host_plane_with_their_stats(capture):
+    rec = capture["bypassed"]
+    ((line, (_name, lo, hi, stats)),) = _named(capture["threads"],
+                                               "maxmq.batch")
+    assert stats["batch"] == rec.id and stats["n"] == rec.n
+    assert stats["via"] == "host"
+    assert hi - lo >= BLOCK_S * 1e9
+    # t0_ns is the tracer's clock at the span's start: the ring's
+    # match_host starts there too
+    host = next(p for p in rec.phases if p[0] == "match_host")
+    assert stats["t0_ns"] == host[1]
+    # the bypass's phases nest inside it, on the same line, same batch
+    for phase in ("prep", "probe", "decode"):
+        ((k, (_n, p0, p1, pst)),) = [
+            hit for hit in _named(capture["threads"],
+                                  f"maxmq.batch.{phase}")
+            if hit[1][3]["batch"] == rec.id]
+        assert k == line and lo <= p0 <= p1 <= hi
+        assert "t0_ns" in pst
+    assert _named(capture["threads"], "maxmq.settle")
+
+
+def test_an_executor_batchs_phases_lie_on_another_line_than_the_loops(
+        capture):
+    rec = capture["device"]
+    loop_line = _named(capture["threads"], "maxmq.batch")[0][0]
+    lines = set()
+    for phase in ("prep", "dispatch", "fetch", "decode"):
+        hits = [hit for hit in _named(capture["threads"],
+                                      f"maxmq.batch.{phase}")
+                if hit[1][3]["batch"] == rec.id]
+        assert len(hits) == 1, phase
+        lines.add(hits[0][0])
+    assert len(lines) == 1 and loop_line not in lines
+    # a device batch has no enclosing annotation
+    assert all(ev[3]["batch"] != rec.id
+               for _k, ev in _named(capture["threads"], "maxmq.batch"))
+
+
+def test_one_offset_carries_the_tracers_clock_to_the_profilers(capture):
+    """Every annotation's t0_ns minus its start in the file is the same
+    offset, to well under the shortest span of interest."""
+    offsets = [ev[3]["t0_ns"] - ev[1] for events in capture["threads"]
+               for ev in events if "t0_ns" in ev[3]]
+    assert len(offsets) >= 8
+    assert max(offsets) - min(offsets) < 500_000        # 0.5 ms
+
+
+def test_trace_gaps_gives_the_inline_block_to_maxmq_batch(capture, tool):
+    out = tool.analyse(capture["data"])
+    assert out["device_planes"] == 0            # the CPU: all of it idle
+    assert out["idle_s"] == pytest.approx(out["slice_s"])
+    loop = out["groups"]["loop"]["names"]
+    assert BLOCK_S <= loop["maxmq.batch"] < BLOCK_S + 0.05
+    assert "maxmq.settle" in loop
+    # nested phases are not top-level on the loop's thread...
+    assert not [n for n in loop if n.startswith("maxmq.batch.")]
+    # ...and are on the executor's, which has no enclosing annotation
+    other = out["groups"]["other"]["names"]
+    assert {"maxmq.batch.prep", "maxmq.batch.dispatch",
+            "maxmq.batch.fetch", "maxmq.batch.decode"} <= set(other)
+    assert out["gaps"][0]["covered_by"] == "loop:maxmq.batch"
+    assert out["tracer_minus_profiler_clock_ns"] is not None
+    tool.show(out)                               # prints, does not raise
+
+
+def test_interval_arithmetic_on_made_up_events(tool):
+    """No profiler: the attribution on events written by hand."""
+    assert tool.merge([(5, 9), (0, 2), (1, 3), (9, 9)]) == [(0, 3), (5, 9)]
+    assert tool.complement([(2, 4), (6, 8)], 0, 10) == \
+        [(0, 2), (4, 6), (8, 10)]
+    assert tool.complement([], 3, 7) == [(3, 7)]
+    assert tool.complement([(0, 10)], 0, 10) == []
+    assert tool.overlap([(0, 4), (6, 9)], [(2, 7), (8, 20)]) == 2 + 1 + 1
+    # nested events are not top-level; siblings are
+    events = [("maxmq.batch", 10, 50), ("maxmq.batch.prep", 12, 20),
+              ("maxmq.settle", 50, 55), ("maxmq.read", 70, 90)]
+    assert tool.top_level(events) == [events[0], events[2], events[3]]
+    # device busy 20..30 and 60..70 of a slice 0..100
+    idle = tool.complement([(20, 30), (60, 70)], 0, 100)
+    loop = tool.top_level(events)
+    worker = [("maxmq.batch.fetch", 0, 25)]
+    got = tool.attribute(idle, tool.spans_by_name([loop]))
+    assert got["names"] == {"maxmq.batch": 30e-9, "maxmq.settle": 5e-9,
+                            "maxmq.read": 20e-9}
+    assert got["unannotated"] == pytest.approx((80 - 55) * 1e-9)
+    groups = {"loop": tool.spans_by_name([loop]),
+              "other": tool.spans_by_name([worker])}
+    assert tool.covering((0, 20), groups) == "other:maxmq.batch.fetch"
+    assert tool.covering((30, 60), groups) == "loop:maxmq.batch"
+    assert tool.covering((95, 100), groups) == "unannotated"
+    # a batch's dispatch and fetch pair up by id, whatever the thread
+    threads = [[("maxmq.batch.dispatch", 5, 6, {"batch": 3}),
+                ("maxmq.batch.fetch", 8, 9, {"batch": 3}),
+                ("maxmq.batch.dispatch", 20, 21, {"batch": 4})],
+               [("maxmq.batch.fetch", 30, 31, {"batch": 4}),
+                ("maxmq.read", 1, 2, {})]]
+    assert tool.round_trips(threads) == [(5, 9), (20, 31)]
+    assert tool.loop_thread(threads) == 1
+    assert tool.loop_thread([threads[0]]) is None
