@@ -788,6 +788,12 @@ class ClientRegistry:
     def get(self, client_id: str) -> Client | None:
         return self._clients.get(client_id)
 
+    def resolve(self, result) -> tuple[list, dict, int, int]:
+        """A match result against the sessions that exist, read now:
+        ``result.resolve`` (trie.SubscriberSet.resolve) on this
+        registry's dict."""
+        return result.resolve(self._clients)
+
     def add(self, client: Client) -> None:
         self._clients[client.id] = client
 

@@ -88,6 +88,14 @@ class OverloadState:
         self.copied_bytes = 0       # delivered bytes copied/subscriber
         self.writev_batches = 0     # transport.writelines burst flushes
         self.writev_buffers = 0     # buffers handed to writelines
+        # -- what the fan-out was handed, and what could be delivered --
+        # a match result resolves itself against the client registry
+        # (ADR 007): fanout_matched counts the plain entries + $share
+        # candidates the results held, fanout_resolved those whose
+        # client has a session. resolved / matched is the share of
+        # matcher output that was deliverable.
+        self.fanout_matched = 0
+        self.fanout_resolved = 0
 
     # -- byte accounting (called by every OutboundQueue put/get) -------
 

@@ -1426,44 +1426,42 @@ class Broker:
         subscriber delivery. The trie path calls it directly so a QoS0
         publish costs no extra coroutine hop.
 
-        ``subscribers`` is either a SubscriberSet or a DeliveryIntents
-        (ADR 007: the native decode's fan-out-ready form — iterable of
-        (cid, sub) with a ``shared`` dict and ``has_client``). Intents
-        skip the merged-dict materialization on the hot path; the hook
-        override path materializes the cheapest safe SubscriberSet form
-        via _select_subscribers' tiers."""
-        to_set = getattr(subscribers, "to_set", None)
+        ``subscribers`` is a SubscriberSet or a DeliveryIntents (ADR
+        007: the native decode's fan-out-ready form). Either resolves
+        itself against the client registry in one pass, so what is
+        walked here are the entries with a session, each already
+        paired with its Client, and the $share keys that can yield a
+        pick: a table of stored subscriptions costs O(recipients) a
+        publish, not O(matched). The hook override path first
+        materializes the cheapest safe SubscriberSet form via
+        _select_subscribers' tiers, and what the hooks return is what
+        gets resolved."""
         if self.hooks.overrides("on_select_subscribers"):
             # shared_only hooks (the worker-pool $share ownership
             # filter) only drop keys from the outer shared dict: on a
             # shared-free intents result they are identity, so the fast
             # path survives — pool deployments must not pay set
             # materialization on every publish
-            shared_only = to_set is not None and all(
+            shared_only = hasattr(subscribers, "to_set") and all(
                 getattr(h, "select_subscribers_shared_only", False)
                 for h in self.hooks._overriders("on_select_subscribers"))
             if not (shared_only and len(subscribers) == subscribers.n):
                 subscribers = self._select_subscribers(subscribers, packet)
-                to_set = None
-        if to_set is None:
-            shared = subscribers.shared
-            if shared:
-                plain = subscribers.subscriptions
-                self._fan_out_shared(shared, plain.__contains__, packet)
-            for cid, sub in subscribers.subscriptions.items():
-                self._publish_to_client(cid, sub, packet, shared=False)
-            return
-        # intents fast path: flat entries, no dict in sight
-        if len(subscribers) != subscribers.n:   # any shared candidates?
-            self._fan_out_shared(subscribers.shared,
-                                 subscribers.has_client, packet)
-        for cid, sub in subscribers:
-            self._publish_to_client(cid, sub, packet, shared=False)
+        pairs, shared, matched, resolved = self.clients.resolve(subscribers)
+        overload = self.overload
+        overload.fanout_matched += matched
+        overload.fanout_resolved += resolved
+        if shared:
+            self._fan_out_shared(shared, pairs, packet)
+        for client, sub in pairs:
+            self._publish_to_client(client, sub, packet, shared=False)
 
-    def _fan_out_shared(self, shared, has_plain, packet: Packet) -> None:
+    def _fan_out_shared(self, shared, pairs, packet: Packet) -> None:
         """$share: pick one member per (group, filter), merging per
-        client; a client already receiving a plain delivery is skipped
-        [MQTT-4.8.2-4]."""
+        client; a client already receiving a plain delivery (one of the
+        resolved ``pairs``) is skipped [MQTT-4.8.2-4]. ``shared`` holds
+        only keys with a registered candidate: a key without one picks
+        nobody and moves no cursor, so it was cut before this."""
         selected: dict[str, Subscription] = {}
         sessions = self._cluster_sessions()
         token = None
@@ -1475,6 +1473,7 @@ class Broker:
             # (pin mode never reads it: skip the payload hash)
             token = crc32(packet.payload,
                           crc32(packet.topic.encode()))
+        get = self.clients.get
         for (group, filt), candidates in shared.items():
             if sessions is not None and not sessions.owns_share(
                     group, filt, token):
@@ -1485,16 +1484,19 @@ class Broker:
                 continue
             pick = self.topics.select_shared(
                 group, filt, candidates,
-                alive=lambda cid: (c := self.clients.get(cid)) is not None
+                alive=lambda cid: (c := get(cid)) is not None
                 and not c.closed)
             if pick is not None:
                 cid, sub = pick
                 prev = selected.get(cid)
                 if prev is None or sub.qos > prev.qos:
                     selected[cid] = sub
+        if not selected:
+            return
+        plain = {client.id for client, _sub in pairs}
         for cid, sub in selected.items():
-            if not has_plain(cid):
-                self._publish_to_client(cid, sub, packet, shared=True)
+            if cid not in plain:
+                self._publish_to_client(get(cid), sub, packet, shared=True)
 
     async def _match_async(self, topic: str) -> SubscriberSet:
         async_fn = getattr(self.matcher, "subscribers_async", None)
@@ -1707,16 +1709,15 @@ class Broker:
             self._trace_drain(client, packet)
         return True
 
-    def _publish_to_client(self, client_id: str, sub: Subscription,
+    def _publish_to_client(self, client: Client, sub: Subscription,
                            packet: Packet, shared: bool) -> None:
-        """Parity: v2/server.go:795-868 (publishToClient)."""
-        client = self.clients.get(client_id)
-        if client is None:
-            return
-        if sub.no_local and packet.origin == client_id:
+        """Parity: v2/server.go:795-868 (publishToClient). ``client``
+        comes resolved: the fan-out looked the session up when it
+        walked the match result (_fan_out_local)."""
+        if sub.no_local and packet.origin == client.id:
             return  # v5 NoLocal [MQTT-3.8.3-3]
         skip = packet.__dict__.get("_content_skip")
-        if skip is not None and not shared and client_id in skip:
+        if skip is not None and not shared and client.id in skip:
             return  # ADR 023: every claim this client has on the topic
             #         is content-gated and none passed (shared picks
             #         are exempt: $share filters carry no options)

@@ -466,6 +466,5 @@ class ContentPlane:
                         topic=self.emit_topic(sub), payload=payload,
                         origin="$aggregate", created=time.time())
         packet._content_skip = frozenset()
-        broker._publish_to_client(sub.client_id, s, packet,
-                                  shared=False)
+        broker._publish_to_client(client, s, packet, shared=False)
         self.agg_emitted += 1

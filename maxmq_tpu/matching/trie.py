@@ -159,6 +159,33 @@ class SubscriberSet:
     def __len__(self) -> int:
         return len(self.subscriptions) + sum(len(g) for g in self.shared.values())
 
+    def resolve(self, registry: dict) -> tuple[list, dict, int, int]:
+        """This result against the client registry's dict, in one pass
+        (ADR 007): ``(pairs, shared, matched, resolved)``.
+
+        ``pairs`` are the plain entries whose client id is a key of
+        ``registry``, in iteration order, each already paired with the
+        registry's value: ``(client, sub)``. ``shared`` is the $share
+        map cut to the (group, filter) keys with at least one
+        registered candidate; the member maps are this result's own,
+        whole, because the round-robin cursor indexes the sorted full
+        candidate set. ``matched`` counts plain entries + shared
+        candidates held, ``resolved`` those with a session. Nothing is
+        written onto the result: results are cached and shared."""
+        get = registry.get
+        pairs = [(client, sub) for cid, sub in self.subscriptions.items()
+                 if (client := get(cid)) is not None]
+        shared: dict = {}
+        matched = len(self.subscriptions)
+        resolved = len(pairs)
+        for key, members in self.shared.items():
+            matched += len(members)
+            hits = sum(map(registry.__contains__, members))
+            if hits:
+                resolved += hits
+                shared[key] = members
+        return pairs, shared, matched, resolved
+
 
 _PySubscriberSet = SubscriberSet
 try:

@@ -940,6 +940,17 @@ def _register_fanout_metrics(registry: Registry, broker) -> None:
              "Wire buffers carried by those writelines batches")):
         registry.counter_func(f"maxmq_broker_fanout_{name}_total",
                               help_, lambda n=name: getattr(over, n))
+    for name, help_ in (
+            ("fanout_matched",
+             "Plain entries + $share candidates held by the match "
+             "results handed to the fan-out"),
+            ("fanout_resolved",
+             "Those of them whose client has a session: what the "
+             "fan-out walks once a result is resolved against the "
+             "client registry (resolved / matched = the deliverable "
+             "share of matcher output)")):
+        registry.counter_func(f"maxmq_broker_{name}_total", help_,
+                              lambda n=name: getattr(over, n))
     sched = getattr(broker, "flush_sched", None)
     if sched is not None:
         for name, help_ in (

@@ -288,7 +288,7 @@ class ChainedIntents:
     live on exactly one shard, so the chained iteration can never name a
     client twice and no cross-shard per-client merge exists to do.
     Duck-types the ADR-007 consumer surface (__iter__/n/__len__/shared/
-    has_client/to_set); shared-group candidate maps MAY span shards (a
+    resolve/to_set); shared-group candidate maps MAY span shards (a
     group's members hash apart), so ``shared`` is a lazy outer-merged
     view. Immutable, like every cached match result."""
 
@@ -328,8 +328,23 @@ class ChainedIntents:
             self._shared = merged
         return self._shared
 
-    def has_client(self, cid: str) -> bool:
-        return any(p.has_client(cid) for p in self.parts)
+    def resolve(self, registry: dict) -> tuple[list, dict, int, int]:
+        """``SubscriberSet.resolve`` over the chained parts: each shard
+        resolves its own entries; a $share key that survives on any
+        shard keeps the MERGED member map (the rotation indexes the
+        group's full candidate set, which may span shards)."""
+        pairs: list = []
+        keys: set = set()
+        matched = resolved = 0
+        for p in self.parts:
+            pp, cut, m, r = p.resolve(registry)
+            pairs += pp
+            keys.update(cut)
+            matched += m
+            resolved += r
+        shared = ({k: v for k, v in self.shared.items() if k in keys}
+                  if keys else {})
+        return pairs, shared, matched, resolved
 
     def to_set(self) -> SubscriberSet:
         if self._set is None:

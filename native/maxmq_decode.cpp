@@ -52,8 +52,10 @@
 // low temporal locality — each slot is touched once per union
 #if defined(__GNUC__) || defined(__clang__)
 #define PREFETCH_W(p) __builtin_prefetch((p), 1, 1)
+#define PREFETCH_R(p) __builtin_prefetch((p), 0, 1)
 #else
 #define PREFETCH_W(p) ((void)0)
+#define PREFETCH_R(p) ((void)0)
 #endif
 #include <vector>
 
@@ -316,6 +318,101 @@ PyObject *subset_repr(PyObject *self_o) {
                               self->subscriptions, self->shared);
 }
 
+// ----------------------------------------------------------------- //
+//  resolve(registry) — a result against the client registry         //
+// ----------------------------------------------------------------- //
+//
+// The fan-out delivers only to clients that have a session, and on a
+// large table nearly every matched entry has none. So a result resolves
+// itself against the registry's dict in one pass (the contract is
+// SubscriberSet.resolve's, matching/trie.py; ADR 007):
+//
+//   resolve(registry) -> (pairs, shared, matched, resolved)
+//
+// pairs are the (client, sub) of the plain entries whose id is a key of
+// the registry, in iteration order; shared is the $share map cut to the
+// keys with a registered candidate, member maps aliased whole. Nothing
+// is written onto the result: it is cached and shared.
+
+struct Resolve {
+  PyObject *reg;      // borrowed: the registry dict
+  PyObject *pairs;    // owned until resolve_finish
+  Py_ssize_t matched;
+  Py_ssize_t resolved;
+};
+
+bool resolve_begin(Resolve *r, PyObject *reg) {
+  if (!PyDict_Check(reg)) {
+    PyErr_SetString(PyExc_TypeError, "resolve() takes the registry dict");
+    return false;
+  }
+  *r = {reg, PyList_New(0), 0, 0};
+  return r->pairs != nullptr;
+}
+
+// one plain entry: a registered client's (client, sub) joins the list
+static inline int resolve_entry(Resolve *r, PyObject *cid, PyObject *sub) {
+  PyObject *client = PyDict_GetItemWithError(r->reg, cid);  // borrowed
+  if (!client) return PyErr_Occurred() ? -1 : 0;
+  PyObject *pair = PyTuple_Pack(2, client, sub);
+  if (!pair) return -1;
+  const int rc = PyList_Append(r->pairs, pair);
+  Py_DECREF(pair);
+  return rc;
+}
+
+// the $share half: NEW reference to the cut map (always a dict)
+PyObject *resolve_shared(Resolve *r, PyObject *shared) {
+  PyObject *cut = PyDict_New();
+  if (!cut || !shared) return cut;
+  PyObject *key, *members, *cid, *sub;
+  Py_ssize_t pos = 0;
+  while (PyDict_Next(shared, &pos, &key, &members)) {
+    Py_ssize_t mpos = 0, hits = 0;
+    while (PyDict_Next(members, &mpos, &cid, &sub)) {
+      if (PyDict_GetItemWithError(r->reg, cid))
+        hits++;
+      else if (PyErr_Occurred()) {
+        Py_DECREF(cut);
+        return nullptr;
+      }
+    }
+    r->matched += PyDict_GET_SIZE(members);
+    r->resolved += hits;
+    if (hits && PyDict_SetItem(cut, key, members) < 0) {
+      Py_DECREF(cut);
+      return nullptr;
+    }
+  }
+  return cut;
+}
+
+// ``rc`` is the plain walk's outcome, ``plain`` its entry count; the
+// pairs list is consumed either way
+PyObject *resolve_finish(Resolve *r, int rc, Py_ssize_t plain,
+                         PyObject *shared) {
+  PyObject *cut = rc < 0 ? nullptr : resolve_shared(r, shared);
+  if (!cut) {
+    Py_DECREF(r->pairs);
+    return nullptr;
+  }
+  return Py_BuildValue("(NNnn)", r->pairs, cut, r->matched + plain,
+                       r->resolved + PyList_GET_SIZE(r->pairs));
+}
+
+PyObject *subset_resolve(PyObject *self_o, PyObject *reg) {
+  auto *self = reinterpret_cast<SubSetObject *>(self_o);
+  Resolve r;
+  if (!resolve_begin(&r, reg)) return nullptr;
+  PyObject *cid, *sub;
+  Py_ssize_t pos = 0;
+  int rc = 0;
+  while (rc == 0 && PyDict_Next(self->subscriptions, &pos, &cid, &sub))
+    rc = resolve_entry(&r, cid, sub);
+  return resolve_finish(&r, rc, PyDict_GET_SIZE(self->subscriptions),
+                        self->shared);
+}
+
 PyMemberDef subset_members[] = {
     {"subscriptions", Py_T_OBJECT_EX, offsetof(SubSetObject, subscriptions),
      0, "client_id -> merged Subscription"},
@@ -332,6 +429,8 @@ PyMethodDef subset_methods[] = {
      "Subscription-deep copy for hooks that may mutate."},
     {"select_copy", subset_select_copy, METH_NOARGS,
      "Fresh outer dicts over aliased records (hook modify-chain form)."},
+    {"resolve", subset_resolve, METH_O,
+     "(pairs, shared, matched, resolved) against the registry dict."},
     {nullptr, nullptr, 0, nullptr}};
 
 PyType_Slot subset_slots[] = {
@@ -648,24 +747,49 @@ PyObject *intents_select_set(PyObject *self_o, PyObject *) {
   return reinterpret_cast<PyObject *>(res);
 }
 
-// has_client(cid) -> bool; linear scan (used only by the rare $share
-// overlap check, on sets of a few hundred entries at most)
-PyObject *intents_has_client(PyObject *self_o, PyObject *cid) {
+// One flat run of a resolve walk; ``sub_at(i)`` is entry i's record.
+// The probe reads each id's type and cached hash out of its str, and
+// the ids of a fat row lie all over a heap of a million objects: a DRAM
+// miss an entry, which is all the walk costs. Asking for the str
+// kResolveAhead entries early overlaps the misses (the flooded
+// 1M-filter cell: a publish that delivers nothing 38 -> 20 us of
+// fan-out, PERF.md section 6, PR 26).
+constexpr Py_ssize_t kResolveAhead = 12;
+
+template <class SubAt>
+static inline int resolve_run(Resolve *r, PyObject *const *cids,
+                              Py_ssize_t n, SubAt sub_at) {
+  for (Py_ssize_t i = 0; i < n && i < kResolveAhead; i++)
+    PREFETCH_R(cids[i]);
+  int rc = 0;
+  for (Py_ssize_t i = 0; rc == 0 && i < n; i++) {
+    if (i + kResolveAhead < n) PREFETCH_R(cids[i + kResolveAhead]);
+    rc = resolve_entry(r, cids[i], sub_at(i));
+  }
+  return rc;
+}
+
+// resolve(registry): the walk of intents_iternext (own tail, then the
+// bases with their slot overrides) testing membership only — no tuple
+// and no frame for an entry without a session
+PyObject *intents_resolve(PyObject *self_o, PyObject *reg) {
   auto *self = reinterpret_cast<IntentsObject *>(self_o);
-  auto scan = [&](const IntentsObject *part) -> int {
-    for (Py_ssize_t i = 0; i < part->n; i++) {
-      if (part->cids[i] == cid) return 1;
-      const int eq =
-          PyObject_RichCompareBool(part->cids[i], cid, Py_EQ);
-      if (eq != 0) return eq;   // hit or error
-    }
-    return 0;
-  };
-  int r = scan(self);
-  for (int32_t b = 0; r == 0 && b < self->n_bases; b++)
-    r = scan(self->bases[b]);
-  if (r < 0) return nullptr;
-  return PyBool_FromLong(r);
+  Resolve r;
+  if (!resolve_begin(&r, reg)) return nullptr;
+  int rc = resolve_run(&r, self->cids, self->n,
+                       [&](Py_ssize_t i) { return self->subs[i]; });
+  Py_ssize_t oi = 0;  // cursor into ovr_slots: global slots ascend
+  for (int32_t b = 0; rc == 0 && b < self->n_bases; b++) {
+    const IntentsObject *bb = self->bases[b];
+    const int32_t off = self->base_off[b];
+    rc = resolve_run(&r, bb->cids, bb->n, [&](Py_ssize_t j) {
+      while (oi < self->n_ovr && self->ovr_slots[oi] < off + j) oi++;
+      return (oi < self->n_ovr && self->ovr_slots[oi] == off + j)
+                 ? self->ovr_subs[oi]
+                 : bb->subs[j];
+    });
+  }
+  return resolve_finish(&r, rc, intents_total(self), self->shared);
 }
 
 PyObject *intents_get_shared(PyObject *self_o, void *) {
@@ -759,8 +883,8 @@ PyMethodDef intents_methods[] = {
      "Materialize (and cache) the SubscriberSet twin for hook paths."},
     {"select_set", intents_select_set, METH_NOARGS,
      "Fresh hook-ready SubscriberSet (new dicts, aliased records)."},
-    {"has_client", intents_has_client, METH_O,
-     "True when the client id has a plain (non-shared) delivery entry."},
+    {"resolve", intents_resolve, METH_O,
+     "(pairs, shared, matched, resolved) against the registry dict."},
     {nullptr, nullptr, 0, nullptr}};
 
 PyGetSetDef intents_getset[] = {

@@ -9,6 +9,8 @@ import asyncio
 import importlib.util
 import inspect
 import os
+import subprocess
+import sys
 
 import pytest
 
@@ -30,6 +32,32 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_compilation_cache", False)
+
+
+def pytest_configure(config):
+    """Build the native libraries once, here on the controller, before
+    any xdist worker starts. ``native/*.so`` is git-ignored and
+    ``maxmq_tpu/native.py`` builds on first use only when a library is
+    absent, with ``make`` linking straight into its target: on a fresh
+    checkout six workers each started a build, one could load a
+    half-written ``maxmq_decode.so``, mark the extension absent for the
+    life of its process and fail every intents test it was handed. The
+    ``make`` also rebuilds a library older than its source, which the
+    on-demand build never does. No compiler: the tests that need the
+    extension skip, as before."""
+    if hasattr(config, "workerinput"):
+        return          # an xdist worker: the controller has built
+    native = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "native")
+    try:
+        done = subprocess.run(["make", "-C", native, "-s"], timeout=300,
+                              capture_output=True, text=True)
+    except (OSError, subprocess.SubprocessError) as exc:
+        print(f"conftest: native build not run: {exc!r}", file=sys.stderr)
+        return
+    if done.returncode:
+        print(f"conftest: native build failed:\n{done.stderr[-2000:]}",
+              file=sys.stderr)
 
 
 if not _HAVE_PYTEST_TIMEOUT:

@@ -349,7 +349,7 @@ def test_sharded_intents_parity(seed):
     """Cluster-mode ADR 007: chained per-shard DeliveryIntents must
     match the CPU trie exactly (client-hash sharding makes the chain
     merge-free), including $share groups spanning shards and the
-    to_set()/has_client surface."""
+    to_set()/resolve surface."""
     from test_nfa_parity import normalize
 
     from maxmq_tpu.native import decode_module
@@ -378,8 +378,8 @@ def test_sharded_intents_parity(seed):
             by_iter = {cid: sub for cid, sub in r}
             assert len(by_iter) == r.n, f"client chained twice: {topic}"
             assert set(by_iter) == set(want.subscriptions), topic
-            for cid in by_iter:
-                assert r.has_client(cid)
+            for cid, sub in by_iter.items():
+                assert r.resolve({cid: cid})[0] == [(cid, sub)]
             s = r.to_set()
             assert normalize(s) == normalize(want), topic
         else:
@@ -387,6 +387,50 @@ def test_sharded_intents_parity(seed):
             s = to_set() if to_set is not None else r
             assert normalize(s) == normalize(want), topic
     assert saw_chained, "chained intents path never engaged"
+
+
+@pytest.mark.parametrize("registry", ["empty", "all", "third"])
+def test_sharded_intents_resolve(registry):
+    """ChainedIntents.resolve (ADR 007): the per-shard passes chained,
+    with a surviving $share key keeping the member map merged across
+    shards (the rotation indexes the group's whole candidate set)."""
+    from maxmq_tpu.native import decode_module
+    if decode_module() is None:
+        pytest.skip("maxmq_decode extension unavailable")
+    from maxmq_tpu.parallel.sharded import ChainedIntents, ShardedSigEngine
+
+    idx = TopicIndex()
+    for i in range(60):
+        idx.subscribe(f"cl{i}", Subscription(filter="rs/#", qos=i % 3))
+    for i in range(0, 60, 4):
+        idx.subscribe(f"cl{i}", Subscription(filter="$share/g/rs/+",
+                                             qos=1))
+        idx.subscribe(f"sh{i}", Subscription(filter="$share/h/rs/a",
+                                             qos=1))
+    eng = ShardedSigEngine(idx, mesh=make_mesh())
+    eng.emit_intents = True
+    results = [r for r in eng.subscribers_batch(["rs/a", "rs/b", "no/x"])
+               if isinstance(r, ChainedIntents)]
+    assert results, "chained intents path never engaged"
+    rng = random.Random(registry)
+    cids = [f"cl{i}" for i in range(60)] + [f"sh{i}"
+                                            for i in range(0, 60, 4)]
+    keep = {"empty": [], "all": cids,
+            "third": rng.sample(cids, len(cids) // 3)}[registry]
+    reg = {cid: object() for cid in keep}
+    for r in results:
+        pairs, shared, matched, resolved = r.resolve(reg)
+        assert pairs == [(reg[cid], sub) for cid, sub in r if cid in reg]
+        want = {k: m for k, m in r.shared.items()
+                if any(cid in reg for cid in m)}
+        assert shared == want and list(shared) == list(want)
+        assert matched == len(r)
+        assert resolved == len(pairs) + sum(
+            cid in reg for m in r.shared.values() for cid in m)
+    spans = [k for r in results for k, m in r.shared.items()
+             if not any(len(m) == len(p.shared.get(k, ()))
+                        for p in r.parts)]
+    assert spans, "no $share group spanned shards"
 
 
 async def test_sharded_intents_broker_delivery():

@@ -664,8 +664,8 @@ def test_intents_parity_randomized(seed):
                 assert sub.qos == w.qos, (topic, cid)
                 assert dict(sub.identifiers) == dict(w.identifiers), \
                     (topic, cid)
-                assert result.has_client(cid)
-            assert not result.has_client("no-such-client")
+                assert result.resolve({cid: cid})[0] == [(cid, sub)]
+            assert result.resolve({"no-such-client": 0})[0] == []
             assert len(result) == len(want.subscriptions) + sum(
                 len(m) for m in want.shared.values())
         assert normalize(_as_set(result)) == normalize(want), topic
@@ -698,7 +698,8 @@ def test_intents_empty_and_shared_surface():
     got = eng.collect_fixed(t, eng.dispatch_fixed(t))
     r, empty = got
     assert ("g", "$share/g/sh/+") in r.shared
-    assert r.has_client("p1") and not r.has_client("s1")
+    # a $share member is a candidate, never a plain entry
+    assert [c for c, _s in r.resolve({"p1": "p1", "s1": "s1"})[0]] == ["p1"]
     assert len(empty) == 0 and list(empty) == []
     assert empty.shared == {}
 
@@ -724,7 +725,7 @@ def test_intents_chained_base_parity():
     """Fat-row topics build CHAINED intents (immutable single-row base +
     per-topic tail with slot overrides) — the cold-stream wall killer.
     Every consumer surface must agree with the trie: iteration (dedup,
-    merged qos/identifiers), n, len, has_client, to_set, $share maps."""
+    merged qos/identifiers), n, len, resolve, to_set, $share maps."""
     _native_mod()
     idx = TopicIndex()
     # fat '#' bucket well past g_chain_min_base (default 64,
@@ -764,8 +765,8 @@ def test_intents_chained_base_parity():
             assert sub.qos == w.qos, (topic, cid)
             assert dict(sub.identifiers) == dict(w.identifiers), \
                 (topic, cid)
-            assert r.has_client(cid)
-        assert not r.has_client("no-such-client")
+            assert r.resolve({cid: cid})[0] == [(cid, sub)]
+        assert r.resolve({"no-such-client": 0})[0] == []
         assert len(r) == len(want.subscriptions) + sum(
             len(m) for m in want.shared.values()), topic
         assert normalize(r.to_set()) == normalize(want), topic
@@ -1106,6 +1107,192 @@ def test_intents_multi_base_composition():
         mod._set_chain_params(*saved)
     assert multi == plain
     assert single == plain
+
+
+# --------------------------------------------------------------------
+# resolve(registry): a match result against the client registry in one
+# pass (ADR 007) — the fan-out walks only entries with a session
+# --------------------------------------------------------------------
+
+
+class _Session:
+    """Stands for a Client: resolve pairs an entry with whatever value
+    the registry dict holds."""
+
+    def __init__(self, cid: str) -> None:
+        self.id = cid
+
+
+def _fuzz_index(rng, overlap: bool) -> TopicIndex:
+    """The corpus shape of test_intents_chain_fuzz_equivalence: fat '#'
+    buckets (the first the fattest, so it anchors a single-base chain),
+    thin filters, v5 identifiers and $share groups. With ``overlap``
+    half the thin filters belong to clients of the fattest bucket:
+    their merged records become slot overrides of the chain."""
+    idx = TopicIndex()
+    for b in range(rng.randint(2, 3)):
+        root = ["fz", "fz/x", "deep/fz"][b]
+        for i in range(140 if b == 0 else rng.randint(70, 120)):
+            idx.subscribe(f"b{b}c{i}", Subscription(
+                filter=f"{root}/#", qos=rng.randint(0, 2)))
+    for i in range(40):
+        cid = (f"b0c{rng.randrange(70)}" if overlap and i % 2
+               else f"s{i}")
+        f = rng.choice(["fz/+", "fz/x/+", "fz/x/a", f"fz/t{i}",
+                        "deep/fz/+/q", "$share/g/fz/#",
+                        "$share/h/fz/x/+", "fz/x/a/b"])
+        idx.subscribe(cid, Subscription(
+            filter=f, qos=rng.randint(0, 2),
+            identifier=rng.randint(0, 6)))
+    return idx
+
+
+_FUZZ_TOPICS = ["fz/x/a", "fz/x/a/b", "fz/q", "fz/x/zz", "fz/t3",
+                "deep/fz/m/q", "fz/x/a/b/c", "none/x"]
+
+
+def _resolve_results(shape: str, rng) -> list:
+    """Match results of one shape over the fuzz corpus. The chain
+    toggles are restored before returning: a built result keeps its
+    form."""
+    from maxmq_tpu.matching.trie import _PySubscriberSet
+    idx = _fuzz_index(rng, overlap=shape != "chained_intents")
+    if shape == "trie_set":
+        out = []
+        for t in _FUZZ_TOPICS:
+            r = idx.subscribers(t)
+            out.append(_PySubscriberSet(dict(r.subscriptions),
+                                        dict(r.shared)))
+        return out
+    mod = _native_mod()
+    if shape == "native_set":
+        out = [idx.subscribers(t) for t in _FUZZ_TOPICS]
+        assert all(type(r) is mod.SubscriberSet for r in out)
+        return out
+    eng = _intents_engine(idx)
+    eng.route_small = False
+    saved = _saved_chain_params(mod)
+    try:
+        if shape == "plain_intents":
+            mod._set_chain_enabled(False)
+        else:
+            mod._set_chain_params(32, 4, 1)
+            mod._set_multi_base(shape == "multi_base_intents")
+        got = eng.collect_fixed(_FUZZ_TOPICS,
+                                eng.dispatch_fixed(_FUZZ_TOPICS))
+    finally:
+        mod._set_chain_enabled(True)
+        mod._set_multi_base(True)
+        mod._set_chain_params(*saved)
+    assert all(isinstance(r, mod.DeliveryIntents) for r in got)
+    reprs = [repr(r) for r in got]
+    if shape == "plain_intents":
+        assert not any(r.chained for r in got)
+    elif shape == "chained_intents":
+        assert any("bases=1," in x for x in reprs), reprs
+    elif shape == "multi_base_intents":
+        assert any("bases=2," in x or "bases=3," in x for x in reprs), reprs
+    else:
+        assert shape == "override_intents"
+        assert any(r.chained and "overrides=0" not in x
+                   for r, x in zip(got, reprs)), reprs
+    return got
+
+
+def _entries(result) -> list:
+    if hasattr(result, "to_set"):
+        return list(result)
+    return list(result.subscriptions.items())
+
+
+def _registry_for(kind: str, results, rng) -> dict:
+    cids = sorted({cid for r in results for cid, _s in _entries(r)}
+                  | {cid for r in results for m in r.shared.values()
+                     for cid in m})
+    if kind == "empty":
+        keep = []
+    elif kind == "all":
+        keep = cids
+    elif kind == "one_percent":
+        keep = rng.sample(cids, max(1, len(cids) // 100))
+    else:
+        assert kind == "half"
+        keep = rng.sample(cids, len(cids) // 2)
+    # sessions the table does not know must be harmless too
+    return {cid: _Session(cid) for cid in keep + ["not-in-the-table"]}
+
+
+def _check_resolved(result, reg: dict) -> None:
+    entries = _entries(result)
+    before = [(cid, id(sub)) for cid, sub in entries]
+    pairs, shared, matched, resolved = result.resolve(reg)
+    want = [(reg[cid], sub) for cid, sub in entries if cid in reg]
+    assert len(pairs) == len(want)
+    for (gc, gs), (wc, ws) in zip(pairs, want):
+        assert gc is wc and gs is ws
+    want_shared = {k: m for k, m in result.shared.items()
+                   if any(cid in reg for cid in m)}
+    assert list(shared) == list(want_shared)        # order too
+    for k, m in shared.items():
+        assert m is result.shared[k]        # the member map, whole
+    assert matched == len(result)
+    assert resolved == len(want) + sum(
+        cid in reg for m in result.shared.values() for cid in m)
+    # nothing was written onto the (shared, cached) result
+    assert [(cid, id(sub)) for cid, sub in _entries(result)] == before
+
+
+@pytest.mark.parametrize("registry",
+                         ["empty", "all", "one_percent", "half"])
+@pytest.mark.parametrize("shape", [
+    "plain_intents", "chained_intents", "multi_base_intents",
+    "override_intents", "native_set", "trie_set"])
+def test_resolve_parity(shape, registry):
+    """For every result shape and registry, the resolved pairs are the
+    entries with a session, in iteration order, each paired with the
+    registry's value, and the cut $share map is the keys with a
+    registered candidate."""
+    rng = random.Random(f"{shape}/{registry}")
+    results = _resolve_results(shape, rng)
+    reg = _registry_for(registry, results, rng)
+    for result in results:
+        _check_resolved(result, reg)
+    held = sum(len(r) for r in results)
+    assert held > 300, "the corpus no longer exercises a fat result"
+    if registry == "empty":
+        assert all(r.resolve(reg)[:2] == ([], {}) for r in results)
+
+
+@pytest.mark.parametrize("shape", ["chained_intents", "native_set"])
+def test_resolve_caches_nothing_on_shared_results(shape):
+    """A cached result resolved against two registries gives two
+    answers, and the first again afterwards: liveness is read from the
+    registry it is shown, never remembered on the object."""
+    rng = random.Random(7)
+    results = _resolve_results(shape, rng)
+    fat = max(results, key=len)
+    reg_a = _registry_for("half", results, rng)
+    reg_b = _registry_for("half", results, rng)
+    assert set(reg_a) != set(reg_b)
+    first = fat.resolve(reg_a)
+    _check_resolved(fat, reg_b)
+    second = fat.resolve(reg_b)
+    assert [c.id for c, _s in first[0]] != [c.id for c, _s in second[0]]
+    again = fat.resolve(reg_a)
+    assert [(c, id(s)) for c, s in again[0]] == \
+        [(c, id(s)) for c, s in first[0]]
+    assert again[1:] == first[1:]
+    del reg_a["not-in-the-table"]           # the registry is read live
+    assert fat.resolve(reg_a)[2:] == first[2:]
+    gone = first[0][0][0].id
+    del reg_a[gone]
+    assert gone not in [c.id for c, _s in fat.resolve(reg_a)[0]]
+
+
+def test_resolve_rejects_a_non_dict_registry():
+    mod = _native_mod()
+    with pytest.raises(TypeError):
+        mod.SubscriberSet().resolve(["c1"])
 
 
 # --------------------------------------------------------------------

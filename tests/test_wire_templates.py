@@ -96,7 +96,7 @@ async def _deliver(broker, cl, sub, packet, expect: str) -> bytes:
     rec: list = []
     cl.outbound.put_nowait = lambda item, size=0: rec.append((item, size))
     try:
-        broker._publish_to_client(cl.id, sub, packet, shared=False)
+        broker._publish_to_client(cl, sub, packet, shared=False)
     finally:
         del cl.outbound.put_nowait
     assert len(rec) == 1, f"expected one delivery, saw {len(rec)}"
