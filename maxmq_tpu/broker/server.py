@@ -988,6 +988,11 @@ class Broker:
             raise ProtocolError(codes.ErrReceiveMaximumExceeded)
         return True
 
+    @property
+    def match_cache(self) -> VersionedTopicCache:
+        """The trie-path match cache, for the metrics bridge to read."""
+        return self._match_cache
+
     def _match_cached(self, topic: str) -> SubscriberSet:
         # safe even with on_select_subscribers hooks installed:
         # _select_subscribers hands hooks fresh dicts (records aliased

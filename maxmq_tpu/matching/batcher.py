@@ -161,6 +161,14 @@ class MicroBatcher:
     def index(self):
         return self.engine.index
 
+    @property
+    def topic_cache_evictions(self) -> int:
+        return self._cache.evictions
+
+    @property
+    def topic_cache_size(self) -> int:
+        return len(self._cache)
+
     # ------------------------------------------------------------------
 
     def enqueue(self, topic: str) -> asyncio.Future:

@@ -34,13 +34,16 @@ class VersionedTopicCache:
     invalidates every entry. Shared by the broker's trie-path match
     cache and the MicroBatcher's matcher-mode cache — cached results
     are SHARED objects; consumers must treat them as immutable and
-    deep_copy before mutating."""
+    deep_copy before mutating. ``evictions`` counts the entries a full
+    cache dropped to take a new topic: a topic set larger than
+    ``maxsize`` shows there, not in the hit count alone."""
 
-    __slots__ = ("_cache", "maxsize")
+    __slots__ = ("_cache", "maxsize", "evictions")
 
     def __init__(self, maxsize: int = 8192) -> None:
         self._cache: dict[str, tuple[int, object]] = {}
         self.maxsize = maxsize
+        self.evictions = 0
 
     def get(self, topic: str, version: int):
         hit = self._cache.get(topic)
@@ -52,6 +55,7 @@ class VersionedTopicCache:
         cache = self._cache
         if topic not in cache and len(cache) >= self.maxsize:
             cache.pop(next(iter(cache)))
+            self.evictions += 1
         cache[topic] = (version, result)
 
     def __len__(self) -> int:

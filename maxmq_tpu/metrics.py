@@ -1008,6 +1008,16 @@ def _register_filter_metrics(registry: Registry, broker) -> None:
 
 
 def _register_matcher_metrics(registry: Registry, broker) -> None:
+    cache = broker.match_cache
+    registry.counter_func(
+        "maxmq_broker_match_cache_evictions_total",
+        "Entries the broker's full trie-path match cache dropped to "
+        "take a new topic",
+        lambda: cache.evictions)
+    registry.gauge_func(
+        "maxmq_broker_match_cache_size",
+        "Topics the broker's trie-path match cache holds",
+        lambda: len(cache))
     matcher = getattr(broker, "matcher", None)
     if matcher is not None and hasattr(matcher, "matches"):
         registry.counter_func(
@@ -1031,6 +1041,17 @@ def _register_matcher_metrics(registry: Registry, broker) -> None:
                 "maxmq_matcher_cache_hits_total",
                 "Matches served from the version-keyed topic cache",
                 lambda: matcher.cache_hits)
+        if hasattr(matcher, "topic_cache_evictions"):
+            registry.counter_func(
+                "maxmq_matcher_topic_cache_evictions_total",
+                "Entries the batcher's full topic cache dropped to take "
+                "a new topic (a live topic set larger than the cache)",
+                lambda: matcher.topic_cache_evictions)
+            registry.gauge_func(
+                "maxmq_matcher_topic_cache_size",
+                "Topics the batcher's topic cache holds (stale entries "
+                "of older table versions included)",
+                lambda: matcher.topic_cache_size)
         if hasattr(matcher, "bypasses"):
             registry.counter_func(
                 "maxmq_matcher_bypassed_topics_total",
