@@ -28,6 +28,7 @@ import time
 from typing import TYPE_CHECKING
 
 from ..trace import host_span
+from .supervisor import fail_batch
 from .trie import VersionedTopicCache, subs_version
 
 if TYPE_CHECKING:
@@ -106,6 +107,10 @@ class MicroBatcher:
         self.bypasses = 0                 # topics served by the bypass
         self.errors = 0                   # batches whose engine call
                                           # raised (ADR 011 observability)
+        # ADR 011: callable(batch, exc) told of a batch whose answer
+        # raised, once, before its futures are failed; the supervisor
+        # sets it and answers them from the CPU trie instead
+        self.on_batch_failed = None
         # ADR 015: when the broker's PipelineTracer is attached (see
         # bootstrap.build_matcher) and sampling is on, match futures
         # are stamped with dispatch/done clock marks so the tracer can
@@ -219,6 +224,12 @@ class MicroBatcher:
                 if done_ns:
                     fut._t_done = done_ns
                 fut.set_result(result)
+
+    def _fail(self, batch, exc: Exception) -> None:
+        """One batch's answer raised: the ADR-011 supervisor, where
+        there is one, answers its futures from the CPU trie."""
+        self.errors += 1
+        fail_batch(self, batch, exc)
 
     async def subscribers_async(self, topic: str) -> "SubscriberSet":
         """Queue one match; resolves when its micro-batch returns."""
@@ -378,10 +389,7 @@ class MicroBatcher:
                        self._traced_inline(rec, host is not None,
                                            answer, topics))
         except Exception as exc:
-            self.errors += 1
-            for _, fut in batch:
-                if not fut.done():
-                    fut.set_exception(exc)
+            self._fail(batch, exc)
             return
         self._update_cost_model(host is not None, n,
                                 time.perf_counter() - t0)
@@ -514,11 +522,8 @@ class MicroBatcher:
             # worker thread: overlap device time with the event loop
             results = await loop.run_in_executor(
                 None, *self._call(rec, self._batch_fn, topics))
-        except Exception as exc:  # engine failure → fail the callers
-            self.errors += 1      # (the ADR-011 supervisor above us
-            for _, fut in batch:  # answers them from the CPU trie)
-                if not fut.done():
-                    fut.set_exception(exc)
+        except Exception as exc:
+            self._fail(batch, exc)
             return
         took = time.perf_counter() - t0
         if rec is not None:

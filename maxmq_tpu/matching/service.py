@@ -42,6 +42,7 @@ from .. import faults
 from ..hooks.base import Hook
 from ..protocol.packets import Subscription
 from ..utils.framing import frame as _frame, read_frame as _read_frame
+from .supervisor import SupervisedMatcher, fail_batch
 from .trie import (SubscriberSet, TopicIndex,
                    VersionedTopicCache, subs_version)
 
@@ -335,6 +336,10 @@ class ServiceMatcher:
         self.cache_hits = 0
         self.reconnects = 0
         self.reconnect_attempts = 0
+        # ADR 011: callable(batch, exc) told of the (topic, future)
+        # pairs a dead transport or an errored reply is about to fail;
+        # the supervisor sets it and answers them from the CPU trie
+        self.on_batch_failed = None
 
     # our ``fallbacks`` are dead-transport fast-fails, not row
     # overflows; the ADR-011 supervisor counts those same events under
@@ -400,10 +405,9 @@ class ServiceMatcher:
         if w is not None:
             with contextlib.suppress(Exception):
                 w.close()
-        for fut, _t, _v in self._pending.values():
-            if not fut.done():
-                fut.set_exception(ConnectionError(msg))
+        failed = [(topic, fut) for fut, topic, _v in self._pending.values()]
         self._pending.clear()
+        fail_batch(self, failed, ConnectionError(msg))
         # a dropped transport opens a divergence window (ops queued
         # while down are not forwarded; the service may have restarted
         # empty): drop the result cache wholesale — the reconnect
@@ -427,7 +431,7 @@ class ServiceMatcher:
             if fut.done():
                 continue
             if "e" in msg:
-                fut.set_exception(RuntimeError(
+                fail_batch(self, [(topic, fut)], RuntimeError(
                     f"matcher service error: {msg['e']}"))
             else:
                 if "td" in msg:
@@ -474,7 +478,8 @@ class ServiceMatcher:
             # dead transport: fail fast (trie fallback upstream) and
             # kick one background reconnect; subscription state is
             # re-seeded by _reseed once the new connection is up
-            fut.set_exception(ConnectionError("matcher service down"))
+            fail_batch(self, [(topic, fut)],
+                       ConnectionError("matcher service down"))
             self.fallbacks += 1
             if self._reconnect_task is None or self._reconnect_task.done():
                 self._reconnect_task = loop.create_task(self._reconnect())
@@ -616,7 +621,6 @@ async def attach_matcher_service(broker, path: str,
     broker.add_hook(_ForwardHook(matcher))
     attach = matcher
     if supervisor is not None:
-        from .supervisor import SupervisedMatcher
         attach = SupervisedMatcher(matcher, index=broker.topics,
                                    logger=getattr(broker, "log", None),
                                    **supervisor)

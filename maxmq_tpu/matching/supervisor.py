@@ -28,6 +28,15 @@ itself against):
 ``refresh()`` is crash-safe: a failed recompile keeps serving the
 last-good tables (and counts toward the breaker) instead of raising.
 
+On the async surface (``enqueue``, the publish pipeline's) the ladder is
+bookkeeping beside the inner matcher's own future, not a wrapper around
+it (ADR 011, addendum): the deadlines of all topics in flight stand in
+one queue served by one timer, and an inner that reports a failed batch
+(``on_batch_failed``: the MicroBatcher, the ServiceMatcher) has its
+topics answered from the trie on the futures the pipeline already
+holds. An inner without that surface keeps a second future per topic
+(``wrapped_topics``) on the same queue and the same accounting.
+
 Observability: ``breaker_state`` (0 closed / 1 open / 2 half-open),
 ``fallbacks_by_reason`` (overflow / error / deadline / breaker_open),
 ``degraded_seconds``, ``breaker_trips``, ``refresh_failures`` — all
@@ -52,6 +61,22 @@ BREAKER_HALF_OPEN = 2
 
 _STATE_NAMES = {BREAKER_CLOSED: "closed", BREAKER_OPEN: "open",
                 BREAKER_HALF_OPEN: "half_open"}
+
+def fail_batch(inner, batch, exc: Exception) -> None:
+    """What an inner matcher with the ``on_batch_failed`` surface does
+    with ``(topic, future)`` pairs whose answer raised: tell the
+    observer once (the supervisor answers them from the CPU trie), then
+    fail whatever it left pending — all of them with no observer."""
+    if inner.on_batch_failed is not None:
+        inner.on_batch_failed(batch, exc)
+    for _, fut in batch:
+        if not fut.done():
+            fut.set_exception(exc)
+
+
+# ``_probe_fut`` while the inner's enqueue runs for the half-open probe:
+# its future does not exist yet, and a failure reported now is its own
+_PROBE_UNBORN = object()
 
 
 class SupervisedMatcher:
@@ -89,6 +114,24 @@ class SupervisedMatcher:
         self.refresh_failures = 0
         self.breaker_trips = 0
         self.breaker_recoveries = 0
+        # the async surface's deadline queue: (due, future, topic, probe)
+        # in enqueue order, which is due order (one constant deadline),
+        # and the ONE timer armed for its head
+        self._watched: collections.deque[tuple] = collections.deque()
+        self._sweep_timer: asyncio.TimerHandle | None = None
+        self._sweep_loop: asyncio.AbstractEventLoop | None = None
+        self.deadline_timers_armed = 0    # each runs one sweep
+        self.wrapped_topics = 0           # topics that took a second future
+        # the future the half-open probe rides (_PROBE_UNBORN while the
+        # inner's enqueue is being called for it)
+        self._probe_fut = None
+        # an inner that reports a failed batch needs no wrapper: its
+        # futures are answered from the trie where it learns of the
+        # failure, one call a batch
+        self._direct = (hasattr(inner, "on_batch_failed")
+                        and hasattr(inner, "enqueue"))
+        if self._direct:
+            inner.on_batch_failed = self._batch_failed
 
     # -- delegation ----------------------------------------------------
 
@@ -159,6 +202,8 @@ class SupervisedMatcher:
     def _admit(self) -> str:
         """Route one call: 'device' (closed), 'probe' (the single
         half-open reprobe), or 'trie' (open / probe already in flight)."""
+        if self._state == BREAKER_CLOSED:
+            return "device"
         with self._lock:
             if self._state == BREAKER_CLOSED:
                 return "device"
@@ -200,9 +245,9 @@ class SupervisedMatcher:
                            backoff_s=self._backoff)
 
     def _record_success(self, probe: bool) -> None:
+        if not probe:
+            return
         with self._lock:
-            if not probe:
-                return
             self._probe_inflight = False
             if self._state != BREAKER_CLOSED:
                 self._state = BREAKER_CLOSED
@@ -338,30 +383,48 @@ class SupervisedMatcher:
     def enqueue(self, topic: str) -> asyncio.Future:
         """The ADR-006 pipeline surface: returns a future that ALWAYS
         resolves by the deadline — device result, or trie answer on
-        error / deadline / open breaker."""
-        loop = asyncio.get_running_loop()
-        out: asyncio.Future = loop.create_future()
+        error / deadline / open breaker. With an inner that reports its
+        failed batches it is the inner's own future: its awaiter wakes
+        in the loop iteration after the answer was given."""
         route = self._admit()
         if route == "trie":
             self.breaker_fallbacks += 1
-            self._settle_from_trie(out, topic, None)
-            return out
+            return self._trie_future(topic, None)
         probe = route == "probe"
+        if not self._direct:
+            return self._enqueue_wrapped(topic, probe)
+        if probe:
+            # a failure reported from inside the call is the probe's
+            self._probe_fut = _PROBE_UNBORN
+        try:
+            fut = self.inner.enqueue(topic)
+        except Exception as exc:
+            self._probe_fut = None
+            self._record_failure(probe)
+            self.error_fallbacks += 1
+            return self._trie_future(topic, exc)
+        if probe and self._probe_fut is _PROBE_UNBORN:
+            self._probe_fut = fut
+            fut.add_done_callback(self._probe_done)
+        if not fut.done():
+            self._watch(fut, topic, probe)
+        return fut
+
+    def _enqueue_wrapped(self, topic: str, probe: bool) -> asyncio.Future:
+        """An inner that cannot say when a batch failed (an engine in
+        the executor, ``subscribers_async``, a bare ``enqueue``): a
+        second future stands between its exception and the pipeline.
+        The deadline queue and the accounting are the direct path's."""
+        self.wrapped_topics += 1
         try:
             inner = self._inner_enqueue(topic)
         except Exception as exc:
             self._record_failure(probe)
             self.error_fallbacks += 1
-            self._settle_from_trie(out, topic, exc)
-            return out
-        timer = None
-        if self.deadline_ms > 0:
-            timer = loop.call_later(self.deadline_ms / 1e3,
-                                    self._on_deadline, out, topic, probe)
+            return self._trie_future(topic, exc)
+        out: asyncio.Future = asyncio.get_running_loop().create_future()
 
         def done(f: asyncio.Future) -> None:
-            if timer is not None:
-                timer.cancel()
             if f.cancelled():
                 # shutdown-path cancel, not a device failure
                 if probe:
@@ -381,10 +444,7 @@ class SupervisedMatcher:
                 self._settle_from_trie(out, topic, exc)
             else:
                 self._record_success(probe)
-                # forward the ADR-015 dispatch/done clock marks, the
-                # batch record and the answerer the batcher stamped on
-                # ITS future, so the tracer's queue/device split and the
-                # batch's phases survive the supervisor wrapper
+                # the ADR-015 marks an inner stamped on ITS future
                 for attr in ("_t_dispatch", "_t_done", "_t_batch",
                              "_t_via"):
                     v = getattr(f, attr, 0)
@@ -393,15 +453,93 @@ class SupervisedMatcher:
                 out.set_result(f.result())
 
         inner.add_done_callback(done)
+        self._watch(out, topic, probe)
         return out
 
-    def _on_deadline(self, out: asyncio.Future, topic: str,
-                     probe: bool) -> None:
-        if out.done():
+    def _probe_done(self, fut: asyncio.Future) -> None:
+        """The one done-callback of the direct path: the half-open
+        probe's future finished. Nothing to do where the deadline or the
+        error hedge judged the probe first."""
+        if self._probe_fut is not fut:
             return
-        self._record_failure(probe)
-        self.deadline_fallbacks += 1
-        self._settle_from_trie(out, topic, None)
+        self._probe_fut = None
+        if fut.cancelled():
+            self._probe_abort()     # shutdown: neither success nor failure
+        elif fut.exception() is None:
+            self._record_success(True)
+        else:
+            self._record_failure(True)
+
+    def _batch_failed(self, batch, exc: Exception) -> None:
+        """The inner's ``on_batch_failed``: one call for a batch of
+        ``(topic, future)`` whose answer raised (or a transport that
+        died under them). Every topic still waiting is answered from the
+        trie on its own future and counted as one error fallback and
+        one breaker failure, as a per-topic callback would have; one the
+        deadline answered already was counted then."""
+        for topic, fut in batch:
+            if fut.done():
+                continue
+            probe = self._probe_fut is fut \
+                or self._probe_fut is _PROBE_UNBORN
+            if probe:
+                self._probe_fut = None
+            self._record_failure(probe)
+            self.error_fallbacks += 1
+            self._settle_from_trie(fut, topic, exc)
+
+    # -- the deadline queue ---------------------------------------------
+
+    def _watch(self, fut: asyncio.Future, topic: str, probe: bool) -> None:
+        """Put ``fut`` under the per-topic deadline, counted from now.
+        The deadline is one constant, so the queue is in due order and
+        one timer for its head serves every topic in flight."""
+        if self.deadline_ms <= 0:
+            return
+        loop = asyncio.get_running_loop()
+        if self._sweep_loop is not loop:
+            # first use, or a new loop: what the old one left is dead
+            self._watched.clear()
+            self._sweep_timer = None
+            self._sweep_loop = loop
+        due = loop.time() + self.deadline_ms / 1e3
+        self._watched.append((due, fut, topic, probe))
+        if self._sweep_timer is None:
+            self._arm(loop, due)
+
+    def _arm(self, loop: asyncio.AbstractEventLoop, due: float) -> None:
+        self.deadline_timers_armed += 1
+        self._sweep_timer = loop.call_at(due, self._sweep, loop, due)
+
+    def _sweep(self, loop: asyncio.AbstractEventLoop, armed: float) -> None:
+        """The timer fired: drop every answered head, answer every due
+        one from the trie (reason="deadline", one breaker failure each),
+        and arm for the first topic that is neither. ``armed`` stands
+        for a clock that reads a tick short of what it was set for."""
+        if self._sweep_loop is not loop:
+            return
+        now = max(loop.time(), armed)
+        watched = self._watched
+        while watched:
+            due, fut, topic, probe = watched[0]
+            if not fut.done():
+                if due > now:
+                    self._arm(loop, due)
+                    return
+                if probe:
+                    self._probe_fut = None
+                self._record_failure(probe)
+                self.deadline_fallbacks += 1
+                self._settle_from_trie(fut, topic, None)
+            watched.popleft()
+        self._sweep_timer = None
+
+    # -- degraded answers on the async surface --------------------------
+
+    def _trie_future(self, topic: str, cause: Exception | None):
+        out = asyncio.get_running_loop().create_future()
+        self._settle_from_trie(out, topic, cause)
+        return out
 
     def _settle_from_trie(self, out: asyncio.Future, topic: str,
                           cause: Exception | None) -> None:
@@ -410,9 +548,10 @@ class SupervisedMatcher:
             answer = self._trie(topic)
             if tracer is not None and tracer.sample_n:
                 # ADR 015: answered here, by the trie, whatever the
-                # inner future goes on to do
+                # inner goes on to do with the batch it rode in
                 out._t_done = tracer.clock()
                 out._t_via = "fallback"
+                out._t_batch = None
             out.set_result(answer)
         except Exception:
             out.set_exception(cause if cause is not None else

@@ -1162,6 +1162,17 @@ def _register_breaker_metrics(registry: Registry, matcher) -> None:
         "maxmq_matcher_refresh_failures_total",
         "Table recompiles that failed (last-good tables kept serving)",
         lambda: matcher.refresh_failures)
+    registry.counter_func(
+        "maxmq_matcher_deadline_timers_armed_total",
+        "Timers armed for the head of the supervisor's deadline queue "
+        "(each runs one sweep; a few a second however many topics are "
+        "asked)",
+        lambda: matcher.deadline_timers_armed)
+    registry.counter_func(
+        "maxmq_matcher_wrapped_topics_total",
+        "Topics that took a second future because the inner matcher "
+        "cannot report a failed batch",
+        lambda: matcher.wrapped_topics)
 
 
 def register_pool_metrics(registry: Registry, stats) -> None:
