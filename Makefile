@@ -7,7 +7,7 @@
 PY ?= python
 SHELL := /bin/bash           # pipefail in the test target
 
-.PHONY: all check lint cyclo test test-asan coverage native bench clean hooks
+.PHONY: all check lint cyclo test test-asan coverage native clean hooks
 
 all: check
 
@@ -59,9 +59,6 @@ test-asan:
 	JAX_PLATFORMS=cpu \
 	$(PY) -m pytest tests/test_sig_parity.py tests/test_churn_stress.py \
 	    tests/test_native.py tests/test_refdecode.py -x -q
-
-bench:
-	$(PY) bench.py
 
 hooks:
 	chmod +x scripts/githooks/*

@@ -44,6 +44,9 @@ import time
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, ROOT)
+sys.path.insert(1, os.path.join(ROOT, "perfbench"))
+
+import generators  # noqa: E402  (the benchmark's corpus builder)
 
 FULL_SUBS = 1_000_000          # BASELINE.json config 4's table
 PUBLISHERS = 4                 # and 64 subscribers: see live_plan
@@ -162,10 +165,14 @@ def split(before: dict, after: dict, largest_batch: int) -> dict:
 
 
 def corpus(n: int, seed: int):
-    """BASELINE.json config 4's shape: bench.build_corpus's ``+``/``#``
-    mix with 10% ``$share``, client ``cl-<i>`` at QoS ``i % 3``."""
-    from bench import build_corpus
-    return build_corpus(n, seed=seed, share_frac=0.1)
+    """BASELINE.json config 4's shape, from the benchmark's own builder
+    (perfbench/generators.py): its ``+``/``#`` mix with 10% ``$share``,
+    client ``cl-<i>`` at QoS ``i % 3``; and ``topic_gen(batch, seed)``,
+    that many fresh topics of the corpus's shape."""
+    def topic_gen(batch: int, seed2: int) -> list[str]:
+        rng = random.Random(seed2)
+        return [generators.corpus_topic(rng) for _ in range(batch)]
+    return generators.corpus(n, seed), topic_gen
 
 
 def write_store(path: str, filters: list[str]) -> None:

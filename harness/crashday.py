@@ -33,8 +33,7 @@ Every broker is a REAL subprocess running the production bootstrap
 (run_server) configured purely through MAXMQ_* env; crash points and
 disk faults arm through the MAXMQ_FAULTS rail the subprocess parses at
 import. The scenario emits one machine-checkable SLO sheet
-(``sheet["pass"]`` + violations); ``bench.py`` config ``crashday``
-emits it as a BENCH_r*.json row gated by scripts/bench_compare.py.
+(``sheet["pass"]`` + violations).
 
 ``python -m harness.crashday --smoke`` runs the <60s smoke shape
 (3 kill points, tmpfs store) the tier-1 suite wires in.
@@ -642,7 +641,7 @@ class CrashDay:
             check(fs["breaker_recoveries"] >= 1,
                   "breaker never recovered after fsync failures")
         s["violations"] = violations
-        # the numeric twin bench_compare's *violation* pattern gates on
+        # the list's length, for a reader that compares numbers
         s["violation_count"] = len(violations)
         s["pass"] = not violations
 

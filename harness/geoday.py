@@ -36,8 +36,7 @@ loopback RTT replays here under latency the deadlines must absorb:
                             the replicated inflight window follows it
                             across the shaped mesh
 
-The SLO sheet (``config: geoday`` in BENCH_r*.json, gated by
-scripts/bench_compare.py with RTT-scaled floors): zero PUBACKed
+The SLO sheet: zero PUBACKed
 loss, the will fires exactly once, ZERO false link flaps on healthy
 shaped links, heal-convergence and roam-takeover bounded relative to
 the configured RTT.

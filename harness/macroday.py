@@ -26,9 +26,7 @@ The run is scored against ONE machine-checkable SLO sheet (see
 docs/adr/020-macroday-harness.md for the schema): PUBACKed-loss must
 be 0 across the kill AND the partition, the will fires exactly once,
 recovery/convergence times are recorded, and the per-stage p99 tails
-ride along from the ADR-015 tracer. ``bench.py`` config ``macroday``
-emits the sheet as a BENCH_r*.json row that scripts/bench_compare.py
-gates on (loss and recovery fields block alongside throughput/p99).
+ride along from the ADR-015 tracer.
 
 Since ADR 021 the same day can replay against a SHARDED BOX:
 ``MacroDay(workers=N)`` boots the three mesh roles as in-box pool
@@ -545,8 +543,8 @@ class MacroDay(Scenario):
 
     @staticmethod
     def _trace_stanza(tracer) -> dict:
-        """The ADR-015 stanza, same shape bench.py embeds (duplicated
-        here rather than imported: bench.py imports this module)."""
+        """The ADR-015 stanza of the sheet: what was sampled, and the
+        per-stage and per-QoS quantiles."""
         d = {"sampled": tracer.sampled,
              "slow_captured": tracer.slow_captured,
              "stages": tracer.stage_quantiles(),

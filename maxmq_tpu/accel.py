@@ -10,7 +10,7 @@ import os
 
 CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 # ``matcher`` values that build an engine on the device in this process
-DEVICE_MATCHERS = ("sig", "nfa", "dense")
+DEVICE_MATCHERS = ("sig",)
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 

@@ -109,40 +109,30 @@ def install_event_loop(policy: str, logger: Logger | None = None) -> str:
 
 
 def build_engine(conf: Config, index):
-    """The configured device engine over ``index``: ``nfa``/``dense``/
-    ``sig``, sharded over a device mesh when ``matcher_mesh`` names one
-    (e.g. "2x4", cluster mode). Shared by the in-process matcher build
-    and the worker pool's sidecar (broker/workers.py)."""
+    """The device engine over ``index`` (``matcher = "sig"``): a
+    ``SigEngine``, or a ``ShardedSigEngine`` over a device mesh when
+    ``matcher_mesh`` names one (e.g. "2x4", cluster mode). Shared by the
+    in-process matcher build and the worker pool's sidecar
+    (broker/workers.py)."""
+    if conf.matcher not in DEVICE_MATCHERS:
+        raise ValueError(f"unknown matcher {conf.matcher!r} "
+                         "(want trie|sig|service)")
     require_accelerator(f"matcher = {conf.matcher!r}")
     if conf.matcher_mesh:
-        from .parallel.sharded import (ShardedNFAEngine, ShardedSigEngine,
-                                       make_mesh)
+        from .parallel.sharded import ShardedSigEngine, make_mesh
         rows, _, cols = conf.matcher_mesh.partition("x")
         mesh = make_mesh(shape=(int(rows), int(cols or 1)))
-        if conf.matcher == "nfa":
-            return ShardedNFAEngine(index, mesh=mesh,
-                                    max_levels=conf.matcher_max_levels)
-        # the sharded sig engine derives its depth window from the
-        # corpus (DEPTH_CAP-bounded); matcher_max_levels is a
-        # word-path/nfa/dense knob
+        # the sharded engine derives its depth window from the corpus
+        # (DEPTH_CAP-bounded); matcher_max_levels is a word-path knob
         engine = ShardedSigEngine(index, mesh=mesh)
-        engine.emit_intents = conf.matcher_intents   # ADR 007
-        return engine
-    if conf.matcher == "nfa":
-        from .matching.engine import NFAEngine
-        return NFAEngine(index, max_levels=conf.matcher_max_levels)
-    if conf.matcher == "dense":
-        from .matching.dense import DenseEngine
-        return DenseEngine(index, max_levels=conf.matcher_max_levels)
-    if conf.matcher == "sig":
+    else:
         from .matching.sig import SigEngine
         engine = SigEngine(index, max_levels=conf.matcher_max_levels)
-        # fan-out-ready DeliveryIntents from the native decode (ADR 007)
-        # — the broker handles both result shapes, so this is safe to
-        # default on; matcher_intents = false restores merged sets
-        engine.emit_intents = conf.matcher_intents
-        return engine
-    raise ValueError(f"unknown matcher {conf.matcher!r}")
+    # fan-out-ready DeliveryIntents from the native decode (ADR 007)
+    # — the broker handles both result shapes, so this is safe to
+    # default on; matcher_intents = false restores merged sets
+    engine.emit_intents = conf.matcher_intents
+    return engine
 
 
 def build_matcher(conf: Config, broker: Broker):
@@ -174,15 +164,12 @@ def build_matcher(conf: Config, broker: Broker):
                                    logger=broker.log,
                                    **supervisor_kwargs(conf))
     broker.attach_matcher(attach)
-    warm = getattr(engine, "warm_buckets", None)
-    if warm is not None:
-        # the bucket ladder this broker serves with: compiled now when
-        # there is a table, and after every table compile from here on
-        # (the boot compile in Broker.serve, each background rotation)
-        warm(conf.matcher_max_batch)
-    prewarm = getattr(engine, "prewarm_decode_bases", None)
-    if prewarm is not None:
-        prewarm()    # chained-decode anchors at the boot quiescent point
+    # the bucket ladder this broker serves with: compiled now when
+    # there is a table, and after every table compile from here on
+    # (the boot compile in Broker.serve, each background rotation)
+    engine.warm_buckets(conf.matcher_max_batch)
+    # chained-decode anchors at the boot quiescent point
+    engine.prewarm_decode_bases()
     return attach
 
 

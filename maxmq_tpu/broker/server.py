@@ -5,7 +5,7 @@ Parity surface: vendor/github.com/mochi-co/mqtt/v2/server.go in the reference
 (Server, Capabilities, EstablishConnection, processPublish,
 publishToSubscribers, publishToClient, event loop). Re-designed around
 asyncio: the per-connection read loop serializes that client's packets; the
-topic matcher is pluggable so the TPU NFA engine can replace the CPU trie.
+topic matcher is pluggable so the TPU engine can replace the CPU trie.
 """
 
 from __future__ import annotations
@@ -145,7 +145,7 @@ class Broker:
         self.listeners = Listeners()
         self.hooks = Hooks()
         self.info = SysInfo(version=__version__, started=int(time.time()))
-        self.matcher = None  # optional TPU/NFA matcher engine (set via attach)
+        self.matcher = None  # optional TPU matcher engine (set via attach)
         self._housekeeper: asyncio.Task | None = None
         self._sys_task: asyncio.Task | None = None
         self._will_delays: dict[str, tuple[float, Packet]] = {}
@@ -250,7 +250,7 @@ class Broker:
         return self.listeners.add(listener)
 
     def attach_matcher(self, matcher) -> None:
-        """Install a pluggable matcher engine (e.g. the TPU NFA). It must
+        """Install a pluggable matcher engine (e.g. the sig engine). It must
         expose ``subscribers(topic) -> SubscriberSet``."""
         self.matcher = matcher
 
@@ -320,21 +320,19 @@ class Broker:
         trie whatever the sidecar does.)"""
         if self.matcher is None or self.topics.subscription_count == 0:
             return
+        # a batcher (supervised or not) holds the engine; a bare engine
+        # and a ServiceMatcher are attached as they are
         engine = getattr(self.matcher, "engine", self.matcher)
+        # all but a ServiceMatcher: an engine (sig.OverlayedEngine)
         refresh = getattr(engine, "refresh", None)
         if refresh is None:
             return
 
         def compile_and_prewarm():
             refresh()
-            rewarm = getattr(engine, "rewarm", None)
-            if rewarm is not None:
-                rewarm()
-            prewarm = getattr(engine, "prewarm_decode_bases", None)
-            if prewarm is None:
-                return
+            engine.rewarm()
             try:
-                prewarm()
+                engine.prewarm_decode_bases()
             except Exception as exc:
                 # prewarm is a warm-up optimization: the compiled
                 # tables above are live either way, so a prewarm
@@ -1380,7 +1378,7 @@ class Broker:
 
     async def publish_to_subscribers(self, packet: Packet) -> None:
         """Parity: v2/server.go:766-868. Matching goes through the pluggable
-        matcher (TPU NFA) when attached, else the CPU trie; hooks may then
+        matcher (TPU engine) when attached, else the CPU trie; hooks may then
         override via on_select_subscribers, mirroring the reference.
 
         When the publish pipeline is active, out-of-band producers (wills,
@@ -1616,7 +1614,7 @@ class Broker:
 
     def _template_for(self, packet: Packet, version: int):
         """The (packet, version) shared template, counted on first
-        build (the ledger term the fan-out bench divides by)."""
+        build (``template_builds``)."""
         cache = packet.__dict__.get("_tmpl")
         if cache is None or (5 if version >= 5 else 4) not in cache:
             self.overload.template_builds += 1

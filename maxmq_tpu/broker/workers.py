@@ -24,7 +24,7 @@ metrics) and the per-worker config derivation.
 Shared singletons per box (the perf point of ADR 021):
 
 * ONE matcher sidecar — when the box config asks for a device engine
-  (``sig``/``nfa``/``dense``), the pool parent runs a
+  (``matcher = "sig"``), the pool parent runs a
   :class:`~..matching.service.MatcherService` on a pool socket and
   every worker attaches as a ``matcher=service`` client behind its own
   ADR-011 supervisor. Table compiles happen once per box, and match
@@ -318,8 +318,8 @@ async def inprocess_pool(n: int = 2, link_dir: str | None = None,
 
 
 def _engine_factory(conf):
-    """The sidecar's engine build: bootstrap.build_engine (sig/nfa/
-    dense, mesh-sharded when configured) behind a MicroBatcher — the
+    """The sidecar's engine build: bootstrap.build_engine (the sig
+    engine, mesh-sharded when configured) behind a MicroBatcher — the
     ONE table compile per box the workers share."""
     def factory(index):
         from ..bootstrap import build_engine

@@ -9,8 +9,8 @@ lookup on an (almost always) empty dict when nothing is armed.
 
 Arming is deterministic and counted: ``arm(site, mode, count)`` fires
 the fault for exactly the next ``count`` hits of that site (``count=-1``
-= until disarmed), then self-disarms, so a test (or a degraded-mode
-bench run) can script "fail the next 3 device batches, then recover"
+= until disarmed), then self-disarms, so a test (or a harness's
+degraded-mode run) can script "fail the next 3 device batches, then recover"
 with no sleeps or races. ``fired`` records how many times each site
 actually tripped.
 
@@ -27,8 +27,8 @@ Modes:
   pool worker stops itself. This keeps process-structure faults out of
   the registry's hands — it only ever raises or sleeps.
 
-Env arming (``MAXMQ_FAULTS``) lets ``bench.py`` and subprocess pool
-workers arm faults they can't reach by reference::
+Env arming (``MAXMQ_FAULTS``) lets the day harnesses' broker
+subprocesses and pool workers arm faults they can't reach by reference::
 
     MAXMQ_FAULTS="device.match:raise:3,device.match:hang:1:0.5"
 
@@ -559,7 +559,7 @@ get_shape = REGISTRY.get_shape
 any_shaped = REGISTRY.any_shaped
 fired = REGISTRY.fired
 
-# env arming: subprocess pool workers and bench's degraded-mode runs
+# env arming: subprocess pool workers and the harnesses' brokers
 # inherit MAXMQ_FAULTS through their environment
 _env_spec = os.environ.get("MAXMQ_FAULTS", "")
 if _env_spec:

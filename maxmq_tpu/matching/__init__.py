@@ -1,5 +1,5 @@
-"""Topic matching: CPU reference trie, NFA compiler, and the JAX/Pallas
-batched TPU matcher."""
+"""Topic matching: CPU reference trie, signature-table compiler, and the
+JAX/Pallas batched TPU matcher."""
 
 from .topics import is_dollar, parse_share, split_levels, valid_filter, valid_topic_name
 from .trie import SubscriberSet, TopicAliases, TopicIndex, merge_subscription

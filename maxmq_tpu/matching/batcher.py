@@ -38,8 +38,8 @@ if TYPE_CHECKING:
 class MicroBatcher:
     """Coalesces concurrent single-topic match requests into device batches.
 
-    ``engine`` is any matcher exposing ``subscribers_batch(list[str]) ->
-    list[SubscriberSet]`` (NFAEngine, DenseEngine, ShardedNFAEngine).
+    ``engine`` is a device engine: ``SigEngine`` or ``ShardedSigEngine``,
+    held to the contract ``sig.OverlayedEngine`` states.
     """
 
     # a trie-bypassed batch never exceeds this many topics: the bypass
@@ -64,7 +64,7 @@ class MicroBatcher:
         # single serialized batch makes every queued request wait out
         # the full round trip of the one before it; the sig engine's
         # dispatch/collect split lets batch N+1's upload ride the link
-        # while batch N decodes (same depth the bench pipelines at).
+        # while batch N decodes.
         self.pipeline_depth = max(1, pipeline_depth)
         self._pending: list[tuple[str, asyncio.Future]] = []
         # the matcher-mode analog of the broker's trie-path match cache:
@@ -146,8 +146,10 @@ class MicroBatcher:
 
     @property
     def _batch_fn(self):
-        """Prefer the engine's fixed-slot path (fewest bytes/kernels per
-        micro-batch) when it has one (SigEngine)."""
+        """The engine's fixed-slot path (fewest bytes/kernels per
+        micro-batch): SigEngine's ``subscribers_fixed_batch``. A
+        ShardedSigEngine has no method of that name: its
+        ``subscribers_batch`` is fixed-slot already."""
         return getattr(self.engine, "subscribers_fixed_batch",
                        self.engine.subscribers_batch)
 
@@ -156,11 +158,11 @@ class MicroBatcher:
 
     @property
     def matches(self):
-        return getattr(self.engine, "matches", 0)
+        return self.engine.matches
 
     @property
     def fallbacks(self):
-        return getattr(self.engine, "fallbacks", 0)
+        return self.engine.fallbacks
 
     @property
     def index(self):
@@ -270,17 +272,15 @@ class MicroBatcher:
         self._pending.clear()
         # wait out any in-flight background table recompile: tearing the
         # process down mid-compile aborts inside the runtime library
-        close_fn = getattr(self.engine, "close", None)
-        if close_fn is not None:
-            await asyncio.get_running_loop().run_in_executor(None, close_fn)
+        await asyncio.get_running_loop().run_in_executor(
+            None, self.engine.close)
 
     async def _run(self) -> None:
         loop = asyncio.get_running_loop()
-        # pipelined mode needs the engine's dispatch/collect split
-        # (SigEngine's fixed path); other engines run one batch at a
-        # time through their whole-batch function
+        # pipelined mode needs the dispatch_fixed / collect_fixed split
+        # of SigEngine's fixed path; a ShardedSigEngine lacks it and
+        # runs one batch at a time through its whole-batch function
         split = (hasattr(self.engine, "dispatch_fixed")
-                 and hasattr(self.engine, "collect_fixed")
                  and self.pipeline_depth > 1)
         while True:
             await self._wakeup.wait()
@@ -297,7 +297,11 @@ class MicroBatcher:
             ver = self._subs_version()   # results valid as-of dispatch
             if self._should_bypass(len(batch)):
                 self._run_bypass(batch, topics, ver, rec)
-            elif split and not self._engine_routes():
+            elif split and not self.engine._routes_to_trie():
+                # an ADR-008 routed corpus is served by the whole-batch
+                # surface (which answers from the engine's trie):
+                # dispatch_fixed would force the device round trip the
+                # router rejected
                 await self._dispatch_pipelined(loop, batch, topics, ver,
                                                rec)
             else:
@@ -330,13 +334,6 @@ class MicroBatcher:
                 and self._inflight._value < self.pipeline_depth):
             await asyncio.sleep(self.window_us / 1e6)
 
-    def _engine_routes(self) -> bool:
-        """ADR-008 routed corpora serve via the engine's whole-batch
-        surface (which answers from its trie); dispatch_fixed would
-        force the device round trip the router rejected."""
-        routes = getattr(self.engine, "_routes_to_trie", None)
-        return routes is not None and routes()
-
     # -- adaptive CPU bypass -------------------------------------------
 
     def _host_est(self, n: int) -> float:
@@ -347,8 +344,6 @@ class MicroBatcher:
     def _bypass_cost(self, n: int) -> float:
         """Cheapest host-serving cost for ``n`` topics — the same
         min() _run_bypass takes, so prediction and execution agree."""
-        if getattr(self.engine, "subscribers_host_batch", None) is None:
-            return n * self._trie_cost
         return min(n * self._trie_cost, self._host_est(n))
 
     def _should_bypass(self, n: int) -> bool:
@@ -366,22 +361,20 @@ class MicroBatcher:
     def _run_bypass(self, batch, topics, ver, rec=None) -> None:
         """Serve one small batch on the host, inline on the loop
         (bounded by BYPASS_CAP x per-topic cost), updating whichever
-        cost model served it. Engines exposing the device-free probe
-        path (subscribers_host_batch: exact/'+'/'#' signature probes +
-        the same C decode) serve from it when its fixed+per-topic
-        estimate undercuts the trie's per-topic one (tiny batches over
-        small corpora are the trie's remaining win); others always
-        walk the CPU trie."""
+        cost model served it. The engine's device-free probe path
+        (subscribers_host_batch: exact/'+'/'#' signature probes + the
+        same C decode) serves when its fixed+per-topic estimate
+        undercuts the trie's per-topic one (tiny batches over small
+        corpora are the trie's remaining win); else the CPU trie is
+        walked."""
         n = len(topics)
         host = self._pick_bypass_host(n)
-        kick = getattr(self.engine, "refresh_soon", None)
-        if (host is None and kick is not None
-                and getattr(self.engine, "auto_refresh", True)):
+        if host is None and self.engine.auto_refresh:
             # the trie answers from the live index without touching the
             # engine, which is where staleness is otherwise noticed: a
             # broker whose every batch is this cheap would never
             # recompile its tables after a subscription change
-            kick()
+            self.engine.refresh_soon()
         answer = host if host is not None else self._trie_walk
         t0 = time.perf_counter()
         try:
@@ -422,15 +415,12 @@ class MicroBatcher:
         estimate undercuts the trie's, else None (trie serves). Tiny
         batches periodically re-sample the trie so a winning host path
         cannot let the trie estimate go stale."""
-        host = getattr(self.engine, "subscribers_host_batch", None)
-        if host is None:
-            return None
         if n * self._trie_cost < self._host_est(n):
             return None
         if n <= 8 and self._trie_stale >= 64:
             self._trie_stale = 0
             return None
-        return host
+        return self.engine.subscribers_host_batch
 
     def _update_cost_model(self, via_host: bool, n: int,
                            took: float) -> None:
@@ -502,7 +492,7 @@ class MicroBatcher:
         table rotation holds the interpreter for seconds at a time: on
         a v5e at 1M filters one such sample read 3.1 s, and the bypass
         it talked into winning kept the chip idle long after)."""
-        if getattr(self.engine, "compiling", False):
+        if self.engine.compiling:
             return
         self._rtt_samples += 1
         self._since_probe = 0

@@ -1,10 +1,9 @@
 """Signature matcher: wildcard matching as grouped hash-equality — the
 bandwidth-optimal TPU formulation.
 
-The leveled dense walk (dense.py) is O(B x total-trie-slots) with a
-[B, S] state per level; at 100K subscriptions that is ~330K slots and the
-per-level parent gather dominates (~70ms per 8K batch on a v5e chip). This
-module removes the walk entirely by observing that every MQTT filter is an
+A walk of the subscription trie on the device is O(B x total-trie-slots)
+with a [B, S] state per level, and the per-level parent gather dominates.
+This module has no walk, by observing that every MQTT filter is an
 *exact match in disguise*:
 
 * a filter with no '#' and '+' at positions P matches topic T iff
@@ -22,7 +21,7 @@ signature per group is computed (a tiny [B, G] int op), then compared
 against every row's stored signature — a pure broadcast compare bit-packed
 straight into uint32 match words. No gathers, no per-level state, no MXU
 dependence; the data flow is the shape the VPU and HBM like best. Real
-corpora produce tens-to-hundreds of groups (bench config #3: ~130).
+corpora produce tens-to-hundreds of groups (a 100K-filter IoT mix: ~130).
 
 Collisions cannot corrupt results: the host decode re-verifies every
 candidate row with ``topics.filter_matches_topic`` (an O(levels) exact
@@ -39,10 +38,11 @@ topics.go:484-555 (`Subscribers`/`scanSubscribers`).
 
 from __future__ import annotations
 
+import asyncio
 import logging
 import threading
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 import numpy as np
 
@@ -50,9 +50,8 @@ import jax
 import jax.numpy as jnp
 
 from .. import faults
+from ..protocol.packets import Subscription
 from ..trace import NO_SPAN, active_batch, host_span
-from .dense import extract_nonzero_words
-from .nfa import Entry, EntryBuilder
 from .topics import (batch_bucket as _batch_bucket, filter_matches_topic,
                      intern_level, split_levels, tokenize_cached,
                      tokenize_topics)
@@ -61,6 +60,55 @@ from .trie import SubscriberSet, TopicIndex, merge_subscription
 MAX_GROUPS = 4096   # compile guard: pathological corpora fall back (engine)
 DEPTH_CAP = 63      # deepest literal level any compiled group may inspect
                     # (the compact tokenizer's int8 length encoding bound)
+
+
+@dataclass
+class Entry:
+    """One subscriber bit: an ordinary (client, sub) or a shared pair."""
+
+    client_id: str = ""
+    subscription: Subscription | None = None
+    group: str = ""          # non-empty => shared pair
+    filter: str = ""
+    # shared pairs carry the full candidate map
+    candidates: dict[str, Subscription] = field(default_factory=dict)
+
+    @property
+    def shared(self) -> bool:
+        return bool(self.group)
+
+
+class EntryBuilder:
+    """Accumulates Entry records with `$share` (group, filter) dedup: the
+    subscriber-bit construction of the compiled row tables, so every
+    member of a shared group rides ONE row bit and merge semantics have
+    one definition."""
+
+    def __init__(self) -> None:
+        self.entries: list[Entry] = []
+        self._shared: dict[tuple[str, str], int] = {}
+
+    def add(self, filt: str, client_id: str, sub: Subscription,
+            group: str) -> int | None:
+        """Record one subscription. Returns the bit index to place on the
+        row, or None when this shared (group, filter) pair already has
+        its bit placed (the new member only joins the candidate map)."""
+        if group:
+            key = (group, sub.filter)
+            bit = self._shared.get(key)
+            if bit is not None:
+                self.entries[bit].candidates[client_id] = sub
+                return None
+            bit = len(self.entries)
+            self._shared[key] = bit
+            entry = Entry(group=group, filter=sub.filter)
+            entry.candidates[client_id] = sub
+            self.entries.append(entry)
+            return bit
+        bit = len(self.entries)
+        self.entries.append(Entry(client_id=client_id, subscription=sub,
+                                  filter=filt))
+        return bit
 
 
 def _group_constants(key: tuple[bool, int, tuple[int, ...]],
@@ -223,8 +271,9 @@ def compile_sig(index, version: int | None = None,
 def compile_sig_subscriptions(subs, version: int = 0,  # qa: complex
                               vocab: dict[str, int] | None = None,
                               max_levels: int = 16) -> SigTables:
-    """Build signature tables from a subscription snapshot (same input
-    contract as nfa.compile_subscriptions / dense.compile_dense_*)."""
+    """Build signature tables from a subscription snapshot: the
+    ``(trie path, client_id, subscription, group)`` tuples of
+    ``TopicIndex.all_subscriptions()``."""
     builder = EntryBuilder()
     if vocab is None:
         vocab = {}
@@ -662,10 +711,33 @@ def match_words(consts, planes, sig_adj):
     return acc
 
 
+def extract_nonzero_words(words, lengths, max_words: int):
+    """Sparse tail of the word path: pick the ≤max_words nonzero uint32
+    words of ``words [B, W]`` in ascending word order."""
+    nz = words != 0
+    n_nz = nz.sum(axis=1, dtype=jnp.int32)
+    overflow = (lengths < 0) | (n_nz > max_words)
+    # top_k over (nz ? BIG - word_index : -1): picks nonzero words,
+    # ascending word index; returns their original indices.
+    key = jnp.where(nz, jnp.int32(1 << 30) - jnp.arange(
+        words.shape[1], dtype=jnp.int32)[None, :], jnp.int32(-1))
+    k = min(max_words, words.shape[1])
+    topv, topi = jax.lax.top_k(key, k)
+    word_idx = jnp.where(topv > 0, topi, -1)
+    word_val = jnp.take_along_axis(words, topi, axis=1)
+    word_val = jnp.where(topv > 0, word_val, jnp.uint32(0))
+    if k < max_words:        # tiny tables: pad out to the fixed contract
+        pad = max_words - k
+        word_idx = jnp.pad(word_idx, ((0, 0), (0, pad)),
+                           constant_values=-1)
+        word_val = jnp.pad(word_val, ((0, 0), (0, pad)))
+    return word_idx, word_val, overflow
+
+
 def sig_match_body(consts, planes, toks, lengths, dollar, max_words: int):
     """Traceable signature match over one topic batch (word output form).
 
-    Returns (word_idx, word_val, overflow) as in dense_match_body."""
+    Returns (word_idx, word_val, overflow): extract_nonzero_words."""
     sig_adj = adjusted_signatures(consts, toks, lengths, dollar)
     words = match_words(consts, planes, sig_adj)
     return extract_nonzero_words(words, lengths, max_words)
@@ -1340,9 +1412,43 @@ class Overlay:
 
 
 class OverlayedEngine:
-    """Staleness machinery shared by SigEngine and ShardedSigEngine:
-    background recompile + journal overlay. Subclasses provide
-    ``index``, ``refresh()`` and a ``_refresh_lock``."""
+    """The device engine as its callers see it, and the staleness
+    machinery (background recompile + journal overlay) its two
+    implementations share: ``SigEngine`` (one chip) and
+    ``parallel.sharded.ShardedSigEngine`` (a mesh).
+
+    What ``MicroBatcher``, ``bootstrap.build_matcher`` and
+    ``Broker._compile_matcher_tables`` may call on either engine:
+
+    * answers, each exact against ``index`` (the live ``TopicIndex``):
+      ``subscribers(topic)``, ``subscribers_async(topic)``,
+      ``subscribers_batch(topics)`` (the device) and
+      ``subscribers_host_batch(topics)`` (the device-free probe the
+      batcher's bypass takes). A result is a ``SubscriberSet`` or, with
+      ``emit_intents`` set, a fan-out-ready intents object with
+      ``to_set()`` (ADR 007);
+    * tables: ``refresh(force)`` compiles on the calling thread,
+      ``refresh_soon()`` in the background (unless ``auto_refresh`` is
+      off), ``compiling`` says one is running, ``close()`` waits for it;
+    * warm-up: ``warm_buckets(max_batch)`` names the served bucket
+      ladder, ``rewarm()`` compiles it against the live program,
+      ``prewarm_decode_bases()`` builds the chained-decode anchors;
+    * counters: ``matches``, ``fallbacks``, ``host_matches``,
+      ``bg_refresh_errors``, ``warm_seconds``; ``tracer`` (ADR 015).
+
+    ``SigEngine`` alone has more, and its callers probe for it: the
+    fixed-slot surface (``subscribers_fixed_batch``, and the
+    ``dispatch_fixed`` / ``collect_fixed`` split with the ADR-008 router
+    ``_routes_to_trie`` that pipelining needs), ``trie_routed`` and
+    ``kernel_plan``. A mesh engine's ``subscribers_batch`` is its
+    fixed-slot path.
+
+    Subclasses provide ``index``, ``_state``, a ``_refresh_lock`` and
+    the methods below that raise ``NotImplementedError``."""
+
+    # background recompile on a stale match; SigEngine takes it as a
+    # constructor argument
+    auto_refresh = True
 
     def _init_overlay(self) -> None:
         self._overlay: Overlay | None = None
@@ -1462,10 +1568,8 @@ class OverlayedEngine:
             # don't pay the per-shape compiles
             self.rewarm()
             # repopulate the chained-decode anchors for the fresh
-            # table off the hot path (chunked; yields the GIL); the
-            # sharded engine provides its own cluster form of this
-            # method, hence the getattr indirection
-            getattr(self, "prewarm_decode_bases", lambda: 0)()
+            # table off the hot path (chunked; yields the GIL)
+            self.prewarm_decode_bases()
         except Exception:
             self._note_bg_error("table rotation")
         finally:
@@ -1489,7 +1593,7 @@ class OverlayedEngine:
         no longer reaches back (serve the batch via the CPU trie)."""
         if self.index.sub_version == tables_version:
             return None
-        if getattr(self, "auto_refresh", True):
+        if self.auto_refresh:
             self.refresh_soon()
         with self._overlay_lock:
             ov = self._overlay
@@ -1511,13 +1615,30 @@ class OverlayedEngine:
     def _state_version(state) -> int:
         raise NotImplementedError
 
+    def refresh(self, force: bool = False) -> bool:
+        """Recompile and upload if the index changed, on the calling
+        thread, raising what the compiler raises."""
+        raise NotImplementedError
+
+    def prewarm_decode_bases(self, chunk: int = 2048) -> int:
+        """Build the chained-decode anchors of the live tables; returns
+        the chunk calls made (0 when the intents decode is off)."""
+        raise NotImplementedError
+
+    def subscribers(self, topic: str) -> SubscriberSet:
+        raise NotImplementedError
+
+    async def subscribers_async(self, topic: str) -> SubscriberSet:
+        """Event-loop-friendly match: one topic, on a worker thread."""
+        loop = asyncio.get_running_loop()
+        return await loop.run_in_executor(None, self.subscribers, topic)
+
 
 class SigEngine(OverlayedEngine):
     """Device-resident signature matcher bound to a TopicIndex.
 
-    Same contract as DenseEngine/NFAEngine (subscribers / subscribers_batch
-    / match_raw + CPU-trie fallback on overflow), but the device program is
-    grouped signature equality — the production TPU path at scale.
+    The device program is grouped signature equality; a topic that
+    overflows it (too deep, too many rows) is answered from the CPU trie.
     """
 
     def __init__(self, index: TopicIndex, max_levels: int = 16,
@@ -1563,8 +1684,7 @@ class SigEngine(OverlayedEngine):
         self._xla_fallback_logged = False
         # dual-width plane compare: "auto" runs packed 16-bit planes for
         # eligible groups (compile-time injective fold, see
-        # _pick_fold16), "32" forces the uniform 32-bit planes — the
-        # A/B arm bench.kernel_width_ab measures against
+        # _pick_fold16), "32" forces the uniform 32-bit planes
         if kernel_width not in ("auto", "32"):
             raise ValueError("kernel_width must be 'auto' or '32'")
         self.kernel_width = kernel_width
@@ -1601,8 +1721,8 @@ class SigEngine(OverlayedEngine):
     # ------------------------------------------------------------------
 
     def refresh(self, force: bool = False) -> bool:
-        """Recompile + upload if the index changed (atomic state swap, same
-        double-buffering discipline as DenseEngine.refresh)."""
+        """Recompile + upload if the index changed (atomic state swap:
+        a match in flight keeps the complete state it read)."""
         with self._refresh_lock:
             state = self._state
             if (not force and state is not None
@@ -1797,7 +1917,7 @@ class SigEngine(OverlayedEngine):
 
     def match_raw_many(self, batches: list[list[str]]):
         """Match a stack of equal-sized topic batches in one device
-        dispatch (lax.scan pipeline, as DenseEngine.match_raw_many)."""
+        dispatch (a lax.scan over the stack)."""
         if self.auto_refresh:
             self.refresh_soon()
         state = self._state
@@ -2367,12 +2487,6 @@ class SigEngine(OverlayedEngine):
             return self.index.subscribers(topic)
         return self.subscribers_host_batch([topic])[0]
 
-    async def subscribers_async(self, topic: str) -> SubscriberSet:
-        import asyncio
-
-        loop = asyncio.get_running_loop()
-        return await loop.run_in_executor(None, self.subscribers, topic)
-
     def _has_program(self) -> bool:
         # a declined or ADR-008-routed corpus is served by the trie
         return self._state[2] is not None and not self._routes_to_trie()
@@ -2393,8 +2507,7 @@ class SigEngine(OverlayedEngine):
         few hundred thousand cold topics (measured ~300K topics at 1M
         subs). Production calls this at the boot quiescent point
         (bootstrap.build_matcher) and after each rotation on the
-        background refresh thread; the bench calls it before the timed
-        window for the same reason. Returns the number of chunk calls
+        background refresh thread. Returns the number of chunk calls
         made (0 when the intents decode is unavailable)."""
         if not self.emit_intents:
             return 0

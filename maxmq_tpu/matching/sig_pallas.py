@@ -38,7 +38,7 @@ of 32, and half the plane-constant traffic. Chunks are single-width
 (the two word regions are contiguous by construction), so each
 pallas_call runs either the 32-bit or the packed-16 compare, never a
 mixed one. ``plan(..., force_width32=True)`` builds the uniform 32-bit
-program from the same compiled tables — the bench's A/B arm.
+program from the same compiled tables (``SigEngine(kernel_width="32")``).
 
 Extraction rides a structural fact of the grouping: one word holds 32
 rows of a SINGLE group, and within a group a topic can match at most one

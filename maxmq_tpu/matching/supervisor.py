@@ -1,6 +1,6 @@
 """Matcher degradation ladder: deadline → trie hedge → breaker → reprobe.
 
-The device matchers (NFA/sig engines, the MicroBatcher over them, the
+The device matchers (the sig engines, the MicroBatcher over them, the
 ServiceMatcher socket client) degrade to the CPU trie on *row overflow*
 — but a device error, a hung kernel, a failed recompile, or a dead
 matcher-service socket used to surface as an exception (or a stall)

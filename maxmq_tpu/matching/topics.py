@@ -1,4 +1,4 @@
-"""Topic-name / topic-filter utilities shared by the CPU matcher and the NFA
+"""Topic-name / topic-filter utilities shared by the CPU matcher and the table
 compiler: level splitting, validation, `$share` parsing.
 
 Parity surface: vendor/github.com/mochi-co/mqtt/v2/topics.go:558-624 in the
@@ -102,8 +102,8 @@ UNK = 0  # token id reserved for levels never seen in any filter
 
 def intern_level(vocab: dict[str, int], level: str) -> int:
     """Assign/look up the token id for a level string (0 reserved for UNK).
-    The ONE intern rule shared by the NFA and dense compilers, so a shared
-    vocab always produces identical token ids in both."""
+    The ONE intern rule of the table compilers, so a vocab shared by
+    the shards of a mesh produces identical token ids in every shard."""
     tok = vocab.get(level)
     if tok is None:
         tok = len(vocab) + 1

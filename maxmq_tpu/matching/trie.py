@@ -1,6 +1,6 @@
 """CPU reference topic matcher: a subscription trie with full MQTT wildcard
 semantics. This is both the low-latency fallback matcher and the semantic
-oracle the TPU NFA is parity-tested against.
+oracle the device engines are parity-tested against.
 
 Parity surface: vendor/github.com/mochi-co/mqtt/v2/topics.go in the reference
 (TopicsIndex / particle / Subscribers / scanMessages / topic aliases).
@@ -230,7 +230,7 @@ class TopicIndex:
         self._share_cursor: dict[tuple[str, str], int] = {}
         self.subscription_count = 0
         self.retained_count = 0
-        # bumped on every mutation; lets the NFA engine detect staleness
+        # bumped on every mutation, retained messages included
         self.version = 0
         # bumped on SUBSCRIPTION mutations only — device matchers key
         # their staleness off this so retained-message churn never forces
@@ -508,13 +508,13 @@ class TopicIndex:
                 stack.append((child, False))
 
     # ------------------------------------------------------------------
-    # Introspection (NFA compiler input, $SYS counters)
+    # Introspection (table compiler input, $SYS counters)
     # ------------------------------------------------------------------
 
     def all_subscriptions(self) -> list[tuple[str, str, Subscription, str]]:
         """All (filter, client_id, subscription, group) entries, materialized
         under the lock so callers iterate a stable snapshot. ``group`` is ''
-        for non-shared. Used by the NFA compiler."""
+        for non-shared. Used by the table compilers (sig.compile_sig)."""
         out: list[tuple[str, str, Subscription, str]] = []
         with self._lock:
             stack: list[tuple[_Node, list[str]]] = [(self._root, [])]

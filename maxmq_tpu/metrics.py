@@ -1070,6 +1070,9 @@ def _register_matcher_metrics(registry: Registry, broker) -> None:
                 "no loop hop); 0 until a traced whole-batch call or "
                 "shadow probe (trace_sample_n > 0)",
                 lambda: getattr(matcher, "device_round_trip", 0.0))
+        # a batcher (supervised or not) holds the engine; a bare engine
+        # is its own; a ServiceMatcher has none, and none of the series
+        # below (they are its sidecar's)
         eng = getattr(matcher, "engine", matcher)
         if hasattr(eng, "host_matches"):
             registry.counter_func(
@@ -1077,6 +1080,8 @@ def _register_matcher_metrics(registry: Registry, broker) -> None:
                 "Topics matched by the device-free host sig path "
                 "(bypass + single-topic surface, ADR 008)",
                 lambda: eng.host_matches)
+        # the ADR-008 router and the ADR-010 kernel plan are SigEngine's:
+        # a ShardedSigEngine has neither
         if hasattr(eng, "trie_routed"):
             registry.counter_func(
                 "maxmq_matcher_trie_routed_total",

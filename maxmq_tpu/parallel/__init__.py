@@ -1,7 +1,7 @@
 """Mesh-sharded (cluster-mode) matching: subscriptions partitioned into
-per-device NFA shards over a ('data', 'subs') mesh; matched row ids are
-reassembled across shards over the ICI."""
+per-device signature tables over a ('data', 'subs') mesh; matched row ids
+are reassembled across shards over the ICI."""
 
-from .sharded import ShardedNFAEngine, make_mesh
+from .sharded import ShardedSigEngine, make_mesh
 
-__all__ = ["ShardedNFAEngine", "make_mesh"]
+__all__ = ["ShardedSigEngine", "make_mesh"]

@@ -1,27 +1,28 @@
-"""Mesh-sharded NFA matcher: the cluster mode of the framework.
+"""Mesh-sharded signature matcher: the cluster mode of the framework.
 
 The reference's cluster design is a Route Table of topic-filter -> broker
 IDs with inter-broker PUBLISH forwarding (it exists only as a design doc:
 /root/reference/docs/system-design.md:201-231). TPU-native, the whole idea
 collapses into sharded evaluation + one gather: partition the
-*subscriptions* across the device mesh, compile one (small) NFA per shard,
-let every device walk its own NFA over its slice of the publish batch, and
-reassemble the per-shard matched row ids. The "route lookup + forward"
-becomes moving a few int32 row ids over the ICI.
+*subscriptions* across the device mesh, compile one (small) signature
+table per shard (matching/sig.py), let every device compare its own
+table against its slice of the publish batch, and reassemble the
+per-shard matched row ids. The "route lookup + forward" becomes moving a
+few int32 row ids over the ICI.
 
 Mesh axes:
   * ``data`` — data parallelism over the publish batch (each device matches
     a slice of the topics).
-  * ``subs`` — the scale axis: subscriptions are partitioned round-robin
-    into one NFA per mesh column, so 1M+ subscriptions never need one
-    device's HBM. Per-shard tables are padded to identical shapes and
+  * ``subs`` — the scale axis: subscriptions are partitioned by client
+    hash into one table per mesh column, so 1M+ subscriptions never need
+    one device's HBM. Per-shard tables are padded to identical shapes and
     stacked on a leading axis sharded over 'subs'.
 
-Outputs are per-shard row ids (out_spec P('subs', 'data', None)): the global
-result [sp, B, max_rows] stays sharded on device and the gather rides the
-ICI lazily when the host fetches it. Row ids are local to their shard; the
-host decodes via the matching shard's row_entries table (SubscriberSet
-union is shard-order independent).
+Outputs are per-shard fixed match slots (out_spec P('subs', 'data', None)):
+the global result [sp, B, 1 + max_rows] stays sharded on device and the
+gather rides the ICI lazily when the host fetches it. Row ids are local to
+their shard; the host decodes via the matching shard's row tables
+(SubscriberSet union is shard-order independent).
 """
 
 from __future__ import annotations
@@ -35,8 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..matching.engine import NFAEngine, match_batch_body
-from ..matching.nfa import NFATables, TableFull, compile_subscriptions
+from ..matching.sig import OverlayedEngine
 from ..matching.topics import batch_bucket
 from ..matching.trie import SubscriberSet, TopicIndex, subs_version
 
@@ -153,37 +153,6 @@ def make_multislice_mesh(n_slices: int | None = None,
     return Mesh(mesh_devices, axis_names=("slice", "data", "subs"))
 
 
-def compile_shards(subs, n_shards: int, version: int) -> list[NFATables]:
-    """Partition a subscription list round-robin and compile one NFA per
-    shard, all with a common edge-table size (grown together until every
-    shard's edges fit the probe bound)."""
-    buckets = [subs[i::n_shards] for i in range(n_shards)]
-    vocab: dict[str, int] = {}   # one intern pool => shard-uniform token ids
-    probe = [compile_subscriptions(b, version, vocab=vocab) for b in buckets]
-    size = max([8] + [t.table_size for t in probe])
-    if size == probe[0].table_size and all(
-            t.table_size == size for t in probe):
-        return probe
-    while True:
-        try:
-            return [compile_subscriptions(b, version, table_size=size,
-                                          vocab=vocab) for b in buckets]
-        except TableFull:
-            size *= 2
-
-
-def _sharded_match(tables_dev, toks, lengths, dollar, *, width, table_mask,
-                   max_rows):
-    """Runs INSIDE shard_map: this device's NFA shard (leading axis of
-    length 1, squeezed) over the local batch slice."""
-    local = tuple(t[0] for t in tables_dev)
-    rows, overflow = match_batch_body(
-        *local, toks, lengths, dollar,
-        width=width, table_mask=table_mask, max_rows=max_rows,
-        mesh_axes=("data", "subs"))
-    return rows[None], overflow[None]   # re-add the 'subs' axis
-
-
 def compile_sig_shards(subs, n_shards: int, version: int,
                        by_client: bool = True):
     """Partition subscriptions BY CLIENT (stable crc32 hash of client id)
@@ -235,9 +204,6 @@ def _sharded_sig_match(tables_dev, toks, lens_enc, *, sel_blocks, max_rows):
     out = fixed_slots_from_words(words, too_deep, sel_blocks, max_rows,
                                  fmt16=False)
     return out[None]                      # re-add the 'subs' axis
-
-
-from ..matching.sig import OverlayedEngine
 
 
 def sharded_sig_program(mesh: Mesh, subs_axes: tuple, sel_blocks: int,
@@ -477,8 +443,8 @@ class ShardedSigEngine(OverlayedEngine):
     def prewarm_decode_bases(self, chunk: int = 2048) -> int:
         """Cluster form of SigEngine.prewarm_decode_bases: populate the
         chained-decode anchors for every SHARD's table at a quiescent
-        point (called by the boot path and the background refresh via
-        getattr). Skipped when the shards compiled via the round-robin
+        point (called by the boot path and the background refresh).
+        Skipped when the shards compiled via the round-robin
         fallback (chain_ok False, state[7]) — the intents decode never
         runs there, so anchors would be pinned dead weight. Returns
         total chunk calls made."""
@@ -672,13 +638,6 @@ class ShardedSigEngine(OverlayedEngine):
     def subscribers(self, topic: str) -> SubscriberSet:
         return self.subscribers_batch([topic])[0]
 
-    async def subscribers_async(self, topic: str) -> SubscriberSet:
-        """Event-loop-friendly match (worker thread, like NFAEngine's)."""
-        import asyncio
-
-        loop = asyncio.get_running_loop()
-        return await loop.run_in_executor(None, self.subscribers, topic)
-
     def reshard(self, mesh: Mesh) -> None:
         """Elastic recovery: re-partition + recompile over a NEW mesh
         (e.g. after losing devices). Matching stays exact throughout —
@@ -691,144 +650,3 @@ class ShardedSigEngine(OverlayedEngine):
             self.mesh = mesh
             self._bind_mesh_axes()
         self.refresh(force=True)
-
-
-class ShardedNFAEngine:
-    """NFA matcher sharded over a ('data', 'subs') mesh.
-
-    Equivalent single-device engine: matching.engine.NFAEngine. This class
-    trades per-shard decode for an HBM footprint of subscriptions/``subs``
-    per device, and batch-throughput scaling of ``data``.
-    """
-
-    def __init__(self, index: TopicIndex, mesh: Mesh | None = None,
-                 width: int = 32, max_levels: int = 16,
-                 max_rows: int = 128) -> None:
-        self.index = index
-        self.mesh = mesh if mesh is not None else make_mesh()
-        self.width = width
-        self.max_levels = max_levels
-        self.max_rows = max_rows
-        self.dp = self.mesh.shape["data"]
-        self.sp = self.mesh.shape["subs"]
-        # (version, shards, dev_tables, fn): swapped as ONE attribute so a
-        # concurrent match_raw always pairs vocab, tables and compiled fn
-        self._state = None
-        self._refresh_lock = threading.Lock()
-        self.matches = 0
-        self.fallbacks = 0
-        self.refresh(force=True)
-
-    # ------------------------------------------------------------------
-
-    def refresh(self, force: bool = False) -> bool:
-        """Re-partition + recompile + re-shard if the index changed."""
-        with self._refresh_lock:
-            state = self._state
-            if (not force and state is not None
-                    and state[0] == subs_version(self.index)):
-                return False
-            version = subs_version(self.index)
-            shards = compile_shards(self.index.all_subscriptions(), self.sp,
-                                    version)
-
-            # pad node-indexed arrays to a common node count and stack
-            n_nodes = max(t.n_nodes for t in shards)
-            node_arrays = ("plus_child", "node_mask", "hash_mask")
-
-            def stack(name):
-                outs = []
-                for t in shards:
-                    a = getattr(t, name)
-                    if name in node_arrays and len(a) < n_nodes:
-                        a = np.pad(a, (0, n_nodes - len(a)),
-                                   constant_values=-1)
-                    outs.append(a)
-                return np.stack(outs)
-
-            mesh = self.mesh
-            by_shard = NamedSharding(mesh, P("subs"))
-            dev = tuple(
-                jax.device_put(stack(name), by_shard)
-                for name in ("hash_node", "hash_tok", "hash_val",
-                             "plus_child", "node_mask", "hash_mask"))
-            fn = self.build_fn(shards[0].table_size - 1)
-            self._state = (version, shards, dev, fn)
-            return True
-
-    def build_fn(self, table_mask: int):
-        """jit(shard_map) of the match step over the mesh."""
-        mesh = self.mesh
-        table_specs = tuple(P("subs") for _ in range(6))
-        fn = jax.shard_map(
-            partial(_sharded_match, width=self.width, table_mask=table_mask,
-                    max_rows=self.max_rows),
-            mesh=mesh,
-            in_specs=(table_specs, P("data"), P("data"), P("data")),
-            out_specs=(P("subs", "data", None), P("subs", "data")),
-        )
-        return jax.jit(fn)
-
-    def _compile_shards(self, version: int):
-        """Compile per-shard tables: client-hash first (chaining ok);
-        round-robin fallback when a heavy client overflows a bucket's
-        MAX_GROUPS (spreads shapes across shards, keeping the DEVICE
-        path alive at the cost of merge-free chaining); (None, None)
-        when even round-robin overflows."""
-        from ..matching.sig import MAX_GROUPS
-
-        subs = self.index.all_subscriptions()
-        shards = compile_sig_shards(subs, self.sp, version)
-        if all(len(t.groups) <= MAX_GROUPS for t in shards):
-            return shards, True
-        shards = compile_sig_shards(subs, self.sp, version,
-                                    by_client=False)
-        if all(len(t.groups) <= MAX_GROUPS for t in shards):
-            return shards, False
-        return None, None
-
-    # ------------------------------------------------------------------
-
-    def match_raw(self, topics: list[str]):
-        """Sharded device match. Pads the batch to a multiple of the data
-        axis. Returns (rows int32[sp, B, max_rows], overflow bool[sp, B],
-        shards) as numpy, batch-trimmed."""
-        self.refresh()
-        _version, shards, dev, fn = self._state
-        batch = len(topics)
-        padded = -(-batch // self.dp) * self.dp
-        # shards[0].tokenize: identical token ids across shards (toks are
-        # replicated over 'subs') — guaranteed by compile_shards assigning
-        # ids from a shared intern pass
-        toks, lengths, dollar = shards[0].tokenize(
-            topics + [""] * (padded - batch), self.max_levels)
-        rows, overflow = fn(
-            dev, jnp.asarray(toks), jnp.asarray(lengths),
-            jnp.asarray(dollar))
-        return (np.asarray(rows)[:, :batch], np.asarray(overflow)[:, :batch],
-                shards)
-
-    def subscribers_batch(self, topics: list[str]) -> list[SubscriberSet]:
-        rows, overflow, shards = self.match_raw(topics)
-        out = []
-        for i, topic in enumerate(topics):
-            self.matches += 1
-            if overflow[:, i].any():
-                self.fallbacks += 1
-                out.append(self.index.subscribers(topic))
-                continue
-            result = SubscriberSet()
-            for s, tables in enumerate(shards):
-                NFAEngine.decode(rows[s, i], tables, into=result)
-            out.append(result)
-        return out
-
-    def subscribers(self, topic: str) -> SubscriberSet:
-        return self.subscribers_batch([topic])[0]
-
-    async def subscribers_async(self, topic: str) -> SubscriberSet:
-        """Event-loop-friendly match (worker thread, like NFAEngine's)."""
-        import asyncio
-
-        loop = asyncio.get_running_loop()
-        return await loop.run_in_executor(None, self.subscribers, topic)

@@ -353,7 +353,7 @@ class PipelineTracer:
     Nth); ``slow_ms`` > 0 restricts flight-recorder capture to
     publishes at or past that end-to-end latency (0 captures every
     sampled publish); ``ring`` bounds the recorder. Mutable at runtime
-    — bench flips ``sample_n`` between phases.
+    — a harness flips ``sample_n`` between phases.
 
     Thread model: spans/finish run on the event loop; ``observe`` and
     ``note_error`` may fire from the storage writer thread or client
@@ -468,7 +468,7 @@ class PipelineTracer:
 
     def observe(self, stage: str, seconds: float) -> None:
         """Feed one stage histogram without a per-publish trace (the
-        journal's group commits, bench micro-measurements)."""
+        journal's group commits)."""
         self.stage_hist[stage].observe(seconds)
 
     def observe_journal(self, bucket: str, seconds: float) -> None:
@@ -739,8 +739,9 @@ class PipelineTracer:
         return len(self._ring)
 
     def stage_quantiles(self, qs=(0.5, 0.95, 0.99)) -> dict:
-        """{stage: {count, p50_ms, ...}} over stages with data — what
-        bench.py embeds as the BENCH_*.json ``trace`` stanza."""
+        """{stage: {count, p50_ms, ...}} over stages with data — the
+        ``trace`` stanza of a day harness's SLO sheet and of the
+        $SYS/HTTP trace surface."""
         out: dict = {}
         for stage, h in self.stage_hist.items():
             if not h.count:
@@ -765,8 +766,7 @@ class PipelineTracer:
         return out
 
     def cross_quantiles(self, qs=(0.5, 0.95, 0.99)) -> dict:
-        """Origin-measured cross-node e2e by hop count (ADR 017) —
-        what the ``cluster``/``failover`` bench stanzas embed as the
+        """Origin-measured cross-node e2e by hop count (ADR 017): the
         per-hop attribution row."""
         out: dict = {}
         for hops, h in sorted(self.cross_hist.items()):

@@ -198,7 +198,7 @@ class Config:
                                         # = allow-all
 
     # -- TPU matcher runtime (no reference equivalent: the north-star path) --
-    matcher: str = "sig"                # trie | nfa | dense | sig | service
+    matcher: str = "sig"                # trie | sig | service
     matcher_batch_window_us: int = 200
     matcher_max_batch: int = 256
     # native decode emits fan-out-ready DeliveryIntents (ADR 007)

@@ -11,8 +11,10 @@ from maxmq_tpu.matching.batcher import MicroBatcher
 from maxmq_tpu.matching.trie import TopicIndex
 from maxmq_tpu.protocol.packets import Subscription
 
+from matching_helpers import EngineStub
 
-class FakeEngine:
+
+class FakeEngine(EngineStub):
     """Records the batch shapes the batcher dispatches."""
 
     def __init__(self) -> None:
@@ -22,12 +24,6 @@ class FakeEngine:
     def subscribers_batch(self, topics):
         self.calls.append(list(topics))
         return [f"result:{t}" for t in topics]
-
-    def subscribers(self, topic):
-        return self.subscribers_batch([topic])[0]
-
-    def refresh(self, force=False):
-        return False
 
 
 async def test_concurrent_requests_coalesce():
@@ -83,15 +79,16 @@ async def test_engine_error_propagates():
         await batcher.close()
 
 
-async def test_batched_dense_engine_parity():
-    """End to end with the real dense device matcher: batched answers equal
+async def test_batched_sig_engine_parity():
+    """End to end with the real device matcher: batched answers equal
     the exact CPU trie."""
-    from maxmq_tpu.matching.dense import DenseEngine
+    from maxmq_tpu.matching.sig import SigEngine
 
     index = TopicIndex()
     for i, f in enumerate(["a/+", "a/b", "a/#", "x/y", "+/y", "$sys/#"]):
         index.subscribe(f"cl-{i}", Subscription(filter=f, qos=1))
-    engine = DenseEngine(index, max_levels=6)
+    engine = SigEngine(index, max_levels=6)
+    engine.route_small = False      # the device, not the ADR-008 router
     batcher = MicroBatcher(engine, window_us=500, max_batch=32)
     try:
         topics = ["a/b", "a/c", "x/y", "q/y", "$sys/health", "nope"] * 3
@@ -100,6 +97,7 @@ async def test_batched_dense_engine_parity():
         for topic, s in zip(topics, got):
             want = index.subscribers(topic)
             assert set(s.subscriptions) == set(want.subscriptions), topic
+        assert engine.matches == len(topics) and not batcher.bypasses
     finally:
         await batcher.close()
 
@@ -112,7 +110,7 @@ def test_batcher_delegates_sync_surface():
     assert batcher.index is eng.index
 
 
-class SplitEngine:
+class SplitEngine(EngineStub):
     """Dispatch/collect split with a slow collect: lets the pipelining
     test observe multiple batches in flight."""
 
@@ -144,7 +142,7 @@ class SplitEngine:
     def subscribers_batch(self, topics):
         return self.collect_fixed(topics, self.dispatch_fixed(topics))
 
-    def refresh(self, force=False):
+    def _routes_to_trie(self):
         return False
 
 

@@ -9,6 +9,8 @@ from __future__ import annotations
 import asyncio
 import urllib.request
 
+import pytest
+
 from maxmq_tpu.bootstrap import (build_broker, capabilities_from_config,
                                  run_server)
 from maxmq_tpu.cli import main, make_parser
@@ -61,23 +63,34 @@ class TestConfigMapping:
         assert broker.matcher is None  # trie = built-in CPU path
         assert len(broker.hooks) == 3  # logging + allow + storage
 
-    def test_build_broker_dense_matcher_is_batched(self):
+    def test_build_broker_sig_matcher_is_batched(self):
         from maxmq_tpu.matching.supervisor import SupervisedMatcher
 
         conf = Config(mqtt_tcp_address="", metrics_enabled=False,
-                      matcher="dense", matcher_max_levels=8)
+                      matcher="sig", matcher_max_levels=8)
         broker = build_broker(conf, quiet_logger())
         # ADR 011: the batcher ships wrapped in the degradation ladder
         assert isinstance(broker.matcher, SupervisedMatcher)
         assert isinstance(broker.matcher.inner, MicroBatcher)
         assert broker.matcher.index is broker.topics
+        assert broker.matcher.engine.max_levels == 8
 
     def test_build_broker_matcher_supervision_opt_out(self):
         conf = Config(mqtt_tcp_address="", metrics_enabled=False,
-                      matcher="dense", matcher_max_levels=8,
+                      matcher="sig", matcher_max_levels=8,
                       matcher_supervised=False)
         broker = build_broker(conf, quiet_logger())
         assert isinstance(broker.matcher, MicroBatcher)
+
+    @pytest.mark.parametrize("matcher", ["nfa", "dense"])
+    def test_deleted_engines_are_refused_by_name(self, matcher):
+        """``nfa`` and ``dense`` named engines that are gone: a file that
+        still asks for one fails at boot, told what exists."""
+        conf = Config(mqtt_tcp_address="", metrics_enabled=False,
+                      matcher=matcher)
+        with pytest.raises(ValueError, match=r"trie\|sig\|service") as err:
+            build_broker(conf, quiet_logger())
+        assert repr(matcher) in str(err.value)
 
 
 class TestAccelPolicy:

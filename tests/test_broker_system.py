@@ -289,25 +289,28 @@ async def test_retained_qos_downgrade_and_sub_qos():
         assert msg.qos == 0 and msg.payload == b"keep"
 
 
-async def test_broker_with_nfa_matcher_attached():
-    """Full path: PUBLISH over TCP -> NFA engine match -> fan-out."""
-    from maxmq_tpu.matching.engine import NFAEngine
+async def test_broker_with_bare_engine_attached():
+    """Full path: PUBLISH over TCP -> an engine attached with no batcher
+    (no ``enqueue``: the broker's ``subscribers_async`` road) -> fan-out."""
+    from maxmq_tpu.matching.sig import SigEngine
     async with running_broker() as broker:
-        broker.attach_matcher(NFAEngine(broker.topics))
+        engine = SigEngine(broker.topics)
+        broker.attach_matcher(engine)
         s = await connect(broker, "sub", version=5)
-        await s.subscribe(("nfa/+/path", 1), ("$share/g/nfa/shared", 0))
+        await s.subscribe(("eng/+/path", 1), ("$share/g/eng/shared", 0))
         p = await connect(broker, "pub")
-        await p.publish("nfa/hot/path", b"via-nfa", qos=1)
+        await p.publish("eng/hot/path", b"via-engine", qos=1)
         msg = await s.next_message()
-        assert (msg.topic, msg.payload, msg.qos) == ("nfa/hot/path", b"via-nfa", 1)
-        await p.publish("nfa/shared", b"shared-via-nfa")
+        assert (msg.topic, msg.payload, msg.qos) == ("eng/hot/path", b"via-engine", 1)
+        await p.publish("eng/shared", b"shared-via-engine")
         msg = await s.next_message()
-        assert msg.payload == b"shared-via-nfa"
+        assert msg.payload == b"shared-via-engine"
         # subscription mutations picked up by auto-refresh
-        await s.unsubscribe("nfa/+/path")
-        await p.publish("nfa/hot/path", b"after-unsub")
+        await s.unsubscribe("eng/+/path")
+        await p.publish("eng/hot/path", b"after-unsub")
         with pytest.raises(asyncio.TimeoutError):
             await s.next_message(timeout=0.3)
+        assert engine.matches == 3      # every publish asked the engine
 
 
 async def test_broker_with_sig_matcher_intents():

@@ -38,8 +38,9 @@ def one_chip(topo):
 
 
 def corpus_index(n: int, seed: int = 42) -> TopicIndex:
-    """bench.build_corpus's ``+``/``#`` mix with 10% ``$share`` (the
-    shape chip_smoke.py serves at 1M), at ``n`` filters."""
+    """The fleet corpus's ``+``/``#`` mix with 10% ``$share`` (the
+    shape chip_smoke.py and the benchmark's ``fleet-1m`` serve at 1M),
+    at ``n`` filters."""
     rng = random.Random(seed)
     alphabet = [f"{c}{i}" for c in "abcdefgh" for i in range(12)]
     index = TopicIndex()

@@ -28,7 +28,7 @@ from maxmq_tpu.matching import TopicIndex
 from maxmq_tpu.matching.sig import SigEngine
 from maxmq_tpu.protocol import Subscription
 
-from test_nfa_parity import normalize
+from matching_helpers import normalize
 
 ALPHABET = [f"s{i}" for i in range(10)]
 

@@ -5,9 +5,8 @@ Perfetto tracks), old-peer envelope compatibility (the flag bit is
 capability-negotiated away), clock-skew estimation with scripted
 per-broker clocks, the federated ``/cluster/metrics`` page +
 cardinality bounds, the ADR-015 closure items (QoS2 release-leg span,
-per-bucket journal attribution), the zero-allocations-when-off
-contract across the propagation path, and the bench-regression gate
-(scripts/bench_compare.py) against synthetic rounds."""
+per-bucket journal attribution), and the zero-allocations-when-off
+contract across the propagation path."""
 
 import asyncio
 import importlib.util
@@ -548,39 +547,6 @@ async def test_sys_cluster_health_subtree():
         assert f"{base}/sess_lag" in entries
     finally:
         await close_cluster(brokers)
-
-
-def test_bench_compare_gate(tmp_path):
-    bc = _load_script("bench_compare.py")
-    old = {"parsed": {"detail": {"configs": [
-        {"config": "overload", "msgs_per_sec": 1000.0,
-         "trace": {"e2e": {"qos1": {"p99_ms": 10.0}}}}]}}}
-    new_ok = {"parsed": {"detail": {"configs": [
-        {"config": "overload", "msgs_per_sec": 980.0,
-         "trace": {"e2e": {"qos1": {"p99_ms": 10.5}}}}]}}}
-    new_bad = {"parsed": {"detail": {"configs": [
-        {"config": "overload", "msgs_per_sec": 500.0,
-         "trace": {"e2e": {"qos1": {"p99_ms": 30.0}}}}]}}}
-    p1 = tmp_path / "BENCH_r01.json"
-    p2 = tmp_path / "BENCH_r02.json"
-    p1.write_text(json.dumps(old))
-    p2.write_text(json.dumps(new_ok))
-    assert bc.main([str(p1), str(p2),
-                    "--root", str(tmp_path)]) == 0
-    p2.write_text(json.dumps(new_bad))
-    rc = bc.main([str(p1), str(p2), "--root", str(tmp_path)])
-    assert rc > 0          # throughput -50% AND p99 3x: blocking
-    assert bc.main([str(p1), str(p2), "--root", str(tmp_path),
-                    "--warn-only"]) == 0
-    # tail recovery: the driver-truncated shape still yields rows
-    doc = bc.load_round(str(p2))
-    assert bc.extract_rows(doc)["overload"]["msgs_per_sec"] == 500.0
-    tail_only = {"parsed": None, "tail": 'junk..."configs": [] '
-                 + json.dumps({"config": "c1", "msgs_per_sec": 7.0})}
-    p3 = tmp_path / "BENCH_r03.json"
-    p3.write_text(json.dumps(tail_only))
-    rows = bc.extract_rows(bc.load_round(str(p3)))
-    assert rows["c1"]["msgs_per_sec"] == 7.0
 
 
 def test_checker_self_test_covers_new_families():

@@ -15,7 +15,7 @@ import time
 import pytest
 
 from test_broker_system import connect, running_broker
-from test_nfa_parity import normalize
+from matching_helpers import normalize
 
 from maxmq_tpu import faults
 from maxmq_tpu.matching.batcher import MicroBatcher

@@ -61,11 +61,13 @@ class TestTokenizer:
 
     def test_engine_uses_native_tokenizer(self):
         from maxmq_tpu.matching import TopicIndex
-        from maxmq_tpu.matching.dense import DenseEngine
+        from maxmq_tpu.matching.sig import SigEngine
         idx = TopicIndex()
         idx.subscribe("c1", Subscription(filter="a/+"))
-        engine = DenseEngine(idx)
-        assert sorted(engine.subscribers("a/b").subscriptions) == ["c1"]
+        engine = SigEngine(idx)
+        engine.route_small = False      # not the ADR-008 trie router
+        (got,) = engine.subscribers_host_batch(["a/b"])
+        assert sorted(got.subscriptions) == ["c1"]
         assert engine.tables.__dict__.get("_native_vocab") is not None
 
 

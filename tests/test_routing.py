@@ -7,7 +7,7 @@ from maxmq_tpu.matching import TopicIndex
 from maxmq_tpu.matching.sig import SigEngine
 from maxmq_tpu.protocol import Subscription
 
-from test_nfa_parity import normalize
+from matching_helpers import normalize
 
 
 def _as_set(r):

@@ -8,7 +8,7 @@ import tempfile
 import pytest
 
 from test_broker_system import connect, running_broker
-from test_nfa_parity import normalize
+from matching_helpers import normalize
 
 from maxmq_tpu.matching.batcher import MicroBatcher
 from maxmq_tpu.matching.service import (MatcherService, ServiceMatcher,

@@ -7,8 +7,10 @@ import pytest
 import jax
 
 from maxmq_tpu.matching.trie import TopicIndex
-from maxmq_tpu.parallel.sharded import ShardedNFAEngine, make_mesh
+from maxmq_tpu.parallel.sharded import ShardedSigEngine, make_mesh
 from maxmq_tpu.protocol.packets import Subscription
+
+from matching_helpers import normalize
 
 ALPHABET = ["alpha", "beta", "gamma", "delta", "eps", "zeta"]
 
@@ -53,26 +55,6 @@ def assert_same(got, want, topic):
         assert set(got.shared[key]) == set(members), (topic, key)
 
 
-@pytest.mark.parametrize("shape", [(1, 8), (2, 4), (4, 2)])
-def test_sharded_parity_vs_trie(shape):
-    filters, topics = random_corpus(300, 64, seed=shape[0] * 31 + shape[1])
-    index = build_index(filters)
-    mesh = make_mesh(shape=shape)
-    engine = ShardedNFAEngine(index, mesh=mesh, width=32, max_levels=8)
-    got = engine.subscribers_batch(topics)
-    for topic, s in zip(topics, got):
-        assert_same(s, index.subscribers(topic), topic)
-
-
-def test_sharded_tracks_index_mutations():
-    filters, topics = random_corpus(50, 16, seed=9)
-    index = build_index(filters)
-    engine = ShardedNFAEngine(index, width=32, max_levels=8)
-    index.subscribe("late", Subscription(filter="alpha/#", qos=1))
-    got = engine.subscribers("alpha/beta")
-    assert "late" in got.subscriptions
-
-
 def test_make_mesh_default_shape():
     mesh = make_mesh()
     assert mesh.devices.size == len(jax.devices())
@@ -101,9 +83,6 @@ def test_graft_entry_multichip():
 
 
 # ----------------------------------------------------- sharded sig engine
-
-from maxmq_tpu.parallel.sharded import ShardedSigEngine
-
 
 @pytest.mark.parametrize("shape", [(1, 8), (2, 4), (4, 2)])
 def test_sharded_sig_parity_vs_trie(shape):
@@ -244,7 +223,6 @@ async def test_cluster_broker_qos12_offline_redelivery():
 
     from maxmq_tpu.matching.batcher import MicroBatcher
     from maxmq_tpu.mqtt_client import MQTTClient
-    from maxmq_tpu.parallel.sharded import ShardedSigEngine
 
     async with running_broker() as broker:
         eng = ShardedSigEngine(broker.topics, mesh=make_mesh())
@@ -294,13 +272,11 @@ def test_sharded_chain_in_chain_parity():
     CHAINED intents (fat '#' bucket split across client-hash shards)
     iterate correctly inside the cluster-level ChainedIntents — no
     duplicate clients, exact trie parity, n/len/to_set agree."""
-    from test_nfa_parity import normalize
-
     from maxmq_tpu.native import decode_module
     mod = decode_module()
     if mod is None or not hasattr(mod, "_set_chain_params"):
         pytest.skip("maxmq_decode extension unavailable")
-    from maxmq_tpu.parallel.sharded import ChainedIntents, ShardedSigEngine
+    from maxmq_tpu.parallel.sharded import ChainedIntents
 
     idx = TopicIndex()
     for i in range(200):
@@ -350,12 +326,10 @@ def test_sharded_intents_parity(seed):
     match the CPU trie exactly (client-hash sharding makes the chain
     merge-free), including $share groups spanning shards and the
     to_set()/resolve surface."""
-    from test_nfa_parity import normalize
-
     from maxmq_tpu.native import decode_module
     if decode_module() is None:
         pytest.skip("maxmq_decode extension unavailable")
-    from maxmq_tpu.parallel.sharded import ChainedIntents, ShardedSigEngine
+    from maxmq_tpu.parallel.sharded import ChainedIntents
 
     filters, topics = random_corpus(250, 120, seed)
     idx = TopicIndex()
@@ -397,7 +371,7 @@ def test_sharded_intents_resolve(registry):
     from maxmq_tpu.native import decode_module
     if decode_module() is None:
         pytest.skip("maxmq_decode extension unavailable")
-    from maxmq_tpu.parallel.sharded import ChainedIntents, ShardedSigEngine
+    from maxmq_tpu.parallel.sharded import ChainedIntents
 
     idx = TopicIndex()
     for i in range(60):
@@ -438,7 +412,6 @@ async def test_sharded_intents_broker_delivery():
     from test_broker_system import connect, running_broker
 
     from maxmq_tpu.matching.batcher import MicroBatcher
-    from maxmq_tpu.parallel.sharded import ShardedSigEngine
 
     async with running_broker() as broker:
         eng = ShardedSigEngine(broker.topics, mesh=make_mesh())
@@ -469,9 +442,7 @@ def test_heavy_client_falls_back_to_round_robin(monkeypatch):
     round-robin (spreading the shapes) and turns chaining off, with
     exact results either way."""
     import maxmq_tpu.matching.sig as sigmod
-    from test_nfa_parity import normalize
-
-    from maxmq_tpu.parallel.sharded import ChainedIntents, ShardedSigEngine
+    from maxmq_tpu.parallel.sharded import ChainedIntents
 
     monkeypatch.setattr(sigmod, "MAX_GROUPS", 4)
     idx = TopicIndex()
@@ -498,7 +469,6 @@ def test_client_hash_empty_buckets_ok():
     from maxmq_tpu.native import decode_module
     if decode_module() is None:
         pytest.skip("maxmq_decode extension unavailable")
-    from maxmq_tpu.parallel.sharded import ShardedSigEngine
 
     idx = TopicIndex()
     idx.subscribe("only-a", Subscription(filter="eb/+/t", qos=1))
@@ -518,9 +488,6 @@ def test_sharded_host_batch_parity(seed):
     """Cluster-mode device-free path (subscribers_host_batch: per-shard
     exact/'+'/'#' host probes + chained native decode, no mesh
     dispatch) matches the CPU trie exactly in both result forms."""
-    from test_nfa_parity import normalize
-
-    from maxmq_tpu.parallel.sharded import ShardedSigEngine
 
     filters, topics = random_corpus(250, 120, seed)
     idx = TopicIndex()

@@ -1,7 +1,8 @@
-"""Golden parity for the signature matcher (grouped hash-equality, the
-transfer-optimal TPU path): all three device output forms (match words,
-compact row stream, fixed slots) must agree exactly with the CPU reference
-trie on the corpora the NFA/dense matchers are held to."""
+"""The signature matcher (grouped hash-equality) beyond the golden corpora
+of test_golden_parity.py: its three device output forms (match words,
+compact row stream, fixed slots) against the CPU reference trie on
+randomized corpora, its depth windows and overflows, the kernel plan, and
+the staleness overlay."""
 
 import os
 import random
@@ -13,7 +14,7 @@ from maxmq_tpu.matching import TopicIndex
 from maxmq_tpu.matching.sig import SigEngine, compile_sig, tokenize_compact
 from maxmq_tpu.protocol import Subscription
 
-from test_nfa_parity import normalize, rand_corpus
+from matching_helpers import normalize, rand_corpus
 
 PATHS = ["word", "compact", "fixed"]
 
@@ -50,55 +51,6 @@ def check_parity(index, topics, paths=PATHS, **engine_kw):
     return engine
 
 
-def test_exact_and_wildcard_basics():
-    idx = TopicIndex()
-    idx.subscribe("c1", Subscription(filter="a/b/c", qos=1))
-    idx.subscribe("c2", Subscription(filter="a/+/c", qos=2))
-    idx.subscribe("c3", Subscription(filter="a/#"))
-    idx.subscribe("c4", Subscription(filter="#"))
-    idx.subscribe("c5", Subscription(filter="+"))
-    check_parity(idx, ["a/b/c", "a/x/c", "a", "a/b", "x", "x/y",
-                       "a/b/c/d", "$SYS/x", "$SYS"])
-
-
-def test_hash_parent_and_dollar_rules():
-    idx = TopicIndex()
-    idx.subscribe("c1", Subscription(filter="sport/tennis/#"))
-    idx.subscribe("c2", Subscription(filter="$SYS/#"))
-    idx.subscribe("c3", Subscription(filter="$SYS/+/x"))
-    idx.subscribe("c4", Subscription(filter="+/tennis/+"))
-    check_parity(idx, ["sport/tennis", "sport/tennis/p1", "sport",
-                       "$SYS/broker/x", "$SYS/broker", "$SYS",
-                       "a/tennis/b"])
-
-
-def test_empty_levels_and_unknown_tokens():
-    idx = TopicIndex()
-    idx.subscribe("c1", Subscription(filter="/"))
-    idx.subscribe("c2", Subscription(filter="//"))
-    idx.subscribe("c3", Subscription(filter="+/"))
-    idx.subscribe("c4", Subscription(filter="a//b"))
-    check_parity(idx, ["/", "//", "a//b", "never-seen-token/x", "a/b",
-                       "never/", "/"])
-
-
-def test_shared_subscriptions_parity():
-    idx = TopicIndex()
-    idx.subscribe("w1", Subscription(filter="$share/g1/t/+"))
-    idx.subscribe("w2", Subscription(filter="$share/g1/t/+"))
-    idx.subscribe("w3", Subscription(filter="$share/g2/t/a"))
-    idx.subscribe("n1", Subscription(filter="t/a", qos=1))
-    check_parity(idx, ["t/a", "t/b", "t", "x"])
-
-
-def test_overlap_merge_semantics():
-    idx = TopicIndex()
-    idx.subscribe("c1", Subscription(filter="m/+", qos=0, identifier=3))
-    idx.subscribe("c1", Subscription(filter="m/x", qos=2, identifier=9))
-    idx.subscribe("c1", Subscription(filter="m/#", qos=1, identifier=4))
-    check_parity(idx, ["m/x", "m/y", "m"])
-
-
 def test_exact_rows_match_on_host():
     # exact-shape filters (full-literal AND '+') never occupy device
     # table width: both are host equality probes; the device carries
@@ -115,14 +67,6 @@ def test_exact_rows_match_on_host():
                for r in p.rows) == 1
     # device rows: only the '#' filter (one group, one padded word)
     assert int(t.group_words.sum()) == 1
-
-
-def test_too_deep_topic_falls_back():
-    idx = TopicIndex()
-    idx.subscribe("c1", Subscription(filter="a/#"))
-    deep = "a/" + "/".join(str(i) for i in range(80))
-    engine = check_parity(idx, [deep], max_levels=8)
-    assert engine.fallbacks > 0
 
 
 def test_mid_depth_filter_matches_via_compact_window():
@@ -162,26 +106,6 @@ def test_fixed_slot_overflow_falls_back():
     got = engine2.subscribers_fixed_batch(["x/y/s0/t"])[0]
     want = idx2.subscribers("x/y/s0/t")
     assert normalize(got) == normalize(want)
-
-
-def test_incremental_refresh():
-    idx = TopicIndex()
-    idx.subscribe("c1", Subscription(filter="a/b"))
-    engine = SigEngine(idx)
-    assert normalize(engine.subscribers("a/b"))[0].keys() == {"c1"}
-    idx.subscribe("c2", Subscription(filter="a/+"))
-    got = engine.subscribers("a/b")
-    assert sorted(got.subscriptions) == ["c1", "c2"]
-    idx.unsubscribe("c1", "a/b")
-    got = engine.subscribers("a/b")
-    assert sorted(got.subscriptions) == ["c2"]
-
-
-def test_empty_index():
-    idx = TopicIndex()
-    engine = SigEngine(idx)
-    assert len(engine.subscribers("a/b")) == 0
-    assert len(engine.subscribers_fixed_batch(["a/b"])[0]) == 0
 
 
 def test_tokenize_compact_encoding():
@@ -1457,3 +1381,65 @@ def test_randomized_mixed_width_churn_parity(monkeypatch):
         for topic, result in zip(batch, got):
             want = idx.subscribers(topic)
             assert normalize(result) == normalize(want), (step, topic)
+
+
+def test_sig_dual_width_kernel_raw_outputs(monkeypatch):
+    """Dual-width signature kernels at the RAW output level: on one
+    compiled table set, the mixed-width program's per-topic candidate
+    counts must be a superset of the 32-bit-forced program's wherever
+    neither overflows (a 16-bit fold can only add host-verified false
+    candidates or overflow — never drop a true match), and the row
+    slots must agree exactly on topics where the counts agree."""
+    import maxmq_tpu.matching.sig as sigmod
+    from maxmq_tpu.matching import sig_pallas
+    from maxmq_tpu.matching.sig import prepare_batch
+
+    monkeypatch.setattr(sigmod, "W16_MAX_GROUP_ROWS", 8)
+    idx = TopicIndex()
+    for i in range(30):
+        idx.subscribe(f"w{i}", Subscription(filter=f"k{i}/#", qos=1))
+    for i in range(5):
+        idx.subscribe(f"n{i}", Subscription(filter=f"m/z{i}/#", qos=2))
+    engine = SigEngine(idx, use_pallas=True, fixed_max_rows=7)
+    assert engine.pallas_active
+    tables, consts = engine._state[0], engine._state[1]
+    assert tables.group_w16.any() and (~tables.group_w16).any()
+
+    rng = random.Random(6)
+    topics = ([f"k{i}/t" for i in range(30)]
+              + [f"m/z{i}/d/e" for i in range(5)]
+              + ["m/q", "$SYS/x", "none"]
+              + ["/".join(rng.choice(["k0", "m", "z0", "q"])
+                          for _ in range(rng.randint(1, 4)))
+                 for _ in range(20)])
+    toks8, lens_enc, _ = prepare_batch(tables, topics)
+
+    outs = {}
+    for label, force in (("mixed", False), ("force32", True)):
+        kplan = sig_pallas.plan(tables, force_width32=force)
+        assert kplan is not None
+        fn, fmt = sig_pallas.build_fixed_fn(tables, consts, kplan,
+                                            max_rows=7)
+        assert fmt["kind"] == "stream"
+        cnt, stream = fn(toks8, lens_enc)
+        outs[label] = (np.asarray(cnt), np.asarray(stream))
+
+    m_cnt, m_stream = outs["mixed"]
+    f_cnt, f_stream = outs["force32"]
+    both = (m_cnt != 0xFF) & (f_cnt != 0xFF)
+    assert both.any()
+    assert (m_cnt[both].astype(int) >= f_cnt[both].astype(int)).all()
+    # where the counts agree, the row slots must be identical (stream
+    # is topic-ordered; walk both with per-arm offsets)
+    mo = fo = 0
+    checked = 0
+    for i in range(len(topics)):
+        mc = int(m_cnt[i]) if m_cnt[i] != 0xFF else 0
+        fc = int(f_cnt[i]) if f_cnt[i] != 0xFF else 0
+        if m_cnt[i] != 0xFF and f_cnt[i] != 0xFF and mc == fc:
+            assert np.array_equal(m_stream[mo:mo + mc],
+                                  f_stream[fo:fo + fc]), topics[i]
+            checked += 1
+        mo += mc
+        fo += fc
+    assert checked, "no comparable topics"

@@ -16,7 +16,7 @@ import pytest
 
 from test_broker_system import connect, running_broker
 from test_faults import small_corpus as corpus
-from test_nfa_parity import normalize
+from matching_helpers import EngineStub, normalize
 
 from maxmq_tpu.matching.batcher import MicroBatcher
 from maxmq_tpu.matching.service import ServiceMatcher
@@ -27,7 +27,7 @@ from maxmq_tpu.matching.trie import TopicIndex
 from maxmq_tpu.trace import PipelineTracer
 
 
-class StubEngine:
+class StubEngine(EngineStub):
     """An engine whose answers are the trie's, and which can be told to
     raise, or to hang until released."""
 
@@ -44,14 +44,6 @@ class StubEngine:
         if self.raising:
             raise RuntimeError("device on fire")
         return [self.index.subscribers(t) for t in topics]
-
-    def subscribers(self, topic):
-        return self.subscribers_batch([topic])[0]
-
-
-class HostStubEngine(StubEngine):
-    def subscribers_host_batch(self, topics):
-        return self.subscribers_batch(topics)
 
 
 class BareEnqueue:
@@ -184,7 +176,7 @@ def _raising_whole_batch():
 
 
 def _raising_bypass():
-    eng = HostStubEngine()
+    eng = StubEngine()
     eng.raising = True
     batcher = MicroBatcher(eng, window_us=2000)
     batcher._device_rtt = 1.0              # every batch is bypassed
@@ -334,7 +326,7 @@ def _bypass_host(batcher, eng):
 
 
 def _bypass_trie(batcher, eng):
-    batcher._device_rtt = 1.0
+    batcher._device_rtt, batcher._trie_cost = 1.0, 1e-9
 
 
 def _hang(batcher, eng):
@@ -343,7 +335,7 @@ def _hang(batcher, eng):
 
 @pytest.mark.parametrize("via, engine, arrange", [
     ("cache", StubEngine, _cache_hit),
-    ("host", HostStubEngine, _bypass_host),
+    ("host", StubEngine, _bypass_host),
     ("trie", StubEngine, _bypass_trie),
     ("fallback", StubEngine, _hang),
 ])

@@ -16,6 +16,7 @@ import urllib.request
 
 import pytest
 
+from matching_helpers import EngineStub
 from test_broker_system import connect, running_broker
 
 from maxmq_tpu import faults
@@ -301,15 +302,12 @@ async def test_matcher_pipeline_split_spans_through_supervisor():
     from maxmq_tpu.matching.batcher import MicroBatcher
     from maxmq_tpu.matching.supervisor import SupervisedMatcher
 
-    class _TrieEngine:
+    class _TrieEngine(EngineStub):
         def __init__(self, index):
             self.index = index
 
         def subscribers_batch(self, topics):
             return [self.index.subscribers(t) for t in topics]
-
-        def refresh(self, force=False):
-            return False
 
     async with running_broker(trace_sample_n=1) as broker:
         batcher = MicroBatcher(_TrieEngine(broker.topics),

@@ -17,8 +17,7 @@ Four angles from the ISSUE-15 acceptance sheet:
 * one correlated trace — a sampled cross-worker publish renders both
   workers' legs in a single /traces/chrome document
 
-Single-core box: these assert semantics and invariants, never speedup
-(bench.py config ``cshard`` owns the scaling curve).
+Single-core box: these assert semantics and invariants, never speedup.
 """
 
 import asyncio
