@@ -85,24 +85,45 @@ def _droppable_qos0(item) -> bool:
 
 
 class FlushScheduler:
-    """Per-loop-iteration getter-wake coalescing (ADR 019). A 1→N
-    fan-out enqueues its N deliveries synchronously; completing each
-    parked getter future inline schedules N task wake-ups before the
-    fan-out loop finishes, and a client hit K times in one iteration
-    is scheduled K times. Deferring the completions to one
-    ``loop.call_soon`` callback wakes each writer exactly once per
-    iteration — after its FULL backlog is queued, so the greedy burst
-    sees everything on its first dequeue."""
+    """Per-loop-iteration write coalescing (ADR 019). A 1→N fan-out
+    enqueues its N deliveries synchronously; completing each parked
+    getter future inline schedules N task wake-ups before the fan-out
+    loop finishes, and a client hit K times in one iteration is
+    scheduled K times. Each parked wake waits instead for one pass,
+    run after the FULL backlog is queued.
+
+    The pass writes. For every parked queue whose writer task is still
+    idle it asks the owner to hand the backlog to the socket itself
+    (``Client._write_direct``: one burst, into an empty transport
+    buffer only), so a delivery pays no task wake-up: ``direct``. What
+    the owner may not finish goes to the writer task by completing the
+    getter, as every wake did before: ``woken``, by reason
+    (``backpressure``: the transport still holds bytes, or the burst
+    cap left a rest; ``fault``: a ``client.write`` fault is armed and
+    may ask for an awaited stall; ``facade``: the writer shows no
+    transport to ask; ``stop``: the client is closing; ``error``: the
+    direct write raised, which ends that client's writer and nobody
+    else's). The task is what back-pressure needs, and nothing else.
+
+    The pass runs from ``loop.call_soon`` (the next iteration, first in
+    line) or earlier, where a producer that queued many deliveries
+    calls ``flush_now`` as it runs dry (the publish pipeline's
+    consumer); the ``call_soon`` already scheduled then finds nothing
+    pending."""
 
     __slots__ = ("_pending", "_scheduled", "flushes", "deferred",
-                 "coalesced")
+                 "coalesced", "direct", "woken")
 
     def __init__(self) -> None:
         self._pending: list = []
         self._scheduled = False
-        self.flushes = 0        # call_soon flush passes run
+        self.flushes = 0        # passes that found something parked
         self.deferred = 0       # wakes parked for a flush pass
         self.coalesced = 0      # duplicate wakes absorbed by one park
+        self.direct = 0         # bursts the pass wrote itself
+        # bursts handed to the writer task, by reason
+        self.woken = {"backpressure": 0, "fault": 0, "facade": 0,
+                      "stop": 0, "error": 0}
 
     def defer(self, q: "OutboundQueue") -> bool:
         """Park one queue's getter wake; False when no loop is running
@@ -124,12 +145,33 @@ class FlushScheduler:
 
     def _flush(self) -> None:
         self._scheduled = False
-        pending, self._pending = self._pending, []
+        self.flush_now()
+
+    def flush_now(self) -> None:
+        """Run the pending pass here, in the caller's loop step."""
+        pending = self._pending
+        if not pending:
+            return
+        self._pending = []
         self.flushes += 1
         for q in pending:
             q._wake_deferred = False
             g = q._getter
-            if g is not None and not g.done():
+            if g is None or g.done():
+                continue            # the task is awake: it will look
+            direct = q._direct
+            try:
+                reason = "facade" if direct is None else direct()
+            except Exception:
+                # one owner's fault stays its own: the pass goes on to
+                # the other queues, and this one's task is woken unless
+                # the owner already ended it
+                reason = "error"
+            if reason is None:
+                self.direct += 1
+                continue
+            self.woken[reason] = self.woken.get(reason, 0) + 1
+            if not g.done():
                 g.set_result(None)
 
 
@@ -138,7 +180,9 @@ class OutboundQueue:
     (ADR 012). Each entry carries the byte size charged at enqueue, so
     the per-client ledger (``self.bytes``) and the broker-global ledger
     (``overload.queued_bytes``) stay exact without re-deriving sizes at
-    dequeue. The sole consumer is the client's writer task."""
+    dequeue. The sole consumer is its owner's burst writer
+    (``Client._write_burst``), run by the client's writer task or,
+    while that task is parked in ``wait``, by the flush pass."""
 
     def __init__(self, maxsize: int, overload=None,
                  scheduler: FlushScheduler | None = None) -> None:
@@ -151,6 +195,9 @@ class OutboundQueue:
         # otherwise (inline clients, queues built outside a broker)
         self._scheduler = scheduler
         self._wake_deferred = False
+        # the owner's direct write, asked by the scheduler's pass while
+        # the getter is parked (Client.start sets it); None = wake only
+        self._direct = None
         self.bytes = 0
         # cumulative entry counters (ADR 015): a drain-span watcher
         # registered at enqueue seq S is settled by the first flush
@@ -184,13 +231,22 @@ class OutboundQueue:
         self.removed += 1
         return item
 
-    async def get(self):
+    def peek(self):
+        """The head entry's item, left queued (and so accounted)."""
+        return self._q[0][0]
+
+    async def wait(self) -> None:
+        """Park until something is queued; the consumer dequeues it
+        itself, synchronously."""
         while not self._q:
             self._getter = asyncio.get_running_loop().create_future()
             try:
                 await self._getter
             finally:
                 self._getter = None
+
+    async def get(self):
+        await self.wait()
         return self.get_nowait()
 
     def _account_out(self, size: int) -> None:
@@ -291,7 +347,7 @@ class Client:
         self.drops_by_reason: dict[str, int] = {}
         # ADR 015 drain watchers: (trace, enqueue_ns, enqueue_seq)
         # triples the server registers for sampled deliveries; the
-        # writer loop settles each after the first flush that covers
+        # burst writer settles each after the first burst that covers
         # its seq (one branch per burst when empty)
         self._drain_traces: list = []
         # ADR 017 QoS2 release-leg stopwatches: pid -> PUBREC-sent ns
@@ -368,6 +424,7 @@ class Client:
                 except (AttributeError, RuntimeError):
                     pass
             self.write_progress = time.monotonic()
+            self.outbound._direct = self._write_direct
             self._writer_task = asyncio.get_running_loop().create_task(
                 self._write_loop(), name=f"mq-write-{self.id or id(self)}")
 
@@ -426,13 +483,12 @@ class Client:
                 return
 
     def _write_fault_delay(self) -> float:
-        """0.0 unless a client.write fault applies to this client —
-        then the seconds the writer must stall (hang mode). Kept sync
-        and gated on any_armed() so the idle-registry production cost
-        is one predicate call per written packet; raise-mode faults
-        propagate to the write loop as a recorded writer death."""
-        if not faults.REGISTRY.any_armed():
-            return 0.0
+        """The seconds a client.write fault asks this client's writer
+        to stall before its next item (hang mode), else 0.0. Sync, and
+        asked only while faults.REGISTRY.any_armed(), so the
+        idle-registry production cost is one predicate call per burst;
+        raise-mode faults propagate to the write loop as a recorded
+        writer death."""
         hit = faults.fire_detail(faults.CLIENT_WRITE, key=self.id)
         return hit[1] if hit is not None and hit[0] == "hang" else 0.0
 
@@ -466,63 +522,34 @@ class Client:
         bufs.clear()
 
     async def _write_loop(self) -> None:
+        """The writer task: what back-pressure needs. It parks on the
+        outbound queue; an idle writer's backlog is written by the
+        flush pass (``_write_direct``) without waking it. Woken, it
+        writes burst after burst through the same ``_write_burst`` and
+        awaits the transport's ``drain()`` between them: past the
+        transport's high-water mark that blocks until the consumer
+        catches up, so a slow consumer's backlog stays in the
+        byte-accounted queue where the stall detector and the budgets
+        see it (ADR 012). A ``client.write`` hang fault is slept here."""
         assert self.writer is not None
-        get_nowait = self.outbound.get_nowait
-        info = self.server.info
-        # wire buffers collected across the burst, flushed through ONE
-        # transport.writelines per burst (or before any Packet item,
-        # which must encode+write in order)
-        bufs: list = []
+        q = self.outbound
+        stall = 0.0
         try:
             while True:
-                packet = await self.outbound.get()
-                burst = 0
-                # greedy drain: one task wake-up flushes everything queued
-                # (one await per BURST, not per packet), bounded in bytes
-                while packet is not None:
-                    stall = self._write_fault_delay()
-                    if stall:
-                        # deterministic slow consumer: stall THIS writer
-                        # without blocking the loop (tests/bench arm
-                        # client.write#<id>; see faults.fire_detail)
-                        await asyncio.sleep(stall)
-                    t = type(packet)
-                    if t is bytes:             # pre-encoded fast path
-                        bufs.append(packet)
-                        n = len(packet)
-                        info.bytes_sent += n
-                        info.packets_sent += 1
-                        burst += n
-                        if packet[0] >> 4 == PT.PUBLISH:
-                            info.messages_sent += 1
-                    elif t is tuple:           # ADR 019 buffer sequence
-                        n = 0
-                        for b in packet:
-                            n += len(b)
-                        bufs.extend(packet)
-                        info.bytes_sent += n
-                        info.packets_sent += 1
-                        burst += n
-                        if packet[0][0] >> 4 == PT.PUBLISH:
-                            info.messages_sent += 1
-                    else:
-                        if bufs:               # keep the wire in order
-                            self._flush_bufs(bufs)
-                        self._write_packet(packet)
-                        burst += _estimate_wire(packet)
-                    if burst >= self.BURST_BYTES:
-                        break
-                    try:
-                        packet = get_nowait()
-                    except asyncio.QueueEmpty:
-                        break
+                if stall:
+                    # deterministic slow consumer: stall THIS writer
+                    # without blocking the loop (tests/bench arm
+                    # client.write#<id>; see faults.fire_detail)
+                    await asyncio.sleep(stall)
                 else:
-                    break                      # drained a None: stop
-                if bufs:
-                    self._flush_bufs(bufs)
-                await self._flush_burst()
-            if bufs:
-                self._flush_bufs(bufs)
+                    await q.wait()
+                seq = q.removed
+                stall = self._write_burst(stalled=bool(stall),
+                                          settle=False)
+                if q.removed != seq:
+                    await self._flush_burst()
+                if stall is None:
+                    break                      # the stop sentinel
             await self._drain()
         except asyncio.CancelledError:
             pass
@@ -532,20 +559,119 @@ class Client:
             self.write_error = self.write_error or repr(exc)
 
     async def _flush_burst(self) -> None:
-        """One burst's transport flush. The removed-counter snapshot
+        """The task's wait for one burst. The removed-counter snapshot
         happens BEFORE awaiting: deliveries enqueued while drain() is
-        in flight were not carried by this flush, so their ADR-015
-        watchers must wait for a later one. drain() is the flow
-        control: past the transport high-water mark it blocks until
-        the consumer catches up, backpressuring into the
-        byte-accounted queue where the stall detector and budgets can
-        see it (ADR 012)."""
-        self.write_progress = time.monotonic()
+        in flight were not carried by this burst, so their ADR-015
+        watchers must wait for a later one. A back-pressured
+        consumer's ``drain`` span therefore holds the transport's
+        wait, as it always did; only a burst the pass wrote into an
+        empty transport buffer ends its spans at the hand-over."""
         flushed = self.outbound.removed
         await self.writer.drain()
         self.write_progress = time.monotonic()
         if self._drain_traces:
             self._settle_drain_traces(flushed)
+
+    def _write_burst(self, stalled: bool = False,
+                     settle: bool = True) -> float | None:
+        """Hand one burst of the outbound queue to the transport, in
+        queue order, synchronously: everything queued, bounded in bytes
+        (``BURST_BYTES``). Wire buffers (``bytes`` / ``tuple`` items)
+        are collected and flushed through ONE ``writelines``, or before
+        any Packet item, which must encode+write in order. The one
+        copy of this logic: the writer task and the flush pass's
+        ``_write_direct`` both call it.
+
+        Returns 0.0 when the burst is with the transport, the seconds a
+        ``client.write`` hang fault asks the caller to wait before the
+        head item (left queued; ``stalled`` says that wait is over), or
+        None with the stop sentinel at the head. With ``settle`` the
+        ADR-015 drain watchers are closed here, against the ``removed``
+        count read right after the hand-over, so a delivery enqueued
+        later waits for a later burst; the task passes False and closes
+        them after its ``drain()`` (``_flush_burst``)."""
+        q = self.outbound
+        info = self.server.info
+        armed = faults.REGISTRY.any_armed()
+        bufs: list = []
+        burst = 0
+        ret: float | None = 0.0
+        while q.qsize():
+            packet = q.peek()
+            if packet is None:
+                ret = None
+                break
+            if armed and not stalled and (
+                    stall := self._write_fault_delay()):
+                ret = stall
+                break
+            stalled = False
+            q.get_nowait()
+            t = type(packet)
+            if t is bytes:                 # pre-encoded fast path
+                bufs.append(packet)
+                n = len(packet)
+                info.bytes_sent += n
+                info.packets_sent += 1
+                burst += n
+                if packet[0] >> 4 == PT.PUBLISH:
+                    info.messages_sent += 1
+            elif t is tuple:               # ADR 019 buffer sequence
+                n = 0
+                for b in packet:
+                    n += len(b)
+                bufs.extend(packet)
+                info.bytes_sent += n
+                info.packets_sent += 1
+                burst += n
+                if packet[0][0] >> 4 == PT.PUBLISH:
+                    info.messages_sent += 1
+            else:
+                if bufs:                   # keep the wire in order
+                    self._flush_bufs(bufs)
+                self._write_packet(packet)
+                burst += _estimate_wire(packet)
+            if burst >= self.BURST_BYTES:
+                break
+        if bufs:
+            self._flush_bufs(bufs)
+        if burst:
+            self.write_progress = time.monotonic()
+            if settle and self._drain_traces:
+                self._settle_drain_traces(q.removed)
+        return ret
+
+    def _write_direct(self) -> str | None:
+        """The flush pass's write (``FlushScheduler.flush_now``), asked
+        while the writer task is parked: then the task holds nothing
+        unwritten and the queue was empty before this pass's enqueues,
+        so a burst written here keeps the wire's order. Returns None
+        when the backlog is with the transport and the task may sleep
+        on, else why the task has to take over (the pass wakes it).
+
+        Only into an empty transport buffer, and one burst at most: a
+        wedged consumer's backlog stays in the accounted queue, never
+        in the transport (ADR 012). A write that raises ends this
+        client's writer as the task's own ``except`` does, recorded in
+        ``write_error``; nothing reaches the caller, who is the publish
+        pipeline's consumer or a loop callback."""
+        transport = getattr(self.writer, "transport", None)
+        if transport is None:
+            return "facade"
+        if self.closed:
+            return "stop"
+        if faults.REGISTRY.any_armed():
+            return "fault"                 # hang mode needs an await
+        try:
+            if transport.get_write_buffer_size():
+                return "backpressure"
+            if self._write_burst() is None:
+                return "stop"
+        except Exception as exc:
+            self.write_error = self.write_error or repr(exc)
+            self._writer_task.cancel()     # which cancels the getter:
+            return "error"                 # nothing is left to wake
+        return "backpressure" if self.outbound.qsize() else None
 
     def _write_packet(self, packet: Packet) -> None:
         packet = self.server.hooks.modify("on_packet_encode", packet, self)

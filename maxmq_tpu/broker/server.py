@@ -110,8 +110,8 @@ class Capabilities:
     native_encode: bool = True        # C frame-head assembly when the
                                       # maxmq_decode extension is built;
                                       # False pins the Python builder
-    flush_coalesce: bool = True       # coalesce writer wakes to one
-                                      # flush per loop iteration
+    flush_coalesce: bool = True       # one flush pass per fan-out
+                                      # serves (and writes) each writer
 
     # -- MQTT+ content plane (ADR 023) ---------------------------------
     content_filtering: bool = True    # parse ?$expr/?$agg SUBSCRIBE
@@ -183,11 +183,12 @@ class Broker:
         # deliveries parked while shedding (drained on recovery)
         self.overload = OverloadState(self.capabilities)
         self._half_open = 0
-        # zero-copy fan-out (ADR 019): per-loop-iteration writer-wake
-        # coalescing — one flush pass wakes every writer a fan-out
-        # touched, after its full backlog is queued. None disables
-        # (direct wakes), for latency-sensitive single-subscriber
-        # deployments that prefer the pre-019 behavior.
+        # zero-copy fan-out (ADR 019): per-loop-iteration write
+        # coalescing — one flush pass serves every writer a fan-out
+        # touched, after its full backlog is queued: it writes an idle
+        # writer's burst to the socket itself and wakes the writer
+        # task for what needs back-pressure. None disables (every
+        # enqueue wakes the task), the pre-019 behavior.
         self.flush_sched = (FlushScheduler()
                             if self.capabilities.flush_coalesce else None)
         # (client_id, filter) -> (sub, existing): keyed so a client
@@ -1226,7 +1227,12 @@ class Broker:
         evaluates every (publish x predicate) pair in one vectorized
         pass; arrival order is preserved end to end. With the plane
         inactive the pre-023 single-item path runs unchanged."""
+        sched = self.flush_sched
         while True:
+            if sched is not None and self._pub_queue.empty():
+                # running dry: write what this step queued now, not an
+                # iteration later
+                sched.flush_now()
             item = await self._pub_queue.get()
             cp = self.content
             if cp is not None and cp.active:
@@ -1255,6 +1261,10 @@ class Broker:
         a cancelled future (not a cancelled consumer) or a matcher
         failure serves that one publish from the CPU trie."""
         try:
+            if not fut.done() and self.flush_sched is not None:
+                # about to wait for the matcher: the deliveries of the
+                # publishes before this one go out first
+                self.flush_sched.flush_now()
             return await fut
         except asyncio.CancelledError:
             # CancelledError is a BaseException: catch it
@@ -1590,8 +1600,9 @@ class Broker:
     def _trace_drain(self, client: Client, packet: Packet) -> None:
         """ADR 015: register one subscriber's enqueue->flush watcher on
         the ORIGINAL publish's trace (delivery copies don't alias it);
-        the client's writer task settles it after its next flush, so
-        the span crosses into the writer-task domain."""
+        the client's burst writer (the flush pass, or its writer
+        task) settles it where the burst carrying it is handed to the
+        transport."""
         tr = packet.__dict__.get("_trace")
         if tr is not None and tr.n_drain < MAX_DRAIN_SPANS:
             tr.n_drain += 1

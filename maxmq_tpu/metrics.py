@@ -954,13 +954,22 @@ def _register_fanout_metrics(registry: Registry, broker) -> None:
     sched = getattr(broker, "flush_sched", None)
     if sched is not None:
         for name, help_ in (
-                ("flushes", "Coalesced writer-wake flush passes run"),
+                ("flushes", "Flush passes that found a writer parked"),
                 ("deferred", "Writer wakes parked for a flush pass"),
                 ("coalesced",
-                 "Duplicate same-iteration wakes absorbed by a park")):
+                 "Duplicate same-iteration wakes absorbed by a park"),
+                ("direct",
+                 "Bursts the flush pass handed to an idle writer's "
+                 "socket itself, with no task wake-up")):
             registry.counter_func(
                 f"maxmq_broker_fanout_flush_{name}_total", help_,
                 lambda n=name: getattr(sched, n))
+        registry.multi_func(
+            "maxmq_broker_fanout_flush_woken_total", "counter",
+            "Bursts the flush pass left to the writer task, by reason "
+            "(backpressure | fault | facade | stop | error); direct / "
+            "(direct + woken) is the share of bursts that paid no wake-up",
+            lambda: [({"reason": r}, n) for r, n in sched.woken.items()])
 
 
 def _register_filter_metrics(registry: Registry, broker) -> None:

@@ -329,8 +329,9 @@ async def test_hook_override_e2e_takes_slow_path():
 
 
 async def test_fanout_flush_coalescing_and_writev():
-    """1->N fan-out wakes each writer once per loop iteration and the
-    burst reaches the transport via writelines batches."""
+    """1->N fan-out serves each writer once per loop iteration: one
+    park, one burst through one writelines (not one per delivery), and
+    the pass writes an idle writer's burst itself (direct)."""
     async with running_broker() as broker:
         subs = [await connect(broker, f"w{i}") for i in range(3)]
         for s in subs:
@@ -340,14 +341,54 @@ async def test_fanout_flush_coalescing_and_writev():
         sched, ov = broker.flush_sched, broker.overload
         assert sched is not None
         f0, d0, w0 = sched.flushes, sched.deferred, ov.writev_batches
+        x0, woken0 = sched.direct, dict(sched.woken)
         await p.publish("f/t", b"burst")
         for s in subs:
             assert (await s.next_message()).payload == b"burst"
         assert sched.deferred - d0 >= 3     # one parked wake per writer
         assert sched.flushes - f0 >= 1
+        assert sched.direct - x0 >= 3       # ...each written by the pass
+        assert sched.woken == woken0        # no writer task woke for it
         await poll(lambda: ov.writev_batches - w0 >= 3, what="writev flush")
         for c in subs + [p]:
             await c.disconnect()
+
+
+async def test_subscriber_hit_k_times_in_one_step_gets_one_writev():
+    """Eight publishes arriving in one segment are delivered in one
+    step of the pipeline's consumer: the subscriber is parked once
+    (seven wakes absorbed) and its eight deliveries leave in ONE
+    writev, written where the consumer ran dry."""
+    class _Trie:
+        """Answers at enqueue, as the batcher's topic cache does."""
+        def __init__(self, index): self.index = index
+        def enqueue(self, topic):
+            fut = asyncio.get_running_loop().create_future()
+            fut.set_result(self.index.subscribers(topic))
+            return fut
+
+    async with running_broker() as broker:
+        broker.attach_matcher(_Trie(broker.topics))
+        s = await connect(broker, "k-sub")
+        await s.subscribe("k/#")
+        p = await connect(broker, "k-pub")
+        await asyncio.sleep(0.05)
+        sched, ov = broker.flush_sched, broker.overload
+        w0, b0, x0, c0 = (ov.writev_batches, ov.writev_buffers,
+                          sched.direct, sched.coalesced)
+        frames = b"".join(
+            Packet(fixed=FixedHeader(type=PT.PUBLISH), protocol_version=4,
+                   topic=f"k/{i}", payload=b"%d" % i).encode()
+            for i in range(8))
+        p.writer.write(frames)              # one segment, one read
+        got = [await s.next_message(timeout=5) for _ in range(8)]
+        assert [m.payload for m in got] == [b"%d" % i for i in range(8)]
+        assert ov.writev_batches - w0 == 1
+        assert ov.writev_buffers - b0 == 8
+        assert sched.direct - x0 == 1
+        assert sched.coalesced - c0 == 7
+        await s.disconnect()
+        await p.disconnect()
 
 
 # -- satellite 4: fast/template drops feed the slow path's ledgers -----
