@@ -109,14 +109,18 @@ class FlushScheduler:
     line) or earlier, where a producer that queued many deliveries
     calls ``flush_now`` as it runs dry (the publish pipeline's
     consumer); the ``call_soon`` already scheduled then finds nothing
-    pending."""
+    pending. Either way the pass is ``flush_now``'s one body, and a
+    pass that writes a sampled publish's deliveries (``watch``) is that
+    publish's ADR-015 ``flush`` stage: the whole pass, timed there."""
 
-    __slots__ = ("_pending", "_scheduled", "flushes", "deferred",
-                 "coalesced", "direct", "woken")
+    __slots__ = ("_pending", "_scheduled", "_traced", "tracer", "flushes",
+                 "deferred", "coalesced", "direct", "woken")
 
-    def __init__(self) -> None:
+    def __init__(self, tracer) -> None:
         self._pending: list = []
         self._scheduled = False
+        self.tracer = tracer        # ADR 015: the ``flush`` stage's clock
+        self._traced: list = []     # sampled publishes the next pass writes
         self.flushes = 0        # passes that found something parked
         self.deferred = 0       # wakes parked for a flush pass
         self.coalesced = 0      # duplicate wakes absorbed by one park
@@ -143,6 +147,18 @@ class FlushScheduler:
         self.deferred += 1
         return True
 
+    @property
+    def parked(self) -> int:
+        """Deliveries that met a parked writer so far (each waits, or
+        waited, for a pass)."""
+        return self.deferred + self.coalesced
+
+    def watch(self, trace) -> None:
+        """``trace`` is a sampled publish whose fan-out just parked
+        deliveries (``parked`` moved): the next pass writes them and is
+        its ``flush`` stage (timed on ``tracer``'s clock)."""
+        self._traced.append(trace)
+
     def _flush(self) -> None:
         self._scheduled = False
         self.flush_now()
@@ -154,6 +170,19 @@ class FlushScheduler:
             return
         self._pending = []
         self.flushes += 1
+        traced = self._traced
+        if not traced:
+            self._write(pending)
+            return
+        self._traced = []
+        tracer = self.tracer
+        t0 = tracer.clock()
+        self._write(pending)
+        t1 = tracer.clock()
+        for trace in traced:
+            tracer.attach(trace, "flush", t0, t1)
+
+    def _write(self, pending: list) -> None:
         for q in pending:
             q._wake_deferred = False
             g = q._getter

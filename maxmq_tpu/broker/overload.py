@@ -96,6 +96,13 @@ class OverloadState:
         # matcher output that was deliverable.
         self.fanout_matched = 0
         self.fanout_resolved = 0
+        # the width of the fan-out: the most resolved entries one match
+        # result held since start, and the subscribers' PUBACKs that
+        # came back for QoS 1 deliveries (a wide QoS 1 fan-out pays one
+        # inbound packet, an inflight release and a journal delete a
+        # delivery on the read path)
+        self.fanout_widest = 0
+        self.fanout_acks = 0
 
     # -- byte accounting (called by every OutboundQueue put/get) -------
 

@@ -183,14 +183,6 @@ class Broker:
         # deliveries parked while shedding (drained on recovery)
         self.overload = OverloadState(self.capabilities)
         self._half_open = 0
-        # zero-copy fan-out (ADR 019): per-loop-iteration write
-        # coalescing — one flush pass serves every writer a fan-out
-        # touched, after its full backlog is queued: it writes an idle
-        # writer's burst to the socket itself and wakes the writer
-        # task for what needs back-pressure. None disables (every
-        # enqueue wakes the task), the pre-019 behavior.
-        self.flush_sched = (FlushScheduler()
-                            if self.capabilities.flush_coalesce else None)
         # (client_id, filter) -> (sub, existing): keyed so a client
         # re-SUBSCRIBing during the shed window gets ONE delivery on
         # recovery and the ledger is bounded by the subscription count
@@ -214,6 +206,14 @@ class Broker:
             sample_n=self.capabilities.trace_sample_n,
             slow_ms=self.capabilities.trace_slow_ms,
             ring=self.capabilities.trace_ring)
+        # zero-copy fan-out (ADR 019): per-loop-iteration write
+        # coalescing — one flush pass serves every writer a fan-out
+        # touched, after its full backlog is queued: it writes an idle
+        # writer's burst to the socket itself and wakes the writer
+        # task for what needs back-pressure. None disables (every
+        # enqueue wakes the task), the pre-019 behavior.
+        self.flush_sched = (FlushScheduler(self.tracer)
+                            if self.capabilities.flush_coalesce else None)
         self._sys_trace_topics: set[str] = set()  # retained while sampling
         self._running = False
         self.loop: asyncio.AbstractEventLoop | None = None
@@ -1426,10 +1426,14 @@ class Broker:
                 self.cluster.maybe_forward(packet)
             return
         clock = self.tracer.clock
+        sched = self.flush_sched
+        parked = sched.parked if sched is not None else 0
         t0 = clock()
         self._fan_out_local(subscribers, packet)
         t1 = clock()
         tr.span("fanout", t0, t1)
+        if sched is not None and sched.parked != parked:
+            sched.watch(tr)     # the pass that writes them: ``flush``
         if self.cluster is not None:
             self.cluster.maybe_forward(packet)
             tr.span("bridge", t1, clock())
@@ -1464,6 +1468,8 @@ class Broker:
         overload = self.overload
         overload.fanout_matched += matched
         overload.fanout_resolved += resolved
+        if resolved > overload.fanout_widest:
+            overload.fanout_widest = resolved
         if shared:
             self._fan_out_shared(shared, pairs, packet)
         for client, sub in pairs:
@@ -1897,6 +1903,20 @@ class Broker:
                 self.hooks.notify("on_publish_dropped", client, out)
 
     def _process_puback(self, client: Client, packet: Packet) -> None:
+        """A subscriber's PUBACK: the inflight entry and its send quota
+        are released and the storage hook deletes the journalled
+        record. A wide QoS 1 fan-out sends as many of these as it made
+        deliveries, so while tracing is on the work has a profiler
+        annotation of its own (``maxmq.ack``, inside the chunk's
+        ``maxmq.read``)."""
+        self.overload.fanout_acks += 1
+        if self.tracer.sample_n:
+            with host_span("maxmq.ack"):
+                self._release_acked(client, packet)
+        else:
+            self._release_acked(client, packet)
+
+    def _release_acked(self, client: Client, packet: Packet) -> None:
         if client.inflight.delete(packet.packet_id):
             self.info.inflight -= 1
             client.inflight.return_send_quota()

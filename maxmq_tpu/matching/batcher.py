@@ -47,8 +47,12 @@ class MicroBatcher:
     # when the measured estimates say bigger would still win
     BYPASS_CAP = 512
     # every Nth eligible batch goes to the device anyway, so the RTT
-    # estimate cannot go stale while the bypass is winning
+    # estimate cannot go stale while the bypass is winning; nor may it
+    # stand longer than this many seconds where batches are few (a loop
+    # held by wide fan-outs makes four a second: 64 of them are a
+    # quarter of a minute, PERF.md section 6, PR 32)
     BYPASS_PROBE_EVERY = 64
+    BYPASS_PROBE_SECONDS = 5.0
 
     def __init__(self, engine, window_us: int = 200,
                  max_batch: int = 256, pipeline_depth: int = 3,
@@ -99,6 +103,7 @@ class MicroBatcher:
         self._trie_stale = 0              # host-served passes since the
                                           # last trie cost sample
         self._since_probe = 0
+        self._probed_at = time.perf_counter()   # the last probe's start
         self._probe_task: asyncio.Task | None = None
         # stats (scraped by the metrics bridge)
         self.batches = 0
@@ -384,12 +389,13 @@ class MicroBatcher:
         except Exception as exc:
             self._fail(batch, exc)
             return
-        self._update_cost_model(host is not None, n,
-                                time.perf_counter() - t0)
+        t1 = time.perf_counter()
+        self._update_cost_model(host is not None, n, t1 - t0)
         self._since_probe += 1
         self.bypasses += len(topics)
         self._settle(ver, batch, results)
-        if self._since_probe >= self.BYPASS_PROBE_EVERY:
+        if (self._since_probe >= self.BYPASS_PROBE_EVERY
+                or t1 - self._probed_at >= self.BYPASS_PROBE_SECONDS):
             self._shadow_probe(topics, rec)
 
     def _trie_walk(self, topics):
@@ -449,6 +455,7 @@ class MicroBatcher:
         if self._probe_task is not None and not self._probe_task.done():
             return
         self._since_probe = 0
+        self._probed_at = time.perf_counter()
         rec = None
         if of is not None:
             rec = of.tracer.open_batch(len(topics), of=of)

@@ -951,6 +951,16 @@ def _register_fanout_metrics(registry: Registry, broker) -> None:
              "share of matcher output)")):
         registry.counter_func(f"maxmq_broker_{name}_total", help_,
                               lambda n=name: getattr(over, n))
+    registry.gauge_func(
+        "maxmq_broker_fanout_widest",
+        "Most resolved entries one match result held since start: the "
+        "widest fan-out one publish was paid for (fanout_resolved_total "
+        "is their sum)", lambda: over.fanout_widest)
+    registry.counter_func(
+        "maxmq_broker_fanout_acks_total",
+        "Inbound PUBACKs handled: one a QoS 1 delivery, each an inflight "
+        "release and a journal delete on the read path",
+        lambda: over.fanout_acks)
     sched = getattr(broker, "flush_sched", None)
     if sched is not None:
         for name, help_ in (

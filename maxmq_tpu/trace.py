@@ -26,6 +26,13 @@ Stage model (see docs/adr/015-publish-tracing.md for the contract):
 ``drain``          per-subscriber outbound enqueue -> writer flush
                    (completes after the publisher's e2e; capped at
                    MAX_DRAIN_SPANS subscribers per trace)
+``flush``          the flush pass that wrote this publish's parked
+                   deliveries (``FlushScheduler.flush_now``, from the
+                   pipeline's consumer or from ``call_soon``): the whole
+                   pass, every socket it wrote, since a wide fan-out's
+                   cost moves there (runs after ``fanout`` closes, often
+                   after the publisher's terminal stage: lands like a
+                   drain then; not critical)
 ``takeover``       cross-node session takeover leg at CONNECT (ADR
                    016; histogram-only like journal_commit — it is a
                    connection-path span, not a publish-path one)
@@ -113,17 +120,18 @@ BATCH_PHASES = ("match_host", "match_prep", "match_probe",
                 "device_rtt", "match_hop")
 # canonical pipeline stages; CRITICAL_STAGES are the contiguous
 # publisher-path segments whose durations sum to ~e2e (drain happens
-# after the publisher's terminal stage; journal_commit/takeover/release
-# are not tied to one publish's critical path; bridge_in is critical
+# after the publisher's terminal stage, and so may the flush pass that
+# writes it; journal_commit/takeover/release are not tied to one
+# publish's critical path; bridge_in is critical
 # only on ADOPTED traces, where it IS the path's first local segment;
 # loop_lag is a probe of the loop beside the path)
 STAGES = ("decode", "admission", "match_queue", "match_device",
           "pipeline_wait", "filter", "fanout", "bridge", "bridge_in",
-          "journal_commit", "barrier", "ack", "drain", "takeover",
-          "release", "aggregate", "loop_lag") + BATCH_PHASES
+          "journal_commit", "barrier", "ack", "drain", "flush",
+          "takeover", "release", "aggregate", "loop_lag") + BATCH_PHASES
 CRITICAL_STAGES = frozenset(
     s for s in STAGES
-    if s not in ("drain", "journal_commit", "takeover", "release",
+    if s not in ("drain", "flush", "journal_commit", "takeover", "release",
                  "aggregate", "loop_lag") + BATCH_PHASES)
 # 10us .. 1s: a phase of one micro-batch is tens of microseconds to a
 # few milliseconds, under the default ladder's first bound
@@ -549,10 +557,10 @@ class PipelineTracer:
     def attach(self, trace: PublishTrace, stage: str, start_ns: int,
                end_ns: int, batch: int = 0, shadow: bool = False) -> None:
         """One span for a publish that may have finished already:
-        ``loop_lag`` (top level) or, with ``batch``, a phase of that
-        micro-batch (child of ``match_device``). After the finish it
-        feeds the histogram and is appended to the live flight-recorder
-        entry, the way ``drain_span`` appends drains."""
+        ``loop_lag`` or ``flush`` (top level) or, with ``batch``, a phase
+        of that micro-batch (child of ``match_device``). After the finish
+        it feeds the histogram and is appended to the live
+        flight-recorder entry, the way ``drain_span`` appends drains."""
         dur = max(end_ns - start_ns, 0)
         if not trace.done:
             if batch:

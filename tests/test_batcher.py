@@ -328,6 +328,17 @@ async def test_adaptive_cpu_bypass_serves_small_batches():
         assert batcher._probe_task is not None
         await batcher._probe_task
         assert batcher._since_probe <= 1
+        # ... and so does an estimate that has stood BYPASS_PROBE_SECONDS,
+        # however few batches came by: a broker whose loop makes four
+        # batches a second must not wait 64 of them
+        first = batcher._probe_task
+        await batcher.subscribers_async("by/12/x")
+        assert batcher._probe_task is first     # fresh: no new probe
+        batcher._probed_at -= batcher.BYPASS_PROBE_SECONDS
+        await batcher.subscribers_async("by/13/x")
+        assert batcher._probe_task is not first
+        await batcher._probe_task
+        assert batcher._since_probe <= 1
     finally:
         await batcher.close()
 

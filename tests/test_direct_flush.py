@@ -514,11 +514,48 @@ async def test_failed_direct_write_leaves_the_consumer_alive():
         await bad.close()
 
 
+@pytest.mark.parametrize("who", ["consumer", "call_soon"])
+async def test_the_pass_is_a_sampled_publishs_flush_stage(who):
+    """ADR 015 ``flush``: the pass that writes a sampled publish's parked
+    deliveries is timed once, in ``flush_now``'s one body, whoever runs
+    it (with a matcher the pipeline's consumer as it runs dry, else the
+    ``call_soon`` pass), and lands in the entry's spans; a publish that
+    reaches nobody has none; its subscribers' PUBACKs are counted."""
+    async with running_broker(trace_sample_n=1, trace_ring=64) as broker:
+        if who == "consumer":
+            broker.attach_matcher(_TrieMatcher(broker.topics))
+        subs = [await connect(broker, f"w{i}") for i in range(6)]
+        for c in subs:
+            await c.subscribe(("wide/#", 1))
+        pub = await connect(broker, "pub")
+        await asyncio.sleep(0.05)
+        over, sched = broker.overload, broker.flush_sched
+        acks0 = over.fanout_acks
+        await pub.publish("wide/a", b"x", qos=1)
+        await pub.publish("nobody/a", b"y", qos=1)
+        for c in subs:
+            assert (await c.next_message(timeout=5)).payload == b"x"
+        await poll(lambda: over.fanout_acks - acks0 == 6, what="PUBACKs")
+        assert over.fanout_widest == 6 and not sched._traced
+        entries = {e["topic"]: e for e in broker.tracer.report()["entries"]}
+        wide = {sp["stage"]: sp for sp in entries["wide/a"]["spans"]}
+        assert "flush" in wide and wide["flush"]["parent"] == ""
+        # the pass runs after the fan-out that parked the deliveries
+        assert wide["flush"]["off_us"] >= wide["fanout"]["off_us"]
+        assert len(entries["wide/a"]["drains"]) == 6
+        assert "flush" not in {sp["stage"]
+                               for sp in entries["nobody/a"]["spans"]}
+        assert broker.tracer.stage_hist["flush"].count == 1
+        for c in subs + [pub]:
+            await c.disconnect()
+
+
 async def test_flush_counters_exported():
     from maxmq_tpu.metrics import Registry, register_broker_metrics
     broker = _broker()
     sched = broker.flush_sched
     sched.direct, sched.woken["backpressure"] = 7, 2
+    broker.overload.fanout_widest, broker.overload.fanout_acks = 1000, 3000
     reg = Registry()
     register_broker_metrics(reg, broker)
     text = reg.expose()
@@ -528,4 +565,6 @@ async def test_flush_counters_exported():
     for reason in ("fault", "facade", "stop", "error"):
         assert (f'maxmq_broker_fanout_flush_woken_total{{reason="{reason}"}}'
                 ' 0') in text
+    assert "maxmq_broker_fanout_widest 1000" in text
+    assert "maxmq_broker_fanout_acks_total 3000" in text
     broker.hooks.stop_all()

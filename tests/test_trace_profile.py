@@ -193,3 +193,23 @@ def test_interval_arithmetic_on_made_up_events(tool):
     assert tool.round_trips(threads) == [(5, 9), (20, 31)]
     assert tool.loop_thread(threads) == 1
     assert tool.loop_thread([threads[0]]) is None
+
+
+def test_a_puback_inside_a_read_gets_a_row_of_its_own(tool):
+    """``maxmq.ack`` is opened inside a chunk's ``maxmq.read``: its time
+    is cut out of the span around it, which keeps the rest."""
+    events = [("maxmq.read", 0, 100), ("maxmq.ack", 10, 20),
+              ("maxmq.ack", 30, 45), ("maxmq.flush", 12, 14),
+              ("maxmq.deliver", 120, 150), ("maxmq.ack", 200, 210)]
+    carved = tool.carve(events)
+    assert carved == [("maxmq.read", 0, 10), ("maxmq.ack", 10, 20),
+                      ("maxmq.read", 20, 30), ("maxmq.ack", 30, 45),
+                      ("maxmq.read", 45, 100), ("maxmq.deliver", 120, 150),
+                      ("maxmq.ack", 200, 210)]
+    got = tool.attribute([(0, 300)], tool.spans_by_name([carved]))
+    assert got["names"] == {"maxmq.read": 75e-9, "maxmq.ack": 35e-9,
+                            "maxmq.deliver": 30e-9}
+    assert got["unannotated"] == pytest.approx(160e-9)
+    # without one, a thread's events are its top-level ones, as before
+    plain = [e for e in events if e[0] != "maxmq.ack"]
+    assert tool.carve(plain) == tool.top_level(plain)

@@ -15,7 +15,9 @@ a file and prints:
   file has no device plane, as on the CPU);
 * for the event loop's thread, and for the other threads together, the
   seconds of that idle time each top-level ``maxmq.*`` name covers, its
-  share of the idle time, and what no annotation covers;
+  share of the idle time, and what no annotation covers (``maxmq.ack``,
+  a subscriber's PUBACK handled inside a chunk's ``maxmq.read``, is cut
+  out of the span around it and given a row of its own);
 * the ten longest idle gaps, each with the name that covers most of it;
 * two checks of the clocks: how many device operations began inside an
   annotated dispatch -> fetch of one batch, and the tracer's clock minus
@@ -46,6 +48,9 @@ import xplane  # noqa: E402
 HOST_PLANE = "/host:CPU"
 PREFIX = "maxmq."
 LOOP_MARKS = ("maxmq.read", "maxmq.deliver", "maxmq.settle")
+# nested annotations that get a row of their own: their time is taken
+# from the span around them
+CARVED = ("maxmq.ack",)
 
 
 # -- interval arithmetic (nanoseconds; an interval is (start, end)) --------
@@ -100,6 +105,27 @@ def top_level(events) -> list[tuple[str, int, int]]:
             out.append((name, lo, hi))
             edge = hi
     return out
+
+
+def carve(events) -> list[tuple[str, int, int]]:
+    """One thread's top-level events, with every ``CARVED`` event that
+    lies inside one cut out of it and listed beside it."""
+    inner = sorted((e for e in events if e[0] in CARVED),
+                   key=lambda e: e[1])
+    tops = top_level(e for e in events if e[0] not in CARVED)
+    if not inner:
+        return tops
+    out, i = [], 0          # both lists are in start order: one walk
+    for name, lo, hi in tops:
+        while i < len(inner) and inner[i][2] <= lo:
+            i += 1
+        j = i
+        while j < len(inner) and inner[j][1] < hi:
+            j += 1
+        out += [(name, a, b) for a, b in complement(
+            [(c0, c1) for _n, c0, c1 in inner[i:j]], lo, hi)]
+        i = j
+    return sorted(out + inner, key=lambda e: e[1])
 
 
 def spans_by_name(threads) -> dict:
@@ -192,7 +218,7 @@ def analyse(data) -> dict:
     idle = complement(ops, lo, hi)
     idle_s = sum(b - a for a, b in idle) / 1e9
     k = loop_thread(threads)
-    tops = [top_level([e[:3] for e in events]) for events in threads]
+    tops = [carve([e[:3] for e in events]) for events in threads]
     groups = {"loop": spans_by_name([tops[k]] if k is not None else []),
               "other": spans_by_name(t for i, t in enumerate(tops)
                                      if i != k)}
