@@ -103,6 +103,13 @@ class OverloadState:
         # delivery on the read path)
         self.fanout_widest = 0
         self.fanout_acks = 0
+        # the inflight records the storage hook put for QoS>0
+        # deliveries (ADR 019): records_spliced were assembled from the
+        # fragment their publish's receivers share, records_built whole
+        # (per-receiver v5 properties, resends, held releases).
+        # spliced / (spliced + built) is the share that paid no asdict
+        self.records_spliced = 0
+        self.records_built = 0
 
     # -- byte accounting (called by every OutboundQueue put/get) -------
 

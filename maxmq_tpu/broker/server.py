@@ -126,6 +126,34 @@ class Capabilities:
     filter_window_max_s: float = 3600.0
 
 
+class _FanOut:
+    """What every receiver of one publish shares and the fan-out reads
+    once (ADR 019): the QoS ceiling of the publish and the broker, the
+    hook-set answers that choose a delivery's path, whether tracing
+    watches, the sampled trace's tag and the ``on_qos_publish``
+    handlers. ``overload.shedding`` is not here: a wide fan-out's own
+    enqueues can cross the high-water mark half way, and the entries
+    after it are shed."""
+
+    __slots__ = ("qos", "plain", "tracing", "trace_ref", "qos_publish")
+
+    def __init__(self, broker: "Broker", packet: Packet) -> None:
+        hooks = broker.hooks
+        tracer = broker.tracer
+        self.qos = min(packet.fixed.qos, broker.capabilities.maximum_qos)
+        # no hook has to see each delivery as a Packet of its own
+        self.plain = not (hooks.overrides("on_packet_encode")
+                          or hooks.overrides("on_packet_sent"))
+        self.tracing = bool(tracer.sample_n or tracer.adopted_open)
+        tr = broker._packet_trace(packet)
+        # ADR 017: a lightweight (origin, id) tag -- NOT the trace
+        # itself (delivery copies must not alias the span list) -- so
+        # downstream hooks (session replication) can correlate
+        self.trace_ref = (None if tr is None
+                          else (tr.origin or tracer.node_id, tr.id))
+        self.qos_publish = hooks.handlers("on_qos_publish")
+
+
 @dataclass
 class BrokerOptions:
     capabilities: Capabilities = field(default_factory=Capabilities)
@@ -1472,8 +1500,11 @@ class Broker:
             overload.fanout_widest = resolved
         if shared:
             self._fan_out_shared(shared, pairs, packet)
-        for client, sub in pairs:
-            self._publish_to_client(client, sub, packet, shared=False)
+        if pairs:
+            fan = _FanOut(self, packet)
+            publish = self._publish_to_client
+            for client, sub in pairs:
+                publish(client, sub, packet, False, fan)
 
     def _fan_out_shared(self, shared, pairs, packet: Packet) -> None:
         """$share: pick one member per (group, filter), merging per
@@ -1513,9 +1544,10 @@ class Broker:
         if not selected:
             return
         plain = {client.id for client, _sub in pairs}
+        fan = _FanOut(self, packet)
         for cid, sub in selected.items():
             if cid not in plain:
-                self._publish_to_client(get(cid), sub, packet, shared=True)
+                self._publish_to_client(get(cid), sub, packet, True, fan)
 
     async def _match_async(self, topic: str) -> SubscriberSet:
         async_fn = getattr(self.matcher, "subscribers_async", None)
@@ -1528,24 +1560,21 @@ class Broker:
 
     def _fast_qos0_eligible(self, client: Client, sub: Subscription,
                             packet: Packet) -> bool:
-        """True when the delivered packet carries no per-subscriber state
-        (qos 0 out, retain cleared, no v5 subscription ids / aliases) —
-        its wire bytes are then IDENTICAL for every such subscriber and
-        ONE shared bytes object serves them all. Per-subscriber feature
-        flags no longer force the copy+encode slow path: they select
-        the patched-template strategy instead (_send_template_qos0 /
-        _send_template_qos, ADR 019). Disabled when any hook watches
-        the encode/sent events."""
-        return (min(packet.fixed.qos, sub.qos,
-                    self.capabilities.maximum_qos) == 0
-                and not client.closed
-                and not (sub.retain_as_published and packet.fixed.retain)
-                and not (client.properties.protocol_version >= 5
+        """True when an effective-QoS0 delivery to a live client
+        carries no per-subscriber state (retain cleared, no v5
+        subscription ids / aliases) — its wire bytes are then IDENTICAL
+        for every such subscriber and ONE shared bytes object serves
+        them all. Per-subscriber feature flags no longer force the
+        copy+encode slow path: they select the patched-template
+        strategy instead (_send_template_qos0 / _send_template_qos,
+        ADR 019). The caller has ruled out the hooks that watch the
+        encode/sent events (``_FanOut.plain``)."""
+        props = client.properties
+        return (not (sub.retain_as_published and packet.fixed.retain)
+                and not (props.protocol_version >= 5
                          and (sub.identifiers or sub.identifier
-                              or client.properties.topic_alias_maximum))
-                and not client.properties.maximum_packet_size
-                and not self.hooks.overrides("on_packet_encode")
-                and not self.hooks.overrides("on_packet_sent"))
+                              or props.topic_alias_maximum))
+                and not props.maximum_packet_size)
 
     @staticmethod
     def _delivery_form(packet: Packet, version: int) -> Packet:
@@ -1564,7 +1593,8 @@ class Broker:
             out.properties = type(out.properties)()
         return out
 
-    def _send_fast_qos0(self, client: Client, packet: Packet) -> None:
+    def _send_fast_qos0(self, client: Client, packet: Packet,
+                        fan: _FanOut) -> None:
         """Encode once per (packet, version) and enqueue raw bytes —
         per-subscriber copy + encode is the dominant fan-out cost."""
         version = client.properties.protocol_version
@@ -1600,7 +1630,7 @@ class Broker:
         # per subscriber — every delivered byte is reused, none copied
         self.overload.template_sends += 1
         self.overload.shared_bytes += len(wire)
-        if self.tracer.sample_n or self.tracer.adopted_open:
+        if fan.tracing:
             self._trace_drain(client, packet)
 
     def _trace_drain(self, client: Client, packet: Packet) -> None:
@@ -1615,20 +1645,6 @@ class Broker:
             client._drain_traces.append(
                 (tr, self.tracer.clock(), client.outbound.enqueued))
 
-    def _template_eligible(self, client: Client) -> bool:
-        """ADR 019: per-subscriber frame variation (QoS flags, packet
-        id, v5 subscription ids / topic alias / retain-as-published /
-        max-packet-size) selects a patch strategy over the shared wire
-        template instead of the per-subscriber copy+encode. Encode/sent
-        hook overrides force the slow path — those hooks must observe
-        each delivery as a real mutable Packet — and so does an
-        instance-patched ``send``/``send_buffers`` (the embedder/test
-        seam for intercepting shaped deliveries)."""
-        d = client.__dict__
-        return ("send" not in d and "send_buffers" not in d
-                and not self.hooks.overrides("on_packet_encode")
-                and not self.hooks.overrides("on_packet_sent"))
-
     def _template_for(self, packet: Packet, version: int):
         """The (packet, version) shared template, counted on first
         build (``template_builds``)."""
@@ -1638,7 +1654,7 @@ class Broker:
         return wire.publish_template(packet, version)
 
     def _send_template_qos0(self, client: Client, sub: Subscription,
-                            packet: Packet) -> bool:
+                            packet: Packet, fan: _FanOut) -> bool:
         """One QoS0 delivery whose frame VARIES per subscriber
         (retain-as-published, v5 subscription ids / topic alias, a
         client max-packet-size to honor): patch the shared template
@@ -1689,20 +1705,21 @@ class Broker:
         overload.template_sends += 1
         overload.shared_bytes += tmpl.shared_len
         overload.copied_bytes += size - tmpl.shared_len
-        if self.tracer.sample_n or self.tracer.adopted_open:
+        if fan.tracing:
             self._trace_drain(client, packet)
         return True
 
     def _send_template_qos(self, client: Client, out: Packet,
-                           packet: Packet) -> bool:
+                           packet: Packet, fan: _FanOut) -> bool:
         """One QoS>0 first transmission patched from the shared
-        template (ADR 019). ``out`` is the inflight-registered shaped
-        copy from _build_outbound — the patch derives flags, packet id
-        and the spliced v5 segment from it, so session resume, DUP
-        resends and the ack state machines keep operating on real
-        Packets. Returns False to fall back to _send_outbound (frame
-        over the client's max packet size: encode_under may still
-        save it by shedding user properties)."""
+        template (ADR 019). ``out`` is the shaped packet from
+        _build_outbound, which is the inflight entry itself: the patch
+        reads flags, packet id and the spliced v5 segment from it and
+        queues byte buffers alone, so session resume, DUP resends and
+        the ack state machines keep operating on real Packets. Returns
+        False to fall back to _send_outbound (frame over the client's
+        max packet size: encode_under may still save it by shedding
+        user properties)."""
         version = client.properties.protocol_version
         tmpl = self._template_for(packet, version)
         mid = b""
@@ -1725,15 +1742,18 @@ class Broker:
         overload.template_sends += 1
         overload.shared_bytes += tmpl.shared_len
         overload.copied_bytes += size - tmpl.shared_len
-        if self.tracer.sample_n or self.tracer.adopted_open:
+        if fan.tracing:
             self._trace_drain(client, packet)
         return True
 
     def _publish_to_client(self, client: Client, sub: Subscription,
-                           packet: Packet, shared: bool) -> None:
+                           packet: Packet, shared: bool,
+                           fan: _FanOut | None = None) -> None:
         """Parity: v2/server.go:795-868 (publishToClient). ``client``
         comes resolved: the fan-out looked the session up when it
-        walked the match result (_fan_out_local)."""
+        walked the match result (_fan_out_local), and ``fan`` is what
+        it read once for all of the publish's receivers; a caller with
+        one receiver leaves it out."""
         if sub.no_local and packet.origin == client.id:
             return  # v5 NoLocal [MQTT-3.8.3-3]
         skip = packet.__dict__.get("_content_skip")
@@ -1741,52 +1761,63 @@ class Broker:
             return  # ADR 023: every claim this client has on the topic
             #         is content-gated and none passed (shared picks
             #         are exempt: $share filters carry no options)
-        if self._shed_qos0(client, sub, packet):
-            return  # above the high-water mark: QoS0 fan-out shed
-        if self._fast_qos0_eligible(client, sub, packet):
-            self._send_fast_qos0(client, packet)
-            return
-        template = self._template_eligible(client)
-        if (template and not client.closed
-                and min(packet.fixed.qos, sub.qos,
-                        self.capabilities.maximum_qos) == 0
-                and self._send_template_qos0(client, sub, packet)):
+        if fan is None:
+            fan = _FanOut(self, packet)
+        qos = min(sub.qos, fan.qos)
+        # ADR 019: per-subscriber frame variation (QoS flags, packet
+        # id, v5 subscription ids / topic alias / retain-as-published /
+        # max-packet-size) selects a patch strategy over the shared
+        # wire template instead of the per-subscriber copy+encode.
+        # Encode/sent hook overrides force the slow path (those hooks
+        # must observe each delivery as a real mutable Packet) and so
+        # does an instance-patched ``send``/``send_buffers`` (the
+        # embedder/test seam for intercepting shaped deliveries).
+        d = client.__dict__
+        template = fan.plain and "send" not in d and "send_buffers" not in d
+        if qos == 0:
+            if client.closed:
+                return  # QoS0 is not queued for offline clients
+            if self.overload.shedding:
+                self._shed_qos0(client)
+                return  # above the high-water mark: QoS0 fan-out shed
+            if fan.plain and self._fast_qos0_eligible(client, sub, packet):
+                self._send_fast_qos0(client, packet, fan)
+            elif not (template and self._send_template_qos0(
+                    client, sub, packet, fan)):
+                self._send_outbound(
+                    client, self._build_outbound(client, sub, packet, fan),
+                    packet, fan)
             return
 
-        out = self._build_outbound(client, sub, packet)
-        if client.closed and out.fixed.qos == 0:
-            return  # QoS0 is not queued for offline clients
-        if out.fixed.qos > 0 and not self._enqueue_qos(client, out):
+        out = self._build_outbound(client, sub, packet, fan)
+        if not self._enqueue_qos(client, out, packet, fan, template):
             return  # dropped, exhausted, or parked on send quota
         if client.closed:
             return  # queued in inflight for session resume
-        if (template and out.fixed.qos > 0
-                and self._send_template_qos(client, out, packet)):
-            return
-        self._send_outbound(client, out, packet)
+        if template:
+            if self._send_template_qos(client, out, packet, fan):
+                return
+            # ``out`` is the session's inflight entry and the writer
+            # queue takes a Packet: it gets one of its own
+            out = out.copy()
+        self._send_outbound(client, out, packet, fan)
 
     def _send_outbound(self, client: Client, out: Packet,
-                       packet: Packet) -> None:
+                       packet: Packet, fan: _FanOut) -> None:
         """Enqueue one shaped delivery: a refusal rolls back (ADR 012),
         an accepted one registers its ADR-015 drain watcher."""
         if not client.send(out):
             self._count_refused_send(client, out)
-        elif self.tracer.sample_n or self.tracer.adopted_open:
+        elif fan.tracing:
             self._trace_drain(client, packet)
 
-    def _shed_qos0(self, client: Client, sub: Subscription,
-                   packet: Packet) -> bool:
+    def _shed_qos0(self, client: Client) -> None:
         """Global load-shed (ADR 012): while above the high-water mark
-        effective-QoS0 fan-out is shed outright; QoS>0 continues on the
-        session/inflight rules."""
-        if (not self.overload.shedding or client.closed
-                or min(packet.fixed.qos, sub.qos,
-                       self.capabilities.maximum_qos) > 0):
-            return False
+        effective-QoS0 fan-out to live clients is shed outright; QoS>0
+        continues on the session/inflight rules."""
         self.overload.shed_messages += 1
         self.info.messages_dropped += 1
         client.note_drop("shed")
-        return True
 
     def _count_refused_send(self, client: Client, out: Packet) -> None:
         """A delivery the outbound queue/byte budget refused. QoS>0 is
@@ -1814,25 +1845,21 @@ class Broker:
             self._release_held(client)
 
     def _build_outbound(self, client: Client, sub: Subscription,
-                        packet: Packet) -> Packet:
-        """Shape the delivery copy for one subscriber: effective QoS,
-        retain-as-published, and the v5 property set (subscription ids,
-        outbound topic alias)."""
-        out = packet.copy()
-        tr = self._packet_trace(packet)
-        if tr is not None:
-            # ADR 017: a lightweight (origin, id) tag — NOT the trace
-            # itself (delivery copies must not alias the span list) —
-            # so downstream hooks (session replication) can correlate
-            out._trace_ref = (tr.origin or self.tracer.node_id, tr.id)
-        out.protocol_version = client.properties.protocol_version
-        out.fixed.qos = min(packet.fixed.qos, sub.qos,
-                            self.capabilities.maximum_qos)
-        out.fixed.dup = False
-        if not sub.retain_as_published:
-            out.fixed.retain = False
-        if client.properties.protocol_version < 5:
-            out.properties = type(out.properties)()
+                        packet: Packet,
+                        fan: _FanOut | None = None) -> Packet:
+        """Shape the delivery for one subscriber (``Packet.delivery``):
+        effective QoS, retain-as-published, and for a v5 receiver the
+        publish's properties with this subscription's identifiers and
+        the outbound topic alias."""
+        if fan is None:
+            fan = _FanOut(self, packet)
+        version = client.properties.protocol_version
+        out = packet.delivery(
+            version, min(sub.qos, fan.qos),
+            packet.fixed.retain if sub.retain_as_published else False)
+        if fan.trace_ref is not None:
+            out._trace_ref = fan.trace_ref
+        if version < 5:
             return out
         ids = sorted(set(sub.identifiers.values())
                      or ({sub.identifier} if sub.identifier else set()))
@@ -1847,10 +1874,18 @@ class Broker:
                 out.properties.topic_alias = alias
         return out
 
-    def _enqueue_qos(self, client: Client, out: Packet) -> bool:
+    def _enqueue_qos(self, client: Client, out: Packet, packet: Packet,
+                     fan: _FanOut, entry_is_out: bool) -> bool:
         """QoS>0 inflight bookkeeping; returns False when the message
         was dropped (cap), exhausted (no free packet id), or parked
-        until an ack returns send quota (_release_held)."""
+        until an ack returns send quota (_release_held).
+
+        ``entry_is_out``: the template path will send this delivery, so
+        nothing but the session holds ``out`` (byte buffers are queued,
+        not the Packet) and it is the inflight entry itself. Nobody
+        mutates an entry in place: resends, held releases and takeovers
+        copy first (ADR 019). On the slow path the writer queue holds
+        ``out``, and the entry is a copy of it."""
         if len(client.inflight) >= self.capabilities.maximum_inflight:
             self.info.inflight_dropped += 1
             self.hooks.notify("on_qos_dropped", client, out)
@@ -1861,21 +1896,28 @@ class Broker:
             self.hooks.notify("on_packet_id_exhausted", client, out)
             return False
         out.created = time.time()
-        client.inflight.set(out.copy())
+        client.inflight.set(out if entry_is_out else out.copy())
         self.info.inflight += 1
-        if not client.inflight.take_send_quota():
-            client.held_pids.append(out.packet_id)
+        sent = client.inflight.take_send_quota()
+        if not sent:
             # ADR 018 (satellite): a quota-parked message is IN the
-            # window — notify now so the storage hook journals it and
-            # the session federation replicates it (held=True rides the
-            # record); the release notifies again, clearing the flag.
-            # Without this, a crash or takeover silently dropped every
-            # held message (the shared ADR-014/016 NOT-done gap).
-            self.hooks.notify("on_qos_publish", client, out,
-                              out.created, 0)
-            return False
-        self.hooks.notify("on_qos_publish", client, out, out.created, 0)
-        return True
+            # window — notify all the same so the storage hook journals
+            # it and the session federation replicates it (held=True
+            # rides the record); the release notifies again, clearing
+            # the flag. Without this, a crash or takeover silently
+            # dropped every held message (the shared ADR-014/016
+            # NOT-done gap).
+            client.held_pids.append(out.packet_id)
+        if fan.qos_publish:
+            # for the length of the notification ``out`` names the
+            # publish it was shaped from: a handler that records the
+            # delivery (hooks/storage.py) takes what every receiver's
+            # record shares from there, built once
+            out._src = packet
+            for handler in fan.qos_publish:
+                handler(client, out, out.created, 0)
+            del out._src
+        return sent
 
     # ------------------------------------------------------------------
     # QoS acknowledgement state machines (v2/server.go:909-987)

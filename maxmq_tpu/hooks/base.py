@@ -202,6 +202,11 @@ class Hooks:
         """True when any hook implements ``event`` (fast-path gates)."""
         return bool(self._overriders(event))
 
+    def handlers(self, event: str) -> tuple:
+        """The bound methods ``notify(event, ...)`` calls, in its order:
+        a fan-out reads them once and calls them for each receiver."""
+        return tuple(getattr(h, event) for h in self._overriders(event))
+
     def notify(self, event: str, *args) -> None:
         for h in self._overriders(event):
             getattr(h, event)(*args)

@@ -156,6 +156,55 @@ class MessageRecord:
         return cls(**d)
 
 
+_json_str = json.encoder.encode_basestring_ascii    # json.dumps of a str
+
+
+def _record_fragment(src: Packet) -> tuple:
+    """What the inflight records of one publish's receivers share, as
+    the pieces of ``MessageRecord.to_json()``'s string between the
+    receiver's own fields: built once per publish and cached on it
+    beside its wire templates (ADR 019). The last two are the record's
+    end for a v3.1.1 receiver, who gets no properties, and for a v5
+    receiver, who gets the publish's."""
+    frag = src.__dict__.get("_rec")
+    if frag is None:
+        rec = MessageRecord.from_packet(src)
+        end = ', "expiry": null, "properties_json": %s, "held": '
+        frag = src.__dict__["_rec"] = (
+            f', "origin": {_json_str(rec.origin)}'
+            f', "topic": {_json_str(rec.topic)}'
+            f', "payload": "{rec.payload.hex()}", "qos": ',
+            f', "packet_type": {json.dumps(rec.packet_type)}, "created": ',
+            end % '"{}"',
+            end % _json_str(rec.properties_json))
+    return frag
+
+
+def _spliced_record(client_id: str, packet: Packet, src: Packet,
+                    held: bool) -> str | None:
+    """``MessageRecord.from_packet(packet, client_id)`` with ``held``,
+    ``.to_json()``, byte for byte, for a delivery the broker shaped from
+    the publish ``src`` (``Broker._build_outbound``): the receiver's
+    own fields spliced into the publish's shared fragment. ``None``
+    where this receiver's record shares less than that: subscription
+    identifiers, its own or the publish's (its ``properties_json`` is
+    its own), a topic alias in the topic's place, a retain flag that is
+    no bool."""
+    fixed = packet.fixed
+    retain = fixed.retain
+    v5 = packet.protocol_version >= 5
+    if (packet.topic is not src.topic
+            or not (retain is False or retain is True)
+            or (v5 and (packet.properties.subscription_ids
+                        or src.properties.subscription_ids))):
+        return None
+    head, mid, end_v4, end_v5 = _record_fragment(src)
+    return (f'{{"client_id": {_json_str(client_id)}{head}{fixed.qos:d}'
+            f', "retain": {"true" if retain else "false"}'
+            f', "packet_id": {packet.packet_id:d}{mid}{packet.created!r}'
+            f'{end_v5 if v5 else end_v4}{"true" if held else "false"}}}')
+
+
 QUARANTINE_BUCKET = "quarantine"
 
 
@@ -361,14 +410,26 @@ class StorageHook(Hook):
             # so the rewrite buys nothing — skip it (ADR 014)
             self.rewrites_skipped += 1
             return
-        rec = MessageRecord.from_packet(packet, client.id)
-        if packet.packet_id in getattr(client, "held_pids", ()):
-            # ADR 018: quota-parked — persist the held-ness so restore
-            # re-parks instead of resending past receive maximum (the
-            # release rewrites the record with held cleared)
-            rec.held = True
-        self.store.put("inflight", f"{client.id}|{packet.packet_id}",
-                       rec.to_json())
+        # ADR 018: quota-parked — persist the held-ness so restore
+        # re-parks instead of resending past receive maximum (the
+        # release rewrites the record with held cleared)
+        held = packet.packet_id in getattr(client, "held_pids", ())
+        # ADR 019: a first transmission names the publish it was shaped
+        # from, and its record is spliced from what that publish's
+        # receivers share; everything else is built whole
+        src = packet.__dict__.get("_src")
+        value = (None if src is None or resends
+                 else _spliced_record(client.id, packet, src, held))
+        ledger = getattr(getattr(client, "server", None), "overload", None)
+        if value is None:
+            rec = MessageRecord.from_packet(packet, client.id)
+            rec.held = held
+            value = rec.to_json()
+            if ledger is not None:
+                ledger.records_built += 1
+        elif ledger is not None:
+            ledger.records_spliced += 1
+        self.store.put("inflight", f"{client.id}|{packet.packet_id}", value)
         if inflight is not None:
             inflight.note_stored(packet.packet_id)
 

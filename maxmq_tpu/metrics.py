@@ -961,6 +961,19 @@ def _register_fanout_metrics(registry: Registry, broker) -> None:
         "Inbound PUBACKs handled: one a QoS 1 delivery, each an inflight "
         "release and a journal delete on the read path",
         lambda: over.fanout_acks)
+    for name, help_ in (
+            ("records_spliced",
+             "Inflight records of QoS>0 deliveries the storage hook "
+             "assembled from the fragment their publish's receivers "
+             "share (ADR 019): no MessageRecord, asdict or json.dumps "
+             "a receiver"),
+            ("records_built",
+             "Inflight records built whole: per-receiver v5 properties "
+             "(subscription identifiers, an alias in the topic's "
+             "place), resends, held releases (spliced / (spliced + "
+             "built) = the share that shared its publish's part)")):
+        registry.counter_func(f"maxmq_broker_fanout_{name}_total",
+                              help_, lambda n=name: getattr(over, n))
     sched = getattr(broker, "flush_sched", None)
     if sched is not None:
         for name, help_ in (

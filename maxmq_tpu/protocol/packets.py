@@ -162,6 +162,22 @@ class Packet:
                      for s in self.filters]
         return p
 
+    def delivery(self, version: int, qos: int, retain: bool) -> "Packet":
+        """One receiver's copy of this PUBLISH, made from the fields a
+        delivery carries and no others: a fixed header of its own (DUP
+        clear), topic, payload, origin and created, and for a v5
+        receiver a copy of the properties; a v3.1.1 receiver gets none.
+        It shares no mutable object with ``self``."""
+        f = self.fixed
+        q = _blank_packet(
+            FixedHeader(f.type, False, qos, retain, f.remaining), version,
+            self.properties.copy() if version >= 5 else None)
+        q.topic = self.topic
+        q.payload = self.payload
+        q.origin = self.origin
+        q.created = self.created
+        return q
+
     @property
     def v5(self) -> bool:
         return self.protocol_version >= 5
@@ -558,7 +574,8 @@ _VALID_REASONS = {
 _PACKET_TEMPLATE: dict | None = None
 
 
-def _blank_packet(fixed: FixedHeader, protocol_version: int) -> "Packet":
+def _blank_packet(fixed: FixedHeader, protocol_version: int,
+                  properties: Properties | None = None) -> "Packet":
     global _PACKET_TEMPLATE
     if _PACKET_TEMPLATE is None:
         import dataclasses
@@ -577,7 +594,7 @@ def _blank_packet(fixed: FixedHeader, protocol_version: int) -> "Packet":
     q.protocol_version = protocol_version
     q.reason_codes = []
     q.filters = []
-    q.properties = blank_properties()
+    q.properties = blank_properties() if properties is None else properties
     return q
 
 

@@ -128,8 +128,8 @@ class Properties:
         return self == Properties()
 
     def copy(self) -> "Properties":
-        p = Properties(**{k: v for k, v in self.__dict__.items()
-                          if k not in ("subscription_ids", "user_properties")})
+        p = object.__new__(Properties)
+        p.__dict__.update(self.__dict__)
         p.subscription_ids = list(self.subscription_ids)
         p.user_properties = list(self.user_properties)
         return p

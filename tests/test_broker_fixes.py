@@ -13,6 +13,7 @@ import time
 import pytest
 
 from maxmq_tpu.broker import Broker, BrokerOptions, Capabilities
+from maxmq_tpu.broker.server import _FanOut
 from maxmq_tpu.protocol.codec import FixedHeader, PacketType as PT
 from maxmq_tpu.protocol.packets import Packet
 
@@ -135,7 +136,7 @@ def test_fast_qos0_wire_matches_full_encoder():
                          topic=topic, payload=payload, packet_id=9)
             want = b._delivery_form(pkt, version).encode()
             sink = _WireSink(version)
-            b._send_fast_qos0(sink, pkt)
+            b._send_fast_qos0(sink, pkt, _FanOut(b, pkt))
             assert sink.wires == [want], (version, topic)
 
 

@@ -479,6 +479,7 @@ def test_sqlite_apply_batch_single_transaction(tmp_path):
 class _StubOverload:
     def __init__(self, shedding):
         self.shedding = shedding
+        self.records_spliced = self.records_built = 0
 
 
 class _StubServer:
