@@ -488,6 +488,7 @@ class Client:
             if not chunk:
                 return
             self.server.info.bytes_received += len(chunk)
+            self.server.overload.read_chunks += 1
             self.last_received = time.monotonic()
             buf.extend(chunk)
 

@@ -103,6 +103,17 @@ class OverloadState:
         # delivery on the read path)
         self.fanout_widest = 0
         self.fanout_acks = 0
+        # $share picks (one a (group, filter) key a publish chose a
+        # member for), the candidates in the sets picked from (over
+        # picks: the mean width of a group; the pick sorts and the
+        # resolve counts that many ids a publish) and the widest set
+        self.share_picks = 0
+        self.share_candidates = 0
+        self.share_widest = 0
+        # socket reads that returned bytes (Client.read_loop): beside
+        # packets_received, packets a chunk is what a read task's
+        # wake-up is shared by
+        self.read_chunks = 0
         # the inflight records the storage hook put for QoS>0
         # deliveries (ADR 019): records_spliced were assembled from the
         # fragment their publish's receivers share, records_built whole

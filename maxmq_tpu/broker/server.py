@@ -22,7 +22,7 @@ from ..filtering.expr import ExprError, decode_payload
 from ..filtering.plane import (ContentPlane, ContentQuota,
                                USER_PROP_KEY as FILTER_PROP_KEY)
 from ..hooks.base import Hook, Hooks, RejectPacket
-from ..trace import MAX_DRAIN_SPANS, PipelineTracer, host_span
+from ..trace import MAX_DRAIN_SPANS, NO_SPAN, PipelineTracer, host_span
 from ..matching.topics import valid_filter, valid_topic_name
 from ..matching.trie import (SubscriberSet, TopicIndex,
                              VersionedTopicCache)
@@ -1512,6 +1512,41 @@ class Broker:
         resolved ``pairs``) is skipped [MQTT-4.8.2-4]. ``shared`` holds
         only keys with a registered candidate: a key without one picks
         nobody and moves no cursor, so it was cut before this."""
+        tracer = self.tracer
+        if tracer.sample_n or tracer.adopted_open:
+            selected = self._pick_shared_traced(shared, packet)
+        else:
+            selected = self._pick_shared(shared, packet)
+        if not selected:
+            return
+        plain = {client.id for client, _sub in pairs}
+        fan = _FanOut(self, packet)
+        get = self.clients.get
+        for cid, sub in selected.items():
+            if cid not in plain:
+                self._publish_to_client(get(cid), sub, packet, True, fan)
+
+    def _pick_shared_traced(self, shared, packet: Packet) -> dict:
+        """``_pick_shared`` while tracing is on: the choosing alone,
+        from the first key to the last pick, is the ADR-015 stage
+        ``share_pick`` of a sampled publish (a child of its ``fanout``;
+        the deliveries are not in it) and, in a profiler capture, the
+        annotation ``maxmq.share`` inside the ``maxmq.deliver`` around
+        it."""
+        tracer = self.tracer
+        tr = packet.__dict__.get("_trace")
+        t0 = tracer.clock()
+        # no annotation for an adopted trace alone (ADR 017)
+        with host_span("maxmq.share") if tracer.sample_n else NO_SPAN:
+            selected = self._pick_shared(shared, packet)
+        if tr is not None:
+            tr.span("share_pick", t0, tracer.clock())
+        return selected
+
+    def _pick_shared(self, shared, packet: Packet) -> dict:
+        """client id -> subscription, for the member each (group,
+        filter) key of ``shared`` chose (the highest QoS where one
+        client was chosen twice)."""
         selected: dict[str, Subscription] = {}
         sessions = self._cluster_sessions()
         token = None
@@ -1524,6 +1559,7 @@ class Broker:
             token = crc32(packet.payload,
                           crc32(packet.topic.encode()))
         get = self.clients.get
+        overload = self.overload
         for (group, filt), candidates in shared.items():
             if sessions is not None and not sessions.owns_share(
                     group, filt, token):
@@ -1537,17 +1573,16 @@ class Broker:
                 alive=lambda cid: (c := get(cid)) is not None
                 and not c.closed)
             if pick is not None:
+                width = len(candidates)
+                overload.share_picks += 1
+                overload.share_candidates += width
+                if width > overload.share_widest:
+                    overload.share_widest = width
                 cid, sub = pick
                 prev = selected.get(cid)
                 if prev is None or sub.qos > prev.qos:
                     selected[cid] = sub
-        if not selected:
-            return
-        plain = {client.id for client, _sub in pairs}
-        fan = _FanOut(self, packet)
-        for cid, sub in selected.items():
-            if cid not in plain:
-                self._publish_to_client(get(cid), sub, packet, True, fan)
+        return selected
 
     async def _match_async(self, topic: str) -> SubscriberSet:
         async_fn = getattr(self.matcher, "subscribers_async", None)

@@ -962,6 +962,24 @@ def _register_fanout_metrics(registry: Registry, broker) -> None:
         "release and a journal delete on the read path",
         lambda: over.fanout_acks)
     for name, help_ in (
+            ("share_picks",
+             "$share picks made: one a (group, filter) key of a publish "
+             "for which a member was chosen"),
+            ("share_candidates",
+             "Candidates in the $share sets picked from (over "
+             "share_picks_total: the mean width of a group; a pick "
+             "sorts that many ids and the resolve counts them)"),
+            ("read_chunks",
+             "Socket reads that returned bytes (over "
+             "maxmq_mqtt_packets_received: packets a chunk, what one "
+             "read task's wake-up is shared by)")):
+        registry.counter_func(f"maxmq_broker_{name}_total", help_,
+                              lambda n=name: getattr(over, n))
+    registry.gauge_func(
+        "maxmq_broker_share_widest",
+        "Most candidates a $share set picked from held since start",
+        lambda: over.share_widest)
+    for name, help_ in (
             ("records_spliced",
              "Inflight records of QoS>0 deliveries the storage hook "
              "assembled from the fragment their publish's receivers "

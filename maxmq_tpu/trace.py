@@ -18,6 +18,10 @@ Stage model (see docs/adr/015-publish-tracing.md for the contract):
 ``match_device``   device/trie match time (dispatch -> result ready)
 ``pipeline_wait``  in-order fan-out queueing behind earlier publishes
 ``fanout``         local subscriber selection + outbound enqueue/encode
+``share_pick``     the $share picks of a fan-out alone: from its first
+                   (group, filter) key to its last pick, the deliveries
+                   not included (a child of ``fanout``, not critical;
+                   only a publish that had a $share key has one)
 ``bridge``         cluster route consult + forward enqueue (ADR 013)
 ``journal_commit`` storage group-commit duration (writer thread,
                    histogram-only: not tied to one publish)
@@ -121,18 +125,20 @@ BATCH_PHASES = ("match_host", "match_prep", "match_probe",
 # canonical pipeline stages; CRITICAL_STAGES are the contiguous
 # publisher-path segments whose durations sum to ~e2e (drain happens
 # after the publisher's terminal stage, and so may the flush pass that
-# writes it; journal_commit/takeover/release are not tied to one
+# writes it; share_pick is a part of fanout, which is counted whole;
+# journal_commit/takeover/release are not tied to one
 # publish's critical path; bridge_in is critical
 # only on ADOPTED traces, where it IS the path's first local segment;
 # loop_lag is a probe of the loop beside the path)
 STAGES = ("decode", "admission", "match_queue", "match_device",
-          "pipeline_wait", "filter", "fanout", "bridge", "bridge_in",
-          "journal_commit", "barrier", "ack", "drain", "flush",
+          "pipeline_wait", "filter", "fanout", "share_pick", "bridge",
+          "bridge_in", "journal_commit", "barrier", "ack", "drain", "flush",
           "takeover", "release", "aggregate", "loop_lag") + BATCH_PHASES
 CRITICAL_STAGES = frozenset(
     s for s in STAGES
-    if s not in ("drain", "flush", "journal_commit", "takeover", "release",
-                 "aggregate", "loop_lag") + BATCH_PHASES)
+    if s not in ("drain", "flush", "share_pick", "journal_commit",
+                 "takeover", "release", "aggregate", "loop_lag")
+    + BATCH_PHASES)
 # 10us .. 1s: a phase of one micro-batch is tens of microseconds to a
 # few milliseconds, under the default ladder's first bound
 BATCH_PHASE_BUCKETS = (
@@ -651,7 +657,9 @@ class PipelineTracer:
     def _entry(trace: PublishTrace, e2e_ns: int, slow: bool) -> dict:
         start = trace.start_ns
         spans = [_span_dict(start, s, t0, dur, "", trace.batch, trace.via)
-                 if s == "match_device" else _span_dict(start, s, t0, dur)
+                 if s == "match_device" else
+                 _span_dict(start, s, t0, dur,
+                            "fanout" if s == "share_pick" else "")
                  for s, t0, dur in trace.spans]
         spans += [_span_dict(start, s, t0, dur, "match_device", batch,
                              "", shadow)

@@ -109,6 +109,15 @@ CORPORA = {
         [([sub(f"w{i}", "$share/g1/t/+") for i in range(5)],
           ["t/a", "t", "t/a/b"])],
         after_sig=_one_group_entry),
+    # fleet-fanin-500's ingest pool: one $share key with 500 candidates
+    # (one row bit, a candidate map of 500) beside a plain subscriber
+    "wide_share_group": Corpus(
+        [([sub(f"ingest-{i}", "$share/ingest/fleet/telemetry/#", qos=1)
+           for i in range(500)]
+          + [sub("audit", "fleet/+/dev-7"),
+             sub("ingest-3", "$share/other/fleet/telemetry/dev-7")],
+          ["fleet/telemetry/dev-7", "fleet/telemetry/dev-49999",
+           "fleet/telemetry", "fleet/broadcast/cmd-1", "fleet"])]),
 }
 
 

@@ -213,3 +213,23 @@ def test_a_puback_inside_a_read_gets_a_row_of_its_own(tool):
     # without one, a thread's events are its top-level ones, as before
     plain = [e for e in events if e[0] != "maxmq.ack"]
     assert tool.carve(plain) == tool.top_level(plain)
+
+
+def test_the_share_picks_inside_a_deliver_get_a_row_of_their_own(tool):
+    """``maxmq.share`` is opened inside a publish's ``maxmq.deliver``
+    (or, on the trie path, inside the chunk's ``maxmq.read``): the picks
+    are cut out of the span around them, beside the PUBACKs."""
+    events = [("maxmq.deliver", 0, 100), ("maxmq.share", 5, 40),
+              ("maxmq.flush", 60, 90),
+              ("maxmq.read", 200, 300), ("maxmq.ack", 210, 220),
+              ("maxmq.share", 230, 250), ("maxmq.deliver", 400, 420)]
+    carved = tool.carve(events)
+    assert carved == [("maxmq.deliver", 0, 5), ("maxmq.share", 5, 40),
+                      ("maxmq.deliver", 40, 100), ("maxmq.read", 200, 210),
+                      ("maxmq.ack", 210, 220), ("maxmq.read", 220, 230),
+                      ("maxmq.share", 230, 250), ("maxmq.read", 250, 300),
+                      ("maxmq.deliver", 400, 420)]
+    got = tool.attribute([(0, 500)], tool.spans_by_name([carved]))
+    assert got["names"] == {"maxmq.deliver": 85e-9, "maxmq.share": 55e-9,
+                            "maxmq.read": 70e-9, "maxmq.ack": 10e-9}
+    assert got["unannotated"] == pytest.approx(280e-9)

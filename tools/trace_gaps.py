@@ -16,8 +16,9 @@ a file and prints:
 * for the event loop's thread, and for the other threads together, the
   seconds of that idle time each top-level ``maxmq.*`` name covers, its
   share of the idle time, and what no annotation covers (``maxmq.ack``,
-  a subscriber's PUBACK handled inside a chunk's ``maxmq.read``, is cut
-  out of the span around it and given a row of its own);
+  a subscriber's PUBACK handled inside a chunk's ``maxmq.read``, and
+  ``maxmq.share``, the $share picks inside a ``maxmq.deliver``, are cut
+  out of the span around them and given rows of their own);
 * the ten longest idle gaps, each with the name that covers most of it;
 * two checks of the clocks: how many device operations began inside an
   annotated dispatch -> fetch of one batch, and the tracer's clock minus
@@ -50,7 +51,7 @@ PREFIX = "maxmq."
 LOOP_MARKS = ("maxmq.read", "maxmq.deliver", "maxmq.settle")
 # nested annotations that get a row of their own: their time is taken
 # from the span around them
-CARVED = ("maxmq.ack",)
+CARVED = ("maxmq.ack", "maxmq.share")
 
 
 # -- interval arithmetic (nanoseconds; an interval is (start, end)) --------
