@@ -77,6 +77,7 @@ import logging
 import threading
 import time
 from collections import deque
+from operator import attrgetter
 
 from .. import faults
 from .faultstore import FsyncFailed
@@ -130,6 +131,13 @@ class _Op:
         self.key = key
         self.value = value
         self.size = size
+
+
+# what the writer thread reads off a batch, each in one C pass: it
+# shares the interpreter with the event loop (ADR 014)
+_op_tuple = attrgetter("kind", "bucket", "key", "value")
+_op_bucket = attrgetter("bucket")
+_op_bytes = attrgetter("size")
 
 
 def _op_size(bucket: str, key: str, value: str | None) -> int:
@@ -456,13 +464,18 @@ class WriteBehindStore(Store):
         return True
 
     def _take_batch_locked(self, n: int) -> list[_Op]:
-        batch: list[_Op] = []
-        while self._order and len(batch) < n:
-            op = self._order.popleft()
-            batch.append(op)
-            if (op.kind != _OP_DELETE_PREFIX
-                    and self._pending.get((op.bucket, op.key)) is op):
-                del self._pending[(op.bucket, op.key)]
+        order, pending = self._order, self._pending
+        if len(order) <= n:
+            # the whole queue, which is every op a pending key names
+            batch = list(order)
+            order.clear()
+            pending.clear()
+            return batch
+        batch = [order.popleft() for _ in range(n)]
+        for op in batch:
+            key = (op.bucket, op.key)
+            if pending.get(key) is op:
+                del pending[key]
         return batch
 
     def _commit_batch(self) -> None:
@@ -482,8 +495,7 @@ class WriteBehindStore(Store):
                 # parked journal replays through the fresh connection
                 self._reopen_poisoned()
             faults.crash_point("pre_fsync")
-            self.inner.apply_batch(
-                [(op.kind, op.bucket, op.key, op.value) for op in batch])
+            self.inner.apply_batch(list(map(_op_tuple, batch)))
             faults.crash_point("post_fsync_pre_ack")
         except Exception as exc:
             self._commit_failed(batch, exc)
@@ -496,11 +508,12 @@ class WriteBehindStore(Store):
             # ADR 017 (closing ADR-015's per-op attribution item): the
             # same commit attributed to each storage bucket it touched,
             # so "which writes own the fsync time" is answerable
-            for bucket in {op.bucket for op in batch}:
+            for bucket in set(map(_op_bucket, batch)):
                 self.tracer.observe_journal(bucket, dt)
+        committed_bytes = sum(map(_op_bytes, batch))
         with self._lock:
             self.committed_seq = max(self.committed_seq, batch[-1].seq)
-            self.queued_bytes_now -= sum(op.size for op in batch)
+            self.queued_bytes_now -= committed_bytes
             self._resolve_barriers_locked(self.committed_seq)
             self.commits += 1
             self.ops_written += len(batch)

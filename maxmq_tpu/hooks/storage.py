@@ -20,6 +20,7 @@ into when the broker is load-shedding past the journal watermark.
 
 from __future__ import annotations
 
+import functools
 import json
 import logging
 import os
@@ -27,6 +28,8 @@ import sqlite3
 import threading
 import time
 from dataclasses import asdict, dataclass, fields
+from itertools import chain, groupby
+from operator import itemgetter
 
 from .. import faults
 from ..protocol.codec import FixedHeader, PacketType as PT
@@ -508,6 +511,51 @@ class CorruptStoreError(Exception):
     trigger the move-aside."""
 
 
+_DELETE_PREFIX_SQL = "DELETE FROM kv WHERE bucket=? AND key GLOB ?"
+
+# rows one statement of a group commit carries at most: the writer
+# thread holds the interpreter while it binds a statement's values and
+# lets go of it for the statement's whole step, so a few hundred rows
+# keep the stretch it holds short (ADR 014); the connection's own
+# variable limit can only lower them
+_PUT_ROWS = 256
+_DELETE_ROWS = 512
+
+_op_kind = itemgetter(0)
+_op_bucket = itemgetter(1)
+_op_key = itemgetter(2)
+_op_row = itemgetter(1, 2, 3)
+
+
+@functools.cache        # _row_slices asks for a handful of sizes
+def _put_sql(rows: int) -> str:
+    return ("INSERT INTO kv (bucket, key, value) VALUES "
+            + ",".join(["(?,?,?)"] * rows)
+            + " ON CONFLICT(bucket, key) DO UPDATE SET value=excluded.value")
+
+
+@functools.cache
+def _delete_sql(keys: int) -> str:
+    return ("DELETE FROM kv WHERE bucket=? AND key IN ("
+            + ",".join("?" * keys) + ")")
+
+
+def _floor_pow2(n: int) -> int:
+    return 1 << (n.bit_length() - 1)
+
+
+def _row_slices(n: int, most: int):
+    """``n`` rows as ``(start, rows)`` of one statement each: ``most``
+    rows (a power of two) while that many are left, then the powers of
+    two of the rest, so that the statement texts are a small fixed set
+    and the ``sqlite3`` module's statement cache holds every one."""
+    at = 0
+    while at < n:
+        rows = min(most, _floor_pow2(n - at))
+        yield at, rows
+        at += rows
+
+
 class SQLiteStore(Store):
     """Durable store on stdlib sqlite3 (WAL mode).
 
@@ -522,6 +570,7 @@ class SQLiteStore(Store):
         self.path = path
         self.corruptions = 0
         self.aside_failures = 0         # forensic move-asides that failed
+        self.batch_statements = 0       # statements group commits executed
         self._synchronous = synchronous
         self._busy_timeout_ms = busy_timeout_ms
         self.log = logger or _log
@@ -558,9 +607,16 @@ class SQLiteStore(Store):
                 "bucket TEXT NOT NULL, key TEXT NOT NULL, value TEXT NOT NULL,"
                 "PRIMARY KEY (bucket, key))")
             conn.commit()
+            # Python 3.10 cannot ask; 999 is the least a stock build has
+            variables = (conn.getlimit(sqlite3.SQLITE_LIMIT_VARIABLE_NUMBER)
+                         if hasattr(conn, "getlimit") else 999)
         except BaseException:
             conn.close()
             raise
+        # a statement's rows by what this connection binds at most: a
+        # put row is three variables, a delete's keys follow its bucket
+        self._put_rows = _floor_pow2(min(_PUT_ROWS, variables // 3))
+        self._delete_rows = _floor_pow2(min(_DELETE_ROWS, variables - 1))
         return conn
 
     def _recreate_aside(self, path: str, exc: Exception):
@@ -640,7 +696,7 @@ class SQLiteStore(Store):
     def delete_prefix(self, bucket, prefix):
         with self._lock:
             self._conn.execute(
-                "DELETE FROM kv WHERE bucket=? AND key GLOB ?",
+                _DELETE_PREFIX_SQL,
                 (bucket, prefix.replace("[", "[[]") + "*"))
             self._conn.commit()
 
@@ -650,37 +706,52 @@ class SQLiteStore(Store):
                 "SELECT key, value FROM kv WHERE bucket=?", (bucket,)).fetchall()
         return dict(rows)
 
+    def _batch_statements(self, ops):
+        """The statements of one group commit, in the batch's order:
+        a run of consecutive puts as multi-row upserts (a later row of
+        a key wins over an earlier one, as a later op does), a run of
+        consecutive deletes of one bucket as multi-key deletes, a
+        prefix delete by itself and between runs, the barrier it is in
+        the journal. Nothing here is a Python pass over the ops."""
+        for kind, run in groupby(ops, _op_kind):
+            if kind == "put":
+                rows = list(chain.from_iterable(map(_op_row, run)))
+                for at, n in _row_slices(len(rows) // 3, self._put_rows):
+                    yield _put_sql(n), rows[3 * at:3 * (at + n)]
+            elif kind == "delete":
+                for bucket, same in groupby(run, _op_bucket):
+                    keys = list(map(_op_key, same))
+                    for at, n in _row_slices(len(keys), self._delete_rows):
+                        yield _delete_sql(n), [bucket, *keys[at:at + n]]
+            else:
+                for _, bucket, prefix, _ in run:
+                    yield (_DELETE_PREFIX_SQL,
+                           (bucket, prefix.replace("[", "[[]") + "*"))
+
     def apply_batch(self, ops):
         """Group commit (ADR 014): the whole batch is ONE transaction —
         one fsync per batch under synchronous=FULL, and a crash leaves
-        either all of it or none of it."""
-        mid = len(ops) // 2
+        either all of it or none of it. It costs the interpreter a
+        statement a run of ops and not one an op
+        (:meth:`_batch_statements`); the result is that of the ops
+        applied one by one in order."""
         with self._lock:
+            execute = self._conn.execute
+            done = 0
             try:
-                for i, (kind, bucket, key, value) in enumerate(ops):
-                    if i == mid:
-                        # ADR 024: die INSIDE the open transaction —
-                        # statements executed, nothing committed; the
+                for sql, values in self._batch_statements(ops):
+                    execute(sql, values)
+                    done += 1
+                    if done == 1:
+                        # ADR 024: die INSIDE the open transaction — a
+                        # statement executed, nothing committed; the
                         # restart must see all-or-nothing
                         faults.crash_point("mid_wal_write")
-                    if kind == "put":
-                        self._conn.execute(
-                            "INSERT INTO kv (bucket, key, value) "
-                            "VALUES (?, ?, ?) ON CONFLICT(bucket, key) "
-                            "DO UPDATE SET value=excluded.value",
-                            (bucket, key, value))
-                    elif kind == "delete":
-                        self._conn.execute(
-                            "DELETE FROM kv WHERE bucket=? AND key=?",
-                            (bucket, key))
-                    else:
-                        self._conn.execute(
-                            "DELETE FROM kv WHERE bucket=? AND key GLOB ?",
-                            (bucket, key.replace("[", "[[]") + "*"))
                 self._conn.commit()
             except BaseException:
                 self._conn.rollback()
                 raise
+            self.batch_statements += done
 
     def close(self):
         with self._lock:

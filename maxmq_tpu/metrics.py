@@ -797,6 +797,13 @@ def _register_storage_metrics(registry: Registry, broker) -> None:
             "still booted)", lambda: backing.aside_failures)
     if jr is None:
         return
+    if getattr(backing, "batch_statements", None) is not None:
+        registry.counter_func(
+            "maxmq_storage_statements_total",
+            "Backend statements executed by group commits; "
+            "ops_written_total over it is ops a statement, what the "
+            "writer thread's share of the interpreter goes by",
+            lambda: backing.batch_statements)
     for name, help_, fn in (
             ("queue_depth", "Journal ops awaiting group commit",
              lambda: jr.queue_depth),
