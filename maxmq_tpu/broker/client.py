@@ -17,7 +17,7 @@ from .. import faults
 from ..matching.trie import TopicAliases
 from ..protocol.codec import PacketType as PT
 from ..protocol.packets import Packet, ProtocolError, Subscription, Will, parse_stream
-from ..trace import annotated, host_span
+from ..trace import annotated
 from .inflight import Inflight
 
 
@@ -170,14 +170,18 @@ class FlushScheduler:
             return
         self._pending = []
         self.flushes += 1
-        traced = self._traced
-        if not traced:
+        tracer = self.tracer
+        if not (tracer.sample_n or self._traced):
             self._write(pending)
             return
-        self._traced = []
-        tracer = self.tracer
+        # ADR 015: while the tracer samples every pass is a ``pass``
+        # section (its own Python; the bursts' writevs are ``flush``
+        # sections inside it), and the pass that writes a sampled
+        # publish's deliveries is that publish's ``flush`` stage
+        traced, self._traced = self._traced, []
         t0 = tracer.clock()
-        self._write(pending)
+        with tracer.section("pass"):
+            self._write(pending)
         t1 = tracer.clock()
         for trace in traced:
             tracer.attach(trace, "flush", t0, t1)
@@ -472,9 +476,9 @@ class Client:
             if tracer.sample_n:
                 # ADR 015: the chunk's synchronous work (decode,
                 # admission, enqueue of every packet in it) as one host
-                # span of a profiler capture, closed wherever a handler
-                # really waits
-                await annotated("maxmq.read", self._dispatch_buffered(
+                # section (a profiler capture's span, the loop ledger's
+                # state), closed wherever a handler really waits
+                await annotated(tracer, "read", self._dispatch_buffered(
                     buf, maxsize, on_packet))
             else:
                 await self._dispatch_buffered(buf, maxsize, on_packet)
@@ -540,8 +544,8 @@ class Client:
         if writelines is None:
             self.writer.write(b"".join(bufs))
         elif tracer is not None and tracer.sample_n:
-            # ADR 015: the burst's one writev, for a profiler capture
-            with host_span("maxmq.flush", bufs=len(bufs)):
+            # ADR 015: the burst's one writev
+            with tracer.section("flush", bufs=len(bufs)):
                 writelines(bufs)
         else:
             writelines(bufs)

@@ -22,7 +22,7 @@ from ..filtering.expr import ExprError, decode_payload
 from ..filtering.plane import (ContentPlane, ContentQuota,
                                USER_PROP_KEY as FILTER_PROP_KEY)
 from ..hooks.base import Hook, Hooks, RejectPacket
-from ..trace import MAX_DRAIN_SPANS, NO_SPAN, PipelineTracer, host_span
+from ..trace import MAX_DRAIN_SPANS, PipelineTracer
 from ..matching.topics import valid_filter, valid_topic_name
 from ..matching.trie import (SubscriberSet, TopicIndex,
                              VersionedTopicCache)
@@ -292,6 +292,9 @@ class Broker:
     async def serve(self) -> None:
         self.loop = asyncio.get_running_loop()
         self._running = True
+        # ADR 015: the loop thread's own books; while the tracer samples,
+        # a stock selector loop's select is timed (idle, poll) until close
+        self.tracer.loop.attach(self.loop)
         # ADR 014: find the persistence hook (and its write-behind
         # journal, if it rides one) before restore — the durability
         # barrier and boot-epoch bump both hang off it
@@ -411,6 +414,7 @@ class Broker:
             self._pub_consumer = None
             self._pub_queue = None
         await self.listeners.close_all()
+        self.tracer.loop.detach()
         self.hooks.notify("on_stopped")
         self.hooks.stop_all()
 
@@ -1346,12 +1350,10 @@ class Broker:
     def _pub_deliver_traced(self, fut, subscribers, client,
                             packet: Packet, durable_ack: bool) -> None:
         """``_pub_deliver`` while tracing is on: the matcher leg's spans
-        first, then the delivery under its profiler annotation."""
+        first, then the delivery as the section ``deliver`` (none for an
+        adopted trace alone, ADR 017)."""
         self._trace_match_spans(fut, packet)
-        if not self.tracer.sample_n:    # an adopted trace alone (ADR 017)
-            self._pub_deliver(subscribers, client, packet, durable_ack)
-            return
-        with host_span("maxmq.deliver"):
+        with self.tracer.section("deliver"):
             self._pub_deliver(subscribers, client, packet, durable_ack)
 
     def _trace_match_spans(self, fut, packet: Packet) -> None:
@@ -1530,14 +1532,13 @@ class Broker:
         """``_pick_shared`` while tracing is on: the choosing alone,
         from the first key to the last pick, is the ADR-015 stage
         ``share_pick`` of a sampled publish (a child of its ``fanout``;
-        the deliveries are not in it) and, in a profiler capture, the
-        annotation ``maxmq.share`` inside the ``maxmq.deliver`` around
-        it."""
+        the deliveries are not in it) and the section ``share`` inside
+        the ``deliver`` around it (none for an adopted trace alone, ADR
+        017)."""
         tracer = self.tracer
         tr = packet.__dict__.get("_trace")
         t0 = tracer.clock()
-        # no annotation for an adopted trace alone (ADR 017)
-        with host_span("maxmq.share") if tracer.sample_n else NO_SPAN:
+        with tracer.section("share"):
             selected = self._pick_shared(shared, packet)
         if tr is not None:
             tr.span("share_pick", t0, tracer.clock())
@@ -1983,14 +1984,13 @@ class Broker:
         """A subscriber's PUBACK: the inflight entry and its send quota
         are released and the storage hook deletes the journalled
         record. A wide QoS 1 fan-out sends as many of these as it made
-        deliveries, so while tracing is on the work has a profiler
-        annotation of its own (``maxmq.ack``, inside the chunk's
-        ``maxmq.read``)."""
+        deliveries, so while tracing is on the work is a section of
+        its own (``ack``, inside the chunk's ``read``)."""
         self.overload.fanout_acks += 1
         if self.tracer.sample_n:
-            with host_span("maxmq.ack"):
+            with self.tracer.section("ack"):
                 self._release_acked(client, packet)
-        else:
+        else:                   # one a delivery: not even a no-op ``with``
             self._release_acked(client, packet)
 
     def _release_acked(self, client: Client, packet: Packet) -> None:

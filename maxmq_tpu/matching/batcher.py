@@ -27,7 +27,6 @@ import threading
 import time
 from typing import TYPE_CHECKING
 
-from ..trace import host_span
 from .supervisor import fail_batch
 from .trie import VersionedTopicCache, subs_version
 
@@ -221,7 +220,7 @@ class MicroBatcher:
         if tracer is None:
             self._resolve(version, batch, results, 0)
         else:
-            with host_span("maxmq.settle", n=len(batch)):
+            with tracer.section("settle", n=len(batch)):
                 self._resolve(version, batch, results, tracer.clock())
 
     def _resolve(self, version: int, batch, results, done_ns: int) -> None:
@@ -410,8 +409,8 @@ class MicroBatcher:
         rec.via = "host" if via_host else "trie"
         t0 = rec.tracer.clock()
         try:
-            with host_span("maxmq.batch", batch=rec.id, n=rec.n,
-                           via=rec.via, t0_ns=t0):
+            with rec.tracer.section("batch", batch=rec.id, n=rec.n,
+                                    via=rec.via, t0_ns=t0):
                 return rec.run(answer, topics)
         finally:
             rec.phase("match_host", t0, rec.tracer.clock())

@@ -500,6 +500,34 @@ def _register_trace_metrics(registry: Registry, broker) -> None:
         "maxmq_broker_trace_sample_n",
         "Publish sampling stride (0 = tracing off)",
         lambda: tracer.sample_n)
+    # the loop thread's own books (trace.LoopLedger): they move only
+    # while trace_sample_n > 0; utilisation = 1 - idle / sum
+    ledger = tracer.loop
+    registry.multi_func(
+        "maxmq_loop_seconds_total", "counter",
+        "Seconds the event loop's thread spent in each state: a maxmq.* "
+        "section (self time), and, while its select is timed, idle, poll "
+        "(a select that had ready handles) and other; moves only while "
+        "trace_sample_n > 0",
+        lambda: [({"state": state}, seconds) for state, seconds in
+                 ledger.report()["seconds"].items()])
+    registry.multi_func(
+        "maxmq_loop_entries_total", "counter",
+        "Times the event loop's thread entered each state (flush: the "
+        "writevs; idle + poll: the loop's turns)",
+        lambda: [({"state": state}, n) for state, n in
+                 ledger.report()["entries"].items()])
+    registry.counter_func(
+        "maxmq_loop_cpu_seconds_total",
+        "The event loop thread's CPU seconds as of the newest sampled "
+        "publish; busy seconds minus these is time it held a turn and "
+        "did not run (interpreter lock, blocking calls, the scheduler)",
+        lambda: ledger.report()["cpu_seconds"])
+    registry.counter_func(
+        "maxmq_loop_turns_total",
+        "Turns of the event loop (timed select calls) while "
+        "trace_sample_n > 0",
+        lambda: ledger.report()["turns"])
 
 
 # per-peer link-series cardinality bound, mirroring the ADR-012

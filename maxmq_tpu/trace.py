@@ -58,6 +58,14 @@ Stage model (see docs/adr/015-publish-tracing.md for the contract):
                    callback waits for the loop at that moment (top
                    level, not critical; lands like a drain when the
                    publish finished first)
+``loop_*``         the loop thread's own books (:class:`LoopLedger`): what
+                   the loop spent in each state over the trailing five seconds,
+                   as microseconds a publish admitted (``loop_busy``,
+                   ``loop_idle``, ``loop_offcpu``, ``loop_poll``,
+                   ``loop_other`` and one ``loop_<section>`` a ``maxmq.*``
+                   section, self time). Not an interval of this publish:
+                   a probe beside the path like ``loop_lag``; not critical,
+                   no histogram, not in the Chrome export
 
 ``match_device`` ends where the answer was given and says by whom
 (``via``: cache | host | trie | device | fallback); what the in-order
@@ -84,7 +92,10 @@ phases are copied onto the batch's sampled publishes as CHILD spans of
 
 Host work is also wrapped in ``jax.profiler.TraceAnnotation``
 (``maxmq.*``, :func:`host_span`), so a profiler capture holds it on the
-device trace's clock; ``tools/trace_gaps.py`` reads such a capture.
+device trace's clock; ``tools/trace_gaps.py`` reads such a capture. The
+loop thread's sections open through :meth:`PipelineTracer.section`,
+which feeds the annotation and the :class:`LoopLedger` from one site,
+so a capture and the ledger must agree.
 
 Cross-node model (ADR 017): a node receiving a forwarded publish whose
 envelope carries trace context **adopts** the origin's trace — same
@@ -98,8 +109,9 @@ per-hop-count ``cross_hist`` e2e histograms.
 Cost contract: with ``sample_n == 0`` every instrumented site reduces
 to one attribute check/branch and **zero allocations** (asserted by
 ``tests/test_trace.py`` via the ``allocations`` counter, which counts
-batch records too; no ``TraceAnnotation`` is built and no ``call_soon``
-scheduled) — and with sampling off at the origin no trace context
+batch records too; no ``TraceAnnotation`` is built, no ``call_soon``
+scheduled, the ledger's state never moves and the loop's selector is
+not wrapped) — and with sampling off at the origin no trace context
 crosses the wire, so the propagation path adds zero allocations
 cluster-wide (asserted by ``tests/test_cluster_trace.py``). Sampling is deterministic — a stride
 counter, not a PRNG — and every timestamp is read through the fault
@@ -111,6 +123,7 @@ from __future__ import annotations
 
 import sys
 import threading
+import time
 import types
 from collections import deque
 
@@ -122,6 +135,23 @@ from .metrics import Histogram
 BATCH_PHASES = ("match_host", "match_prep", "match_probe",
                 "match_dispatch", "match_fetch", "match_decode",
                 "device_rtt", "match_hop")
+# the maxmq.* sections the loop thread enters (PipelineTracer.section);
+# the LoopLedger keeps one state each, beside idle, poll and other
+LOOP_SECTIONS = ("read", "deliver", "share", "pass", "flush", "ack",
+                 "batch", "settle")
+LOOP_STATES = ("idle", "poll", "other") + LOOP_SECTIONS
+# what a sampled publish carries of the ledger: microseconds of loop a
+# publish over the trailing LEDGER_SPAN_NS. busy = poll + other + every
+# section; offcpu = busy wall time the loop thread's CPU clock did not
+# see. No histogram, not in the Chrome export.
+LOOP_STAGES = ("loop_busy", "loop_idle", "loop_offcpu") + tuple(
+    "loop_" + s for s in LOOP_STATES[1:])
+# the span: long against a closed loop's generation (its messages in
+# flight over its rate: 0.4 s in the fan-in and fan-out cells), since a
+# sampled publish sits at the head of a burst and a span that ends there
+# holds a whole number of bursts, rounded down
+LEDGER_SPAN_NS = 5_000_000_000
+LEDGER_SNAP_NS = 50_000_000     # least time between two of its snapshots
 # canonical pipeline stages; CRITICAL_STAGES are the contiguous
 # publisher-path segments whose durations sum to ~e2e (drain happens
 # after the publisher's terminal stage, and so may the flush pass that
@@ -129,16 +159,18 @@ BATCH_PHASES = ("match_host", "match_prep", "match_probe",
 # journal_commit/takeover/release are not tied to one
 # publish's critical path; bridge_in is critical
 # only on ADOPTED traces, where it IS the path's first local segment;
-# loop_lag is a probe of the loop beside the path)
+# loop_lag is a probe of the loop beside the path, and so are the
+# ledger's loop_* spans, which are no interval of the publish at all)
 STAGES = ("decode", "admission", "match_queue", "match_device",
           "pipeline_wait", "filter", "fanout", "share_pick", "bridge",
           "bridge_in", "journal_commit", "barrier", "ack", "drain", "flush",
-          "takeover", "release", "aggregate", "loop_lag") + BATCH_PHASES
+          "takeover", "release", "aggregate", "loop_lag") + BATCH_PHASES \
+    + LOOP_STAGES
 CRITICAL_STAGES = frozenset(
     s for s in STAGES
     if s not in ("drain", "flush", "share_pick", "journal_commit",
                  "takeover", "release", "aggregate", "loop_lag")
-    + BATCH_PHASES)
+    + BATCH_PHASES + LOOP_STAGES)
 # 10us .. 1s: a phase of one micro-batch is tens of microseconds to a
 # few milliseconds, under the default ladder's first bound
 BATCH_PHASE_BUCKETS = (
@@ -183,13 +215,13 @@ def host_span(name: str, **stats):
 
 
 @types.coroutine
-def annotated(name: str, coro):
-    """Await ``coro`` under :func:`host_span` ``name``, open only while
+def annotated(tracer: "PipelineTracer", name: str, coro):
+    """Await ``coro`` under ``tracer.section(name)``, open only while
     the coroutine runs: closed at every real suspension and opened again
-    on resume, so the span never covers another callback's work."""
+    on resume, so the section never covers another callback's work."""
     value, exc = None, None
     while True:
-        with host_span(name):
+        with tracer.section(name):
             try:
                 waited = (coro.send(value) if exc is None
                           else coro.throw(exc))
@@ -199,6 +231,250 @@ def annotated(name: str, coro):
             value, exc = (yield waited), None
         except BaseException as thrown:     # cancellation: the coroutine's
             value, exc = None, thrown
+
+
+# -- the loop thread's own books -----------------------------------------
+
+_IDLE, _POLL, _OTHER = (LOOP_STATES.index(s) for s in
+                        ("idle", "poll", "other"))
+_SECTIONS = {name: (LOOP_STATES.index(name), "maxmq." + name)
+             for name in LOOP_SECTIONS}
+
+
+class _Section:
+    """One ``maxmq.*`` section on the loop thread: the ledger's state
+    and the profiler's annotation, moved in the same order at both ends
+    (the ledger's clock read, then the annotation's: a TraceAnnotation
+    starts where it is built) so that the two time the same length."""
+
+    __slots__ = ("ledger", "state", "name", "stats", "span")
+
+    def __init__(self, ledger: "LoopLedger", state: int, name: str,
+                 stats: dict) -> None:
+        self.ledger = ledger
+        self.state = state
+        self.name = name
+        self.stats = stats
+
+    def __enter__(self):
+        self.ledger.enter(self.state)
+        self.span = host_span(self.name, **self.stats)
+        self.span.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.ledger.leave()
+        self.span.__exit__(None, None, None)
+        return False
+
+
+class LoopLedger:
+    """A state timer on the event loop's thread (``tracer.loop``).
+
+    It holds what the thread is doing now (``idle`` in ``select`` with
+    nothing ready, ``poll`` in a ``select`` that had ready handles
+    (booked as busy), ``other``, or the ``maxmq.*`` section on top of a
+    small stack), the stamp of the last change, and one cumulative
+    nanosecond total and one entry count a state. A transition is one
+    read of the tracer's clock: the elapsed time goes to the state that was
+    current, which gives **self time** by construction (``ack`` inside
+    ``read``, ``share`` inside ``deliver``, ``flush`` inside ``pass``),
+    ``sum(sections) + other + poll = busy`` exactly and ``busy + idle =
+    wall``. Sections are fed by :meth:`PipelineTracer.section` on the
+    loop's thread alone; ``idle`` and ``poll`` by the wrapper
+    :meth:`attach` puts around a stock selector loop's ``select``.
+    Without it (uvloop; tracing switched on after ``serve``) ``other``
+    would hold the idle time too, so ``busy``, ``idle``, ``poll``,
+    ``other`` and ``offcpu`` are then absent everywhere, never zero; the
+    sections are kept either way.
+
+    Snapshots of the totals, the thread's CPU clock and the tracer's
+    count of publishes are taken where a turn of the loop ends (in the
+    wrapper, at most one every ``LEDGER_SNAP_NS``), and :meth:`mark`
+    gives every sampled publish one span a state: the newest snapshot
+    against the newest one at least ``LEDGER_SPAN_NS`` older, divided by
+    the publishes admitted in between (:data:`LOOP_STAGES`). Both ends
+    lie between turns, so the span holds whole turns (a turn's reads
+    are admitted together), and it is long against a closed loop's
+    generation (``LEDGER_SPAN_NS`` says why). Without the wrapper there
+    are no turns to cut at, and the snapshot is taken at the sampled
+    publish itself.
+    """
+
+    __slots__ = ("tracer", "ns", "entries", "cpu_ns", "idle_cpu_ns",
+                 "cpu_clock", "tid", "_cur", "_stack", "_last", "_marks",
+                 "_snapped", "_selector", "_select", "_inner")
+
+    def __init__(self, tracer: "PipelineTracer") -> None:
+        self.tracer = tracer
+        self.ns = [0] * len(LOOP_STATES)        # cumulative, a state
+        self.entries = [0] * len(LOOP_STATES)
+        # the loop thread's CPU clock (a test scripts it): what it read
+        # at the newest mark less what it counted inside idle selects,
+        # i.e. the CPU time of the busy states
+        self.cpu_clock = time.thread_time_ns
+        self.cpu_ns = 0
+        self.idle_cpu_ns = 0
+        self.tid = None         # the loop's thread: serve(), else 1st mark
+        self._cur = _OTHER
+        self._stack: list[int] = []
+        self._last = 0          # 0: paused, the next change books nothing
+        self._marks: deque = deque()    # snapshots, the oldest first
+        self._snapped = 0               # the stamp of the newest
+        # the selector whose select is wrapped, the wrapper, what it wraps
+        self._selector = self._select = self._inner = None
+
+    @property
+    def wrapped(self) -> bool:
+        """``select`` is timed: idle and poll are fed, other is busy."""
+        return self._selector is not None
+
+    # -- transitions (the loop's thread) --------------------------------
+
+    def enter(self, state: int) -> None:
+        was = self._cur
+        self._stack.append(was)
+        self._cur = state
+        self.entries[state] += 1
+        # last: the annotation of the same section starts right after
+        # (tracer.clock() inlined: two of these a section)
+        now = (self.tracer._clock or faults.REGISTRY.clock_ns)()
+        if self._last:
+            self.ns[was] += now - self._last
+        self._last = now
+
+    def leave(self) -> None:
+        now = (self.tracer._clock or faults.REGISTRY.clock_ns)()
+        if self._last:
+            self.ns[self._cur] += now - self._last
+        self._last = now
+        self._cur = self._stack.pop() if self._stack else _OTHER
+
+    # -- idle and poll: the selector ------------------------------------
+
+    def attach(self, loop) -> None:
+        """``Broker.serve``, on the loop's thread: remember the thread
+        and, while the tracer samples and ``loop`` is a stock selector
+        loop, time its ``select``: a call with ``timeout == 0`` (ready
+        handles exist: the system call, and the wait for the interpreter
+        on the way back) is ``poll``, any other is ``idle``; each is
+        also a ``maxmq.poll`` / ``maxmq.idle`` annotation."""
+        self.tid = threading.get_ident()
+        selector = getattr(loop, "_selector", None)
+        if (not self.tracer.sample_n or self._selector is not None
+                or not callable(getattr(selector, "select", None))):
+            return
+        inner = selector.select
+        tracer = self.tracer
+
+        def select(timeout=None):
+            if self._selector is None or not tracer.sample_n:
+                self._last = 0      # detached, or sampling switched off
+                return inner(timeout)
+            if timeout == 0:
+                state, name, cpu = _POLL, "maxmq.poll", 0
+            else:
+                # the kernel's own work inside a select that sleeps is
+                # on the thread's CPU clock and in no busy state
+                state, name, cpu = _IDLE, "maxmq.idle", self.cpu_clock()
+            self.enter(state)
+            if self._last - self._snapped >= LEDGER_SNAP_NS:
+                self._snapshot(self._last)      # a turn has just ended
+            span = host_span(name)
+            span.__enter__()
+            try:
+                return inner(timeout)
+            finally:
+                self.leave()
+                span.__exit__(None, None, None)
+                if state == _IDLE:
+                    self.idle_cpu_ns += self.cpu_clock() - cpu
+
+        selector.select = select
+        self._selector, self._select, self._inner = selector, select, inner
+        self._last = 0
+
+    def detach(self) -> None:
+        """``Broker.close``: ``select`` as it was. Another tracer's
+        wrapper put over this one since stays; this one then only passes
+        through."""
+        selector, self._selector = self._selector, None
+        if selector is None or \
+                selector.__dict__.get("select") is not self._select:
+            return
+        if getattr(self._inner, "__self__", None) is selector:
+            del selector.select         # the class's own method again
+        else:
+            selector.select = self._inner
+
+    # -- the sampled publish ---------------------------------------------
+
+    def _snapshot(self, now: int) -> None:
+        """The books as of the last transition, under the stamp ``now``;
+        of the older ones, the newest that is a span old is kept."""
+        self._snapped = now
+        self.cpu_ns = self.cpu_clock() - self.idle_cpu_ns
+        marks = self._marks
+        marks.append((now, self.tracer._count, self.cpu_ns,
+                      tuple(self.ns), tuple(self.entries)))
+        while len(marks) > 1 and now - marks[1][0] >= LEDGER_SPAN_NS:
+            marks.popleft()
+
+    def mark(self, trace: "PublishTrace") -> None:
+        """``tracer.sample`` returned ``trace``: give it the trailing
+        span of every state seen so far. A state that did nothing in the
+        span reads a true 0; before a snapshot is ``LEDGER_SPAN_NS`` old
+        nothing is attached."""
+        if self.tid is None:
+            self.tid = threading.get_ident()
+        if not self.wrapped:
+            # no turns to cut at, and no clock read of its own: the
+            # totals as of the last transition (microseconds ago) under
+            # the publish's own start stamp
+            self._snapshot(trace.start_ns)
+        marks = self._marks
+        if len(marks) < 2:
+            return
+        then, count0, cpu0, ns0, entries0 = marks[0]
+        now, count, cpu, ns, entries = marks[-1]
+        if now - then < LEDGER_SPAN_NS:
+            return
+        n = max(count - count0, 1)
+        spans = trace.loop = [
+            ("loop_" + name, (ns[state] - ns0[state]) / n,
+             (entries[state] - entries0[state]) / n)
+            for name, (state, _span) in _SECTIONS.items()
+            if entries[state]]      # one never entered reports nothing
+        if not self.wrapped:
+            return
+        idle = ns[_IDLE] - ns0[_IDLE]
+        busy = sum(ns) - sum(ns0) - idle
+        turns = (entries[_IDLE] + entries[_POLL]
+                 - entries0[_IDLE] - entries0[_POLL]) / n
+        spans += [
+            ("loop_busy", busy / n, turns),
+            ("loop_idle", idle / n, (entries[_IDLE] - entries0[_IDLE]) / n),
+            ("loop_offcpu", max(busy - (cpu - cpu0), 0) / n, None),
+            ("loop_poll", (ns[_POLL] - ns0[_POLL]) / n,
+             (entries[_POLL] - entries0[_POLL]) / n),
+            ("loop_other", (ns[_OTHER] - ns0[_OTHER]) / n, None)]
+
+    # -- for an operator ---------------------------------------------------
+
+    def report(self) -> dict:
+        """``report()["loop"]``: cumulative seconds and entries a state
+        (``idle``, ``poll`` and ``other`` only while ``select`` is
+        wrapped), the thread's CPU seconds as of the newest sampled
+        publish, and the loop's turns."""
+        states = [s for s in range(len(LOOP_STATES))
+                  if self.wrapped or s > _OTHER]
+        return {"wrapped": self.wrapped,
+                "seconds": {LOOP_STATES[s]: self.ns[s] / 1e9
+                            for s in states},
+                "entries": {LOOP_STATES[s]: self.entries[s]
+                            for s in states if s != _OTHER},
+                "cpu_seconds": self.cpu_ns / 1e9,
+                "turns": self.entries[_IDLE] + self.entries[_POLL]}
 
 
 # -- micro-batch records -------------------------------------------------
@@ -325,7 +601,7 @@ class PublishTrace:
     the object itself and its lists."""
 
     __slots__ = ("id", "topic", "qos", "client", "start_ns", "spans",
-                 "children", "via", "batch",
+                 "children", "via", "batch", "loop",
                  "drains", "degraded", "done", "n_drain", "entry",
                  "t_admit", "t_match", "t_barrier", "origin", "hops")
 
@@ -342,6 +618,9 @@ class PublishTrace:
         self.children: list[tuple[str, int, int, int, bool]] = []
         self.via = ""           # who gave the match_device answer
         self.batch = 0          # id of the micro-batch that did
+        # the LoopLedger's trailing span at this publish's sampling:
+        # (loop_* stage, ns of loop a publish, entries a publish or None)
+        self.loop: list[tuple[str, float, float | None]] | tuple = ()
         self.drains: list[tuple[str, int, int]] = []  # (client, t0, dur)
         self.degraded = ""      # ADR-011 rung label when not healthy
         self.done = False
@@ -388,7 +667,7 @@ class PipelineTracer:
                                         # zero-alloc-when-off witness)
         self.slow_captured = 0
         self.stage_hist: dict[str, Histogram] = {
-            s: Histogram(buckets) for s in STAGES}
+            s: Histogram(buckets) for s in STAGES if s not in LOOP_STAGES}
         self.e2e_hist: dict[int, Histogram] = {
             q: Histogram(buckets) for q in (0, 1, 2)}
         self.stage_errors: dict[tuple[str, str], int] = {}
@@ -402,6 +681,7 @@ class PipelineTracer:
             p: Histogram(BATCH_PHASE_BUCKETS) for p in BATCH_PHASES}
         self._lock = threading.Lock()
         self._buckets = buckets
+        self.loop = LoopLedger(self)    # the loop thread's own books
         # -- cross-node plane (ADR 017) --------------------------------
         self.node_id = ""               # set by the cluster layer
         self.adopted = 0                # remote traces adopted here
@@ -459,8 +739,25 @@ class PipelineTracer:
         self._next_id += 1
         if len(self._open_ids) < 8192:      # rail: a site that never
             self._open_ids.add(self._next_id)   # finishes must not grow
-        return PublishTrace(self._next_id, topic, qos, client,
-                            start_ns or self.clock())
+        trace = PublishTrace(self._next_id, topic, qos, client,
+                             start_ns or self.clock())
+        self.loop.mark(trace)
+        return trace
+
+    def section(self, name: str, **stats):
+        """A context manager around synchronous work ``maxmq.<name>``
+        (one of :data:`LOOP_SECTIONS`) that never spans an ``await``
+        (:func:`annotated` is for coroutines): off, ``NO_SPAN`` after the
+        one attribute check; on, the :func:`host_span` of that name with
+        ``stats`` and, on the loop's thread, the ledger's state."""
+        if not self.sample_n:
+            return NO_SPAN
+        state, span_name = _SECTIONS[name]
+        ledger = self.loop
+        if threading.get_ident() != ledger.tid:
+            # another thread's: the profiler's alone
+            return host_span(span_name, **stats)
+        return _Section(ledger, state, span_name, stats)
 
     def adopt(self, origin: str, trace_id: int, topic: str, qos: int,
               hops: int, start_ns: int) -> PublishTrace:
@@ -664,6 +961,12 @@ class PipelineTracer:
         spans += [_span_dict(start, s, t0, dur, "match_device", batch,
                              "", shadow)
                   for s, t0, dur, batch, shadow in trace.children]
+        for stage, per_publish_ns, calls in trace.loop:
+            span = {"stage": stage, "off_us": 0,
+                    "dur_us": round(per_publish_ns / 1000, 1), "parent": ""}
+            if calls is not None:
+                span["calls"] = round(calls, 3)
+            spans.append(span)
         critical_ns = sum(dur for s, _t0, dur in trace.spans
                           if s in CRITICAL_STAGES)
         entry = {"id": trace.id, "topic": trace.topic, "qos": trace.qos,
@@ -813,7 +1116,7 @@ class PipelineTracer:
                 "e2e_quantiles": self.e2e_quantiles(),
                 "cross_node": self.cross_quantiles(),
                 "entries": entries, "slowest": slowest,
-                "batches": batches}
+                "batches": batches, "loop": self.loop.report()}
 
     def chrome_events(self) -> dict:
         """The ``/traces/chrome`` endpoint body: flight-recorder
@@ -849,6 +1152,8 @@ class PipelineTracer:
                            "dur": int(e["e2e_ms"] * 1000),
                            "pid": 1, "tid": e["id"], "args": args})
             for sp in e["spans"] + e["drains"]:
+                if sp.get("stage") in LOOP_STAGES:
+                    continue        # a rate, not an interval of this publish
                 events.append({
                     "name": sp.get("stage",
                                    f"drain:{sp.get('client', '')}"),
@@ -900,4 +1205,11 @@ class PipelineTracer:
         for qos, row in e2e.items():
             entries[f"$SYS/broker/trace/e2e/{qos}_p99_ms"] = \
                 row["p99_ms"]
+        loop = self.loop.report()
+        for state, seconds in loop["seconds"].items():
+            entries[f"$SYS/broker/trace/loop/{state}_seconds"] = \
+                round(seconds, 6)
+        entries["$SYS/broker/trace/loop/cpu_seconds"] = \
+            round(loop["cpu_seconds"], 6)
+        entries["$SYS/broker/trace/loop/turns"] = loop["turns"]
         return entries

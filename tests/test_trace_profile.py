@@ -41,6 +41,10 @@ def tool():
 async def _traffic(inline, whole) -> dict:
     from test_batcher import _one_batch
 
+    # the loop ledger as ``Broker.serve`` starts it: this thread's
+    # sections, and the selector timed (``maxmq.idle`` / ``maxmq.poll``)
+    ledger = inline.tracer.loop
+    ledger.attach(asyncio.get_running_loop())
     host = inline.engine.subscribers_host_batch
 
     def blocking_host(topics):
@@ -55,8 +59,10 @@ async def _traffic(inline, whole) -> dict:
     finally:
         await inline.close()
         await whole.close()
+        books = ledger.report()
+        ledger.detach()
     assert bypassed.via == "host" and device.via == "whole"
-    return {"bypassed": bypassed, "device": device}
+    return {"bypassed": bypassed, "device": device, "books": books}
 
 
 @pytest.fixture(scope="module")
@@ -159,6 +165,24 @@ def test_trace_gaps_gives_the_inline_block_to_maxmq_batch(capture, tool):
     tool.show(out)                               # prints, does not raise
 
 
+def test_the_ledgers_books_agree_with_the_capture(capture, tool):
+    """One site feeds both: the seconds ``tools/trace_gaps.py`` finds a
+    name in the capture and the loop ledger's total for that state are
+    the same length, and the selector's waits are rows of the tool."""
+    seconds = tool.analyse(capture["data"])["groups"]["loop"]["seconds"]
+    books = capture["books"]
+    assert books["wrapped"]
+    assert books["seconds"]["batch"] >= BLOCK_S
+    assert seconds["maxmq.batch"] == pytest.approx(
+        books["seconds"]["batch"], rel=0.02)
+    assert "maxmq.settle" in seconds and books["entries"]["settle"] == 2
+    # the waits for the executor's batch are idle time in both
+    assert books["entries"]["idle"] >= 1
+    assert seconds["maxmq.idle"] == pytest.approx(
+        books["seconds"]["idle"], rel=0.05, abs=50e-6)
+    assert "maxmq.poll" in seconds
+
+
 def test_interval_arithmetic_on_made_up_events(tool):
     """No profiler: the attribution on events written by hand."""
     assert tool.merge([(5, 9), (0, 2), (1, 3), (9, 9)]) == [(0, 3), (5, 9)]
@@ -197,21 +221,24 @@ def test_interval_arithmetic_on_made_up_events(tool):
 
 def test_a_puback_inside_a_read_gets_a_row_of_its_own(tool):
     """``maxmq.ack`` is opened inside a chunk's ``maxmq.read``: its time
-    is cut out of the span around it, which keeps the rest."""
+    is cut out of the span around it, which keeps the rest; a burst's
+    ``maxmq.flush`` inside it is cut out of the ack in turn (self time
+    at every depth, as the loop ledger's stack gives it)."""
     events = [("maxmq.read", 0, 100), ("maxmq.ack", 10, 20),
               ("maxmq.ack", 30, 45), ("maxmq.flush", 12, 14),
               ("maxmq.deliver", 120, 150), ("maxmq.ack", 200, 210)]
     carved = tool.carve(events)
-    assert carved == [("maxmq.read", 0, 10), ("maxmq.ack", 10, 20),
+    assert carved == [("maxmq.read", 0, 10), ("maxmq.ack", 10, 12),
+                      ("maxmq.flush", 12, 14), ("maxmq.ack", 14, 20),
                       ("maxmq.read", 20, 30), ("maxmq.ack", 30, 45),
                       ("maxmq.read", 45, 100), ("maxmq.deliver", 120, 150),
                       ("maxmq.ack", 200, 210)]
     got = tool.attribute([(0, 300)], tool.spans_by_name([carved]))
-    assert got["names"] == {"maxmq.read": 75e-9, "maxmq.ack": 35e-9,
-                            "maxmq.deliver": 30e-9}
+    assert got["names"] == {"maxmq.read": 75e-9, "maxmq.ack": 33e-9,
+                            "maxmq.flush": 2e-9, "maxmq.deliver": 30e-9}
     assert got["unannotated"] == pytest.approx(160e-9)
-    # without one, a thread's events are its top-level ones, as before
-    plain = [e for e in events if e[0] != "maxmq.ack"]
+    # with nothing carved, a thread's events are its top-level ones
+    plain = [e for e in events if e[0] not in tool.CARVED]
     assert tool.carve(plain) == tool.top_level(plain)
 
 
@@ -225,11 +252,49 @@ def test_the_share_picks_inside_a_deliver_get_a_row_of_their_own(tool):
               ("maxmq.share", 230, 250), ("maxmq.deliver", 400, 420)]
     carved = tool.carve(events)
     assert carved == [("maxmq.deliver", 0, 5), ("maxmq.share", 5, 40),
-                      ("maxmq.deliver", 40, 100), ("maxmq.read", 200, 210),
+                      ("maxmq.deliver", 40, 60), ("maxmq.flush", 60, 90),
+                      ("maxmq.deliver", 90, 100), ("maxmq.read", 200, 210),
                       ("maxmq.ack", 210, 220), ("maxmq.read", 220, 230),
                       ("maxmq.share", 230, 250), ("maxmq.read", 250, 300),
                       ("maxmq.deliver", 400, 420)]
     got = tool.attribute([(0, 500)], tool.spans_by_name([carved]))
-    assert got["names"] == {"maxmq.deliver": 85e-9, "maxmq.share": 55e-9,
-                            "maxmq.read": 70e-9, "maxmq.ack": 10e-9}
+    assert got["names"] == {"maxmq.deliver": 55e-9, "maxmq.share": 55e-9,
+                            "maxmq.flush": 30e-9, "maxmq.read": 70e-9,
+                            "maxmq.ack": 10e-9}
     assert got["unannotated"] == pytest.approx(280e-9)
+
+
+def test_a_pass_keeps_its_own_python_and_the_loops_waits_have_rows(tool):
+    """``maxmq.pass`` is a flush pass whole: its bursts' ``maxmq.flush``
+    are cut out of it, as ``maxmq.ack`` out of ``maxmq.read``, also where
+    the pass itself runs inside another section. The selector's
+    ``maxmq.idle`` and ``maxmq.poll`` are rows like any other, so
+    ``unannotated`` is busy time alone; ``seconds`` is each name whole,
+    not cut to the device's idle time, inside the slice asked for."""
+    events = [("maxmq.idle", 0, 100), ("maxmq.poll", 100, 104),
+              ("maxmq.pass", 110, 200), ("maxmq.flush", 120, 140),
+              ("maxmq.flush", 150, 180), ("maxmq.idle", 220, 300),
+              ("maxmq.read", 310, 400), ("maxmq.pass", 350, 390),
+              ("maxmq.flush", 360, 380)]
+    carved = tool.carve(events)
+    assert carved == [
+        ("maxmq.idle", 0, 100), ("maxmq.poll", 100, 104),
+        ("maxmq.pass", 110, 120), ("maxmq.flush", 120, 140),
+        ("maxmq.pass", 140, 150), ("maxmq.flush", 150, 180),
+        ("maxmq.pass", 180, 200), ("maxmq.idle", 220, 300),
+        ("maxmq.read", 310, 350), ("maxmq.pass", 350, 360),
+        ("maxmq.flush", 360, 380), ("maxmq.pass", 380, 390),
+        ("maxmq.read", 390, 400)]
+    # the device busy 0..50: half of the first wait is not its idle time
+    got = tool.attribute([(50, 400)], tool.spans_by_name([carved]))
+    assert got["names"]["maxmq.idle"] == 130e-9
+    assert got["seconds"] == {
+        "maxmq.idle": 180e-9, "maxmq.poll": 4e-9, "maxmq.pass": 60e-9,
+        "maxmq.flush": 70e-9, "maxmq.read": 50e-9}
+    assert got["unannotated"] == pytest.approx(36e-9)
+    # cut to a slice: what lies outside it is nobody's
+    cut = tool.attribute([(150, 360)],
+                         tool.spans_by_name([carved], 150, 360))
+    assert cut["seconds"] == {
+        "maxmq.idle": 80e-9, "maxmq.poll": 0.0, "maxmq.pass": 30e-9,
+        "maxmq.flush": 30e-9, "maxmq.read": 40e-9}

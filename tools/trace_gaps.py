@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Which host code fills the device's idle time, from one profiler trace.
 
-    python tools/trace_gaps.py <file.xplane.pb> [--json]
+    python tools/trace_gaps.py <file.xplane.pb> [--json] [--between LO_NS HI_NS]
 
 While ``trace_sample_n > 0`` the broker wraps its synchronous host work
 in ``jax.profiler.TraceAnnotation("maxmq.<name>")`` (maxmq_tpu/trace.py,
@@ -16,9 +16,12 @@ a file and prints:
 * for the event loop's thread, and for the other threads together, the
   seconds of that idle time each top-level ``maxmq.*`` name covers, its
   share of the idle time, and what no annotation covers (``maxmq.ack``,
-  a subscriber's PUBACK handled inside a chunk's ``maxmq.read``, and
-  ``maxmq.share``, the $share picks inside a ``maxmq.deliver``, are cut
-  out of the span around them and given rows of their own);
+  a subscriber's PUBACK handled inside a chunk's ``maxmq.read``,
+  ``maxmq.share``, the $share picks inside a ``maxmq.deliver``,
+  ``maxmq.flush``, a burst's ``writev`` inside a flush pass's
+  ``maxmq.pass``, and a pass that runs inside another section are cut
+  out of the span around them and given rows of their own: self time,
+  as the tracer's loop ledger keeps it);
 * the ten longest idle gaps, each with the name that covers most of it;
 * two checks of the clocks: how many device operations began inside an
   annotated dispatch -> fetch of one batch, and the tracer's clock minus
@@ -27,9 +30,18 @@ a file and prints:
 
 Every line of the host plane is named ``python``: the loop's thread is
 told by the events it carries (``maxmq.read``, ``maxmq.deliver``).
-Waiting is not annotated (an annotation never spans an ``await``), so
-``unannotated`` on the loop's thread is the loop idle, or in code no
-annotation names yet.
+Where the broker timed its selector (a stock asyncio loop, sampling on
+at ``serve``) the loop's waits are ``maxmq.idle`` (nothing was ready)
+and ``maxmq.poll`` (a ``select`` with ready handles: busy), and
+``unannotated`` on the loop's thread is busy time in code no section
+names: the ledger's ``other``. Without them it holds the idle time too.
+
+``--json`` also gives each name's whole seconds inside the slice
+(``seconds``, not cut to the device's idle time), which is what the
+ledger's totals (``report()["loop"]``, ``maxmq_loop_seconds_total``)
+are set beside; ``--between`` cuts the slice to two stamps of the
+tracer's clock, carried over by the offset above, so that the two are
+taken over the same interval.
 """
 
 from __future__ import annotations
@@ -49,9 +61,9 @@ import xplane  # noqa: E402
 HOST_PLANE = "/host:CPU"
 PREFIX = "maxmq."
 LOOP_MARKS = ("maxmq.read", "maxmq.deliver", "maxmq.settle")
-# nested annotations that get a row of their own: their time is taken
-# from the span around them
-CARVED = ("maxmq.ack", "maxmq.share")
+# annotations that get a row of their own wherever they nest: their time
+# is taken from the span around them
+CARVED = ("maxmq.ack", "maxmq.share", "maxmq.flush", "maxmq.pass")
 
 
 # -- interval arithmetic (nanoseconds; an interval is (start, end)) --------
@@ -109,33 +121,40 @@ def top_level(events) -> list[tuple[str, int, int]]:
 
 
 def carve(events) -> list[tuple[str, int, int]]:
-    """One thread's top-level events, with every ``CARVED`` event that
-    lies inside one cut out of it and listed beside it."""
-    inner = sorted((e for e in events if e[0] in CARVED),
-                   key=lambda e: e[1])
-    tops = top_level(e for e in events if e[0] not in CARVED)
-    if not inner:
-        return tops
-    out, i = [], 0          # both lists are in start order: one walk
-    for name, lo, hi in tops:
-        while i < len(inner) and inner[i][2] <= lo:
-            i += 1
-        j = i
-        while j < len(inner) and inner[j][1] < hi:
-            j += 1
-        out += [(name, a, b) for a, b in complement(
-            [(c0, c1) for _n, c0, c1 in inner[i:j]], lo, hi)]
-        i = j
-    return sorted(out + inner, key=lambda e: e[1])
+    """One thread's self time: its top-level events and every ``CARVED``
+    event at any depth, each cut where one of the others lies inside
+    it. Anything else that lies inside another event is its parent's."""
+    tops = set(top_level(events))
+    stack: list[tuple[str, int]] = []       # (name, end) of the open ones
+    out, cursor = [], 0
+
+    def close(until: int) -> None:
+        nonlocal cursor
+        while stack and stack[-1][1] <= until:
+            name, end = stack.pop()
+            out.append((name, cursor, end))
+            cursor = end
+
+    for ev in sorted(events, key=lambda e: (e[1], -e[2])):
+        name, lo, hi = ev
+        if name not in CARVED and ev not in tops:
+            continue
+        close(lo)
+        if stack:
+            out.append((stack[-1][0], cursor, lo))
+        stack.append((name, hi))
+        cursor = lo
+    close(max((e[2] for e in events), default=0))
+    return [(name, lo, hi) for name, lo, hi in out if hi > lo]
 
 
-def spans_by_name(threads) -> dict:
-    """``threads``: one list of top-level (name, start, end) a thread.
-    Each name's intervals over all of them, merged."""
+def spans_by_name(threads, lo: int = 0, hi: int = 1 << 63) -> dict:
+    """``threads``: one list of self-time (name, start, end) a thread.
+    Each name's intervals over all of them inside [lo, hi), merged."""
     out: dict = {}
     for events in threads:
-        for name, lo, hi in events:
-            out.setdefault(name, []).append((lo, hi))
+        for name, a, b in events:
+            out.setdefault(name, []).append((max(a, lo), min(b, hi)))
     return {name: merge(spans) for name, spans in out.items()}
 
 
@@ -147,7 +166,9 @@ def attribute(idle, names) -> dict:
     total = sum(hi - lo for lo, hi in idle)
     return {"names": {name: overlap(idle, spans) / 1e9
                       for name, spans in names.items()},
-            "unannotated": (total - overlap(idle, covered)) / 1e9}
+            "unannotated": (total - overlap(idle, covered)) / 1e9,
+            "seconds": {name: sum(hi - lo for lo, hi in spans) / 1e9
+                        for name, spans in names.items()}}
 
 
 def covering(gap, groups) -> str:
@@ -205,7 +226,9 @@ def round_trips(threads) -> list[tuple[int, int]]:
     return merge(out)
 
 
-def analyse(data) -> dict:
+def analyse(data, between=None) -> dict:
+    """``between``: two stamps of the tracer's clock (ns) to cut the
+    slice to."""
     threads = host_threads(data)
     planes = xplane.device_planes(data)
     ops = [(s, s + d) for _n, s, d in xplane.op_events(planes[0])] \
@@ -216,16 +239,23 @@ def analyse(data) -> dict:
         raise SystemExit("no maxmq.* host span and no device operation "
                          "in this trace: was tracing on (trace_sample_n)?")
     lo, hi = min(edges), max(edges)
+    offsets = [stats["t0_ns"] - start for events in threads
+               for _n, start, _e, stats in events if "t0_ns" in stats]
+    offset = int(arith.median(offsets)) if offsets else None
+    if between is not None:
+        if offset is None:
+            raise SystemExit("no annotation here carries the tracer's "
+                             "clock (t0_ns): --between has nothing to go by")
+        lo, hi = max(lo, between[0] - offset), min(hi, between[1] - offset)
     idle = complement(ops, lo, hi)
     idle_s = sum(b - a for a, b in idle) / 1e9
     k = loop_thread(threads)
     tops = [carve([e[:3] for e in events]) for events in threads]
-    groups = {"loop": spans_by_name([tops[k]] if k is not None else []),
-              "other": spans_by_name(t for i, t in enumerate(tops)
-                                     if i != k)}
+    groups = {"loop": spans_by_name([tops[k]] if k is not None else [],
+                                    lo, hi),
+              "other": spans_by_name((t for i, t in enumerate(tops)
+                                      if i != k), lo, hi)}
     trips = round_trips(threads)
-    offsets = [stats["t0_ns"] - start for events in threads
-               for _n, start, _e, stats in events if "t0_ns" in stats]
     gaps = sorted(idle, key=lambda g: g[0] - g[1])[:10]
     return {
         "slice_s": (hi - lo) / 1e9, "device_planes": len(planes),
@@ -238,8 +268,7 @@ def analyse(data) -> dict:
         "device_ops": len(ops),
         "device_ops_inside_a_round_trip": sum(
             1 for a, _b in ops if overlap([(a, a + 1)], trips)),
-        "tracer_minus_profiler_clock_ns":
-            int(arith.median(offsets)) if offsets else None,
+        "tracer_minus_profiler_clock_ns": offset,
     }
 
 
@@ -273,8 +302,11 @@ def main() -> int:
     ap.add_argument("trace", help="a .xplane.pb file")
     ap.add_argument("--json", action="store_true",
                     help="print the numbers as one JSON object")
+    ap.add_argument("--between", nargs=2, type=int,
+                    metavar=("LO_NS", "HI_NS"),
+                    help="cut the slice to two stamps of the tracer's clock")
     args = ap.parse_args()
-    out = analyse(xplane.load(args.trace))
+    out = analyse(xplane.load(args.trace), args.between)
     if args.json:
         print(json.dumps(out))
     else:
