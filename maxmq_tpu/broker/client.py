@@ -944,6 +944,11 @@ class ClientRegistry:
 
     def __init__(self) -> None:
         self._clients: dict[str, Client] = {}
+        # how many members of a $share key's map have a session, for
+        # this version of the registry: (group, filter) -> (members,
+        # len(members), hits), written by ``resolve`` and emptied by
+        # ``add`` and ``delete``, the only writers of ``_clients``
+        self._share_hits: dict = {}
 
     def get(self, client_id: str) -> Client | None:
         return self._clients.get(client_id)
@@ -951,14 +956,16 @@ class ClientRegistry:
     def resolve(self, result) -> tuple[list, dict, int, int]:
         """A match result against the sessions that exist, read now:
         ``result.resolve`` (trie.SubscriberSet.resolve) on this
-        registry's dict."""
-        return result.resolve(self._clients)
+        registry's dict, with what it kept of the $share counts."""
+        return result.resolve(self._clients, self._share_hits)
 
     def add(self, client: Client) -> None:
         self._clients[client.id] = client
+        self._share_hits.clear()
 
     def delete(self, client_id: str) -> None:
         self._clients.pop(client_id, None)
+        self._share_hits.clear()
 
     def __len__(self) -> int:
         return len(self._clients)

@@ -105,8 +105,10 @@ class OverloadState:
         self.fanout_acks = 0
         # $share picks (one a (group, filter) key a publish chose a
         # member for), the candidates in the sets picked from (over
-        # picks: the mean width of a group; the pick sorts and the
-        # resolve counts that many ids a publish) and the widest set
+        # picks: the mean width of a group; a map not seen before costs
+        # the pick a sort and the resolve a count of that many ids, and
+        # TopicIndex.share_orders_{reused,sorted} say how often) and
+        # the widest set
         self.share_picks = 0
         self.share_candidates = 0
         self.share_widest = 0

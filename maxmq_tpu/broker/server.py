@@ -1561,6 +1561,10 @@ class Broker:
                           crc32(packet.topic.encode()))
         get = self.clients.get
         overload = self.overload
+
+        def alive(cid: str) -> bool:
+            return (c := get(cid)) is not None and not c.closed
+
         for (group, filt), candidates in shared.items():
             if sessions is not None and not sessions.owns_share(
                     group, filt, token):
@@ -1569,10 +1573,8 @@ class Broker:
                 # forward copy delivers there, so the group receives
                 # the publish exactly once cluster-wide
                 continue
-            pick = self.topics.select_shared(
-                group, filt, candidates,
-                alive=lambda cid: (c := get(cid)) is not None
-                and not c.closed)
+            pick = self.topics.select_shared(group, filt, candidates,
+                                             alive)
             if pick is not None:
                 width = len(candidates)
                 overload.share_picks += 1

@@ -163,7 +163,8 @@ class SubscriberSet:
     def __len__(self) -> int:
         return len(self.subscriptions) + sum(len(g) for g in self.shared.values())
 
-    def resolve(self, registry: dict) -> tuple[list, dict, int, int]:
+    def resolve(self, registry: dict,
+                kept: dict | None = None) -> tuple[list, dict, int, int]:
         """This result against the client registry's dict, in one pass
         (ADR 007): ``(pairs, shared, matched, resolved)``.
 
@@ -175,7 +176,18 @@ class SubscriberSet:
         whole, because the round-robin cursor indexes the sorted full
         candidate set. ``matched`` counts plain entries + shared
         candidates held, ``resolved`` those with a session. Nothing is
-        written onto the result: results are cached and shared."""
+        written onto the result: results are cached and shared.
+
+        ``kept`` is the registry's memory of these counts, ``(group,
+        filter) -> (members, len(members), hits)``, emptied by its owner
+        whenever a session comes or goes (``ClientRegistry``): a key
+        whose entry holds this very map at this length is not walked, so
+        a group of 500 costs a publish what a group of 4 does. The
+        entry's reference keeps the map's address from being reused,
+        and nothing shrinks or re-keys a result's member map in place
+        (``add_shared`` can only grow one), so the same object at the
+        same length holds the same ids. Without ``kept`` (a bare dict,
+        whose changes nobody reports) every member is counted."""
         get = registry.get
         pairs = [(client, sub) for cid, sub in self.subscriptions.items()
                  if (client := get(cid)) is not None]
@@ -183,8 +195,15 @@ class SubscriberSet:
         matched = len(self.subscriptions)
         resolved = len(pairs)
         for key, members in self.shared.items():
-            matched += len(members)
-            hits = sum(map(registry.__contains__, members))
+            n = len(members)
+            matched += n
+            entry = None if kept is None else kept.get(key)
+            if entry is not None and entry[0] is members and entry[1] == n:
+                hits = entry[2]
+            else:
+                hits = sum(map(registry.__contains__, members))
+                if kept is not None:
+                    kept[key] = (members, n, hits)
             if hits:
                 resolved += hits
                 shared[key] = members
@@ -228,6 +247,13 @@ class TopicIndex:
         self._root = _Node()
         self._lock = threading.RLock()
         self._share_cursor: dict[tuple[str, str], int] = {}
+        # (group, filter) -> (the candidate map select_shared last
+        # sorted, its ids in order): lives and dies with the cursor
+        self._share_order: dict[tuple[str, str], tuple[dict, list]] = {}
+        # select_shared calls served from a kept order, and those that
+        # sorted (maxmq_broker_share_orders_{reused,sorted}_total)
+        self.share_orders_reused = 0
+        self.share_orders_sorted = 0
         self.subscription_count = 0
         self.retained_count = 0
         # bumped on every mutation, retained messages included
@@ -292,6 +318,7 @@ class TopicIndex:
                 if not holders:
                     del node.shared[group]
                     self._share_cursor.pop((group, sub_filter), None)
+                    self._share_order.pop((group, sub_filter), None)
             else:
                 if client_id not in node.subscriptions:
                     return False
@@ -401,15 +428,34 @@ class TopicIndex:
 
         The reference picks effectively-arbitrarily (map iteration order,
         topics.go:255-270); round-robin gives fairer load spreading.
+
+        The order is ``sorted(candidates)`` for every input, sorted
+        once a map and not once a message: the key keeps the map it last
+        sorted beside its ids in order, and a pick asked about that very
+        map at that length is the cursor, the next index and ``alive``.
+        The native decode hands out one immutable map a table row; a
+        map not seen before (the trie's ``_collect`` and a hook's
+        ``select_copy`` build a fresh dict a result) is sorted as it
+        always was and becomes the kept one. Why identity and length
+        decide: ``SubscriberSet.resolve``, ADR 007.
         """
         if not candidates:
             return None
-        ordered = sorted(candidates)
         key = (group, filter_)
         with self._lock:
-            cur = self._share_cursor.get(key, -1)
-            for i in range(1, len(ordered) + 1):
-                idx = (cur + i) % len(ordered)
+            kept = self._share_order.get(key)
+            if (kept is not None and kept[0] is candidates
+                    and len(kept[1]) == len(candidates)):
+                ordered = kept[1]
+                self.share_orders_reused += 1
+            else:
+                ordered = sorted(candidates)
+                self._share_order[key] = (candidates, ordered)
+                self.share_orders_sorted += 1
+            n = len(ordered)
+            idx = self._share_cursor.get(key, -1)
+            for _ in range(n):
+                idx = (idx + 1) % n
                 cid = ordered[idx]
                 if alive is None or alive(cid):
                     self._share_cursor[key] = idx

@@ -1003,7 +1003,8 @@ def _register_fanout_metrics(registry: Registry, broker) -> None:
             ("share_candidates",
              "Candidates in the $share sets picked from (over "
              "share_picks_total: the mean width of a group; a pick "
-             "sorts that many ids and the resolve counts them)"),
+             "from a map not seen before sorts that many ids and the "
+             "resolve counts them)"),
             ("read_chunks",
              "Socket reads that returned bytes (over "
              "maxmq_mqtt_packets_received: packets a chunk, what one "
@@ -1014,6 +1015,18 @@ def _register_fanout_metrics(registry: Registry, broker) -> None:
         "maxmq_broker_share_widest",
         "Most candidates a $share set picked from held since start",
         lambda: over.share_widest)
+    for name, help_ in (
+            ("share_orders_reused",
+             "$share picks served from the sorted order their (group, "
+             "filter) key kept of the candidate map it was last asked "
+             "about: an index and a liveness check (reused / (reused + "
+             "sorted) = the share of picks that sorted nothing)"),
+            ("share_orders_sorted",
+             "$share picks that sorted their candidate ids: a key's "
+             "first pick, a map not seen before (a table rotation, a "
+             "trie walk's or a hook's fresh dict)")):
+        registry.counter_func(f"maxmq_broker_{name}_total", help_,
+                              lambda n=name: getattr(broker.topics, n))
     for name, help_ in (
             ("records_spliced",
              "Inflight records of QoS>0 deliveries the storage hook "

@@ -294,11 +294,15 @@ class ChainedIntents:
             self._shared = merged
         return self._shared
 
-    def resolve(self, registry: dict) -> tuple[list, dict, int, int]:
+    def resolve(self, registry: dict,
+                kept: dict | None = None) -> tuple[list, dict, int, int]:
         """``SubscriberSet.resolve`` over the chained parts: each shard
         resolves its own entries; a $share key that survives on any
         shard keeps the MERGED member map (the rotation indexes the
-        group's full candidate set, which may span shards)."""
+        group's full candidate set, which may span shards). ``kept``
+        (the registry's $share counts, one map a key) is not used: a
+        group that spans shards has a map a shard, and the merged map
+        is this result's own, so a chained result counts as before."""
         pairs: list = []
         keys: set = set()
         matched = resolved = 0

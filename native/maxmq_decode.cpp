@@ -327,26 +327,36 @@ PyObject *subset_repr(PyObject *self_o) {
 // itself against the registry's dict in one pass (the contract is
 // SubscriberSet.resolve's, matching/trie.py; ADR 007):
 //
-//   resolve(registry) -> (pairs, shared, matched, resolved)
+//   resolve(registry[, kept]) -> (pairs, shared, matched, resolved)
 //
 // pairs are the (client, sub) of the plain entries whose id is a key of
 // the registry, in iteration order; shared is the $share map cut to the
 // keys with a registered candidate, member maps aliased whole. Nothing
-// is written onto the result: it is cached and shared.
+// is written onto the result: it is cached and shared. kept is the
+// registry's memory of its $share counts, (group, filter) -> (members,
+// len(members), hits), emptied by its owner when a session comes or
+// goes: a key whose entry holds this very map at this length is not
+// walked (a row hands out one immutable map, publish after publish).
 
 struct Resolve {
   PyObject *reg;      // borrowed: the registry dict
+  PyObject *kept;     // borrowed: the $share counts dict, or nullptr
   PyObject *pairs;    // owned until resolve_finish
   Py_ssize_t matched;
   Py_ssize_t resolved;
 };
 
-bool resolve_begin(Resolve *r, PyObject *reg) {
-  if (!PyDict_Check(reg)) {
-    PyErr_SetString(PyExc_TypeError, "resolve() takes the registry dict");
+// args are resolve()'s own: the registry dict, then kept or None
+bool resolve_begin(Resolve *r, PyObject *const *args, Py_ssize_t nargs) {
+  PyObject *kept = nargs == 2 && args[1] != Py_None ? args[1] : nullptr;
+  if (nargs < 1 || nargs > 2 || !PyDict_Check(args[0]) ||
+      (kept && !PyDict_Check(kept))) {
+    PyErr_SetString(PyExc_TypeError,
+                    "resolve() takes the registry dict and, optionally, "
+                    "the dict of its kept $share counts");
     return false;
   }
-  *r = {reg, PyList_New(0), 0, 0};
+  *r = {args[0], kept, PyList_New(0), 0, 0};
   return r->pairs != nullptr;
 }
 
@@ -361,21 +371,50 @@ static inline int resolve_entry(Resolve *r, PyObject *cid, PyObject *sub) {
   return rc;
 }
 
+// how many ids of one $share member map are keys of the registry: the
+// kept count of this very map at this length, else a walk (which then
+// is the kept one); -1 with an exception set
+static Py_ssize_t resolve_hits(Resolve *r, PyObject *key, PyObject *members) {
+  const Py_ssize_t n = PyDict_GET_SIZE(members);
+  if (r->kept) {
+    PyObject *e = PyDict_GetItemWithError(r->kept, key);  // borrowed
+    if (!e && PyErr_Occurred()) return -1;
+    if (e && PyTuple_CheckExact(e) && PyTuple_GET_SIZE(e) == 3 &&
+        PyTuple_GET_ITEM(e, 0) == members) {
+      const Py_ssize_t len = PyLong_AsSsize_t(PyTuple_GET_ITEM(e, 1));
+      const Py_ssize_t hits = PyLong_AsSsize_t(PyTuple_GET_ITEM(e, 2));
+      if ((len == -1 || hits == -1) && PyErr_Occurred()) return -1;
+      if (len == n && hits >= 0) return hits;
+    }
+  }
+  PyObject *cid, *sub;
+  Py_ssize_t pos = 0, hits = 0;
+  while (PyDict_Next(members, &pos, &cid, &sub)) {
+    if (PyDict_GetItemWithError(r->reg, cid))
+      hits++;
+    else if (PyErr_Occurred())
+      return -1;
+  }
+  if (r->kept) {
+    PyObject *e = Py_BuildValue("(Onn)", members, n, hits);
+    const int rc = e ? PyDict_SetItem(r->kept, key, e) : -1;
+    Py_XDECREF(e);
+    if (rc < 0) return -1;
+  }
+  return hits;
+}
+
 // the $share half: NEW reference to the cut map (always a dict)
 PyObject *resolve_shared(Resolve *r, PyObject *shared) {
   PyObject *cut = PyDict_New();
   if (!cut || !shared) return cut;
-  PyObject *key, *members, *cid, *sub;
+  PyObject *key, *members;
   Py_ssize_t pos = 0;
   while (PyDict_Next(shared, &pos, &key, &members)) {
-    Py_ssize_t mpos = 0, hits = 0;
-    while (PyDict_Next(members, &mpos, &cid, &sub)) {
-      if (PyDict_GetItemWithError(r->reg, cid))
-        hits++;
-      else if (PyErr_Occurred()) {
-        Py_DECREF(cut);
-        return nullptr;
-      }
+    const Py_ssize_t hits = resolve_hits(r, key, members);
+    if (hits < 0) {
+      Py_DECREF(cut);
+      return nullptr;
     }
     r->matched += PyDict_GET_SIZE(members);
     r->resolved += hits;
@@ -400,10 +439,11 @@ PyObject *resolve_finish(Resolve *r, int rc, Py_ssize_t plain,
                        r->resolved + PyList_GET_SIZE(r->pairs));
 }
 
-PyObject *subset_resolve(PyObject *self_o, PyObject *reg) {
+PyObject *subset_resolve(PyObject *self_o, PyObject *const *args,
+                         Py_ssize_t nargs) {
   auto *self = reinterpret_cast<SubSetObject *>(self_o);
   Resolve r;
-  if (!resolve_begin(&r, reg)) return nullptr;
+  if (!resolve_begin(&r, args, nargs)) return nullptr;
   PyObject *cid, *sub;
   Py_ssize_t pos = 0;
   int rc = 0;
@@ -429,8 +469,9 @@ PyMethodDef subset_methods[] = {
      "Subscription-deep copy for hooks that may mutate."},
     {"select_copy", subset_select_copy, METH_NOARGS,
      "Fresh outer dicts over aliased records (hook modify-chain form)."},
-    {"resolve", subset_resolve, METH_O,
-     "(pairs, shared, matched, resolved) against the registry dict."},
+    {"resolve", reinterpret_cast<PyCFunction>(subset_resolve), METH_FASTCALL,
+     "(pairs, shared, matched, resolved) against the registry dict; its "
+     "kept $share counts, if given, spare the walk of a known map."},
     {nullptr, nullptr, 0, nullptr}};
 
 PyType_Slot subset_slots[] = {
@@ -772,10 +813,11 @@ static inline int resolve_run(Resolve *r, PyObject *const *cids,
 // resolve(registry): the walk of intents_iternext (own tail, then the
 // bases with their slot overrides) testing membership only — no tuple
 // and no frame for an entry without a session
-PyObject *intents_resolve(PyObject *self_o, PyObject *reg) {
+PyObject *intents_resolve(PyObject *self_o, PyObject *const *args,
+                          Py_ssize_t nargs) {
   auto *self = reinterpret_cast<IntentsObject *>(self_o);
   Resolve r;
-  if (!resolve_begin(&r, reg)) return nullptr;
+  if (!resolve_begin(&r, args, nargs)) return nullptr;
   int rc = resolve_run(&r, self->cids, self->n,
                        [&](Py_ssize_t i) { return self->subs[i]; });
   Py_ssize_t oi = 0;  // cursor into ovr_slots: global slots ascend
@@ -883,8 +925,9 @@ PyMethodDef intents_methods[] = {
      "Materialize (and cache) the SubscriberSet twin for hook paths."},
     {"select_set", intents_select_set, METH_NOARGS,
      "Fresh hook-ready SubscriberSet (new dicts, aliased records)."},
-    {"resolve", intents_resolve, METH_O,
-     "(pairs, shared, matched, resolved) against the registry dict."},
+    {"resolve", reinterpret_cast<PyCFunction>(intents_resolve), METH_FASTCALL,
+     "(pairs, shared, matched, resolved) against the registry dict; its "
+     "kept $share counts, if given, spare the walk of a known map."},
     {nullptr, nullptr, 0, nullptr}};
 
 PyGetSetDef intents_getset[] = {

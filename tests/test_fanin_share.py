@@ -177,9 +177,10 @@ def check_against_reference(s: Served, sent: list, got: dict,
 @pytest.mark.parametrize("matcher", MATCHERS)
 async def test_each_message_reaches_one_member_round_robin(tmp_path, matcher):
     async with served(tmp_path, matcher) as s:
-        over, info = s.broker.overload, s.broker.info
+        over, info, index = s.broker.overload, s.broker.info, s.broker.topics
         before = (over.share_picks, over.share_candidates, over.read_chunks,
-                  info.packets_received)
+                  info.packets_received,
+                  index.share_orders_reused + index.share_orders_sorted)
         sent = await s.publish_all(MESSAGES)
         n = PUBLISHERS * MESSAGES
         assert len(sent) == n
@@ -192,6 +193,9 @@ async def test_each_message_reaches_one_member_round_robin(tmp_path, matcher):
         assert over.share_picks - before[0] == n
         assert over.share_candidates - before[1] == n * MEMBERS
         assert over.share_widest == MEMBERS
+        # a pick reused the order its key kept, or sorted one to keep
+        assert (index.share_orders_reused + index.share_orders_sorted
+                - before[4]) == n
         packets = info.packets_received - before[3]
         assert packets == 2 * n
         assert n < over.read_chunks - before[2] <= packets
@@ -210,6 +214,18 @@ async def test_each_message_reaches_one_member_round_robin(tmp_path, matcher):
                 ((key, members),) = shared.items()
                 assert key == (GROUP, f"$share/{GROUP}/fleet/telemetry/#")
                 assert sorted(members) == sorted(s.members)
+                # the native rows hand out one map a row: sorted once a
+                # key (at most: a served batch may have seen it first),
+                # and every pick after it reuses the kept order
+                reused, ordered = (index.share_orders_reused,
+                                   index.share_orders_sorted)
+                picks = [index.select_shared(*key, ask([s.hits[k]])[0]
+                                             .shared[key])[0]
+                         for k in range(6)]
+                assert index.share_orders_sorted - ordered <= 1
+                assert index.share_orders_reused - reused >= 5
+                at = sorted(members).index(picks[0])
+                assert picks == (sorted(members) * 2)[at:at + 6]
 
 
 @pytest.mark.parametrize("matcher", MATCHERS)
@@ -281,9 +297,51 @@ async def test_sampling_off_the_picks_allocate_nothing(tmp_path, matcher):
         got = await s.collect(len(sent))
         check_against_reference(s, sent, got, s.members)
         assert s.broker.overload.share_picks == len(sent)
+        index = s.broker.topics
+        assert (index.share_orders_reused + index.share_orders_sorted
+                == len(sent))
         assert tracer.allocations == 0 and tracer.sampled == 0
         assert tracer.stage_hist["share_pick"].count == 0
         assert tracer.report()["entries"] == []
+
+
+def test_share_order_counters_move_a_pick_and_once_a_map():
+    """Reused rises a pick, sorted once a map a key: through the
+    broker's own resolve and picks, on one result asked about again and
+    again (what a native row's is) and then on a fresh walk's."""
+    from types import SimpleNamespace
+
+    from maxmq_tpu.broker import Broker, BrokerOptions
+    from maxmq_tpu.protocol import Subscription
+    from maxmq_tpu.protocol.codec import FixedHeader, PacketType
+    from maxmq_tpu.protocol.packets import Packet
+    broker = Broker(BrokerOptions())
+    index, over = broker.topics, broker.overload
+    filt = f"$share/{GROUP}/fleet/telemetry/#"
+    ids = [f"ingest-{i}" for i in range(MEMBERS)]
+    for cid in ids:
+        index.subscribe(cid, Subscription(filter=filt, qos=1))
+        broker.clients.add(SimpleNamespace(id=cid, closed=False))
+    packet = Packet(fixed=FixedHeader(type=PacketType.PUBLISH),
+                    topic="fleet/telemetry/dev-1", payload=b"x")
+    result = index.subscribers(packet.topic)
+    picked = []
+    for k in range(1, 11):
+        _pairs, shared, matched, resolved = broker.clients.resolve(result)
+        assert (matched, resolved) == (MEMBERS, MEMBERS)
+        picked += broker._pick_shared(shared, packet)
+        assert (index.share_orders_sorted, index.share_orders_reused,
+                over.share_picks) == (1, k - 1, k)
+    assert picked == sorted(ids)[:10]
+    fresh = index.subscribers(packet.topic)     # an equal map, another dict
+    _pairs, shared, _m, _r = broker.clients.resolve(fresh)
+    assert list(broker._pick_shared(shared, packet)) == [sorted(ids)[10]]
+    assert (index.share_orders_sorted, index.share_orders_reused) == (2, 9)
+    # a session ends: the count is taken again, the order is not
+    broker.clients.delete(ids[0])
+    assert broker.clients.resolve(fresh)[2:] == (MEMBERS, MEMBERS - 1)
+    assert list(broker._pick_shared(shared, packet)) == [sorted(ids)[11]]
+    assert (index.share_orders_sorted, index.share_orders_reused) == (2, 10)
 
 
 def test_share_and_chunk_counters_exported():
@@ -293,6 +351,8 @@ def test_share_and_chunk_counters_exported():
     over = broker.overload
     over.share_picks, over.share_candidates = 70_000, 35_000_000
     over.share_widest, over.read_chunks = 500, 140_000
+    broker.topics.share_orders_reused = 69_999
+    broker.topics.share_orders_sorted = 1
     reg = Registry()
     register_broker_metrics(reg, broker)
     text = reg.expose()
@@ -300,6 +360,8 @@ def test_share_and_chunk_counters_exported():
     assert "maxmq_broker_share_candidates_total 35000000" in text
     assert "maxmq_broker_share_widest 500" in text
     assert "maxmq_broker_read_chunks_total 140000" in text
+    assert "maxmq_broker_share_orders_reused_total 69999" in text
+    assert "maxmq_broker_share_orders_sorted_total 1" in text
 
 
 for _case in (test_each_message_reaches_one_member_round_robin,
