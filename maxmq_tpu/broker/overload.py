@@ -103,6 +103,10 @@ class OverloadState:
         # delivery on the read path)
         self.fanout_widest = 0
         self.fanout_acks = 0
+        # most matched entries folded into one receiver since start: a
+        # session matched through that many of its own filters got one
+        # delivery, at the highest QoS among them (Subscription.folded)
+        self.fanout_overlap_widest = 0
         # $share picks (one a (group, filter) key a publish chose a
         # member for), the candidates in the sets picked from (over
         # picks: the mean width of a group; a map not seen before costs

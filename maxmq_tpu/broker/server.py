@@ -22,7 +22,7 @@ from ..filtering.expr import ExprError, decode_payload
 from ..filtering.plane import (ContentPlane, ContentQuota,
                                USER_PROP_KEY as FILTER_PROP_KEY)
 from ..hooks.base import Hook, Hooks, RejectPacket
-from ..trace import MAX_DRAIN_SPANS, PipelineTracer
+from ..trace import MAX_DRAIN_SPANS, NO_SPAN, PipelineTracer, host_span
 from ..matching.topics import valid_filter, valid_topic_name
 from ..matching.trie import (SubscriberSet, TopicIndex,
                              VersionedTopicCache)
@@ -1494,7 +1494,12 @@ class Broker:
                 for h in self.hooks._overriders("on_select_subscribers"))
             if not (shared_only and len(subscribers) == subscribers.n):
                 subscribers = self._select_subscribers(subscribers, packet)
-        pairs, shared, matched, resolved = self.clients.resolve(subscribers)
+        tracer = self.tracer
+        if tracer.sample_n or tracer.adopted_open:
+            found = self._resolve_traced(subscribers, packet)
+        else:
+            found = self.clients.resolve(subscribers)
+        pairs, shared, matched, resolved = found
         overload = self.overload
         overload.fanout_matched += matched
         overload.fanout_resolved += resolved
@@ -1505,8 +1510,28 @@ class Broker:
         if pairs:
             fan = _FanOut(self, packet)
             publish = self._publish_to_client
+            widest = overload.fanout_overlap_widest
             for client, sub in pairs:
+                if sub.folded > widest:
+                    widest = overload.fanout_overlap_widest = sub.folded
                 publish(client, sub, packet, False, fan)
+
+    def _resolve_traced(self, subscribers, packet: Packet) -> tuple:
+        """``clients.resolve`` while tracing is on: the result's one
+        pass over the client registry is the ADR-015 stage ``resolve``
+        of a sampled publish (a child of its ``fanout``, which can so be
+        read with and without it) and ``maxmq.resolve`` in a profiler
+        capture (none for an adopted trace alone, ADR 017). The
+        annotation alone, no section of the loop's ledger: its
+        ``deliver`` holds this pass as it did before the stage was."""
+        tracer = self.tracer
+        tr = packet.__dict__.get("_trace")
+        t0 = tracer.clock()
+        with host_span("maxmq.resolve") if tracer.sample_n else NO_SPAN:
+            found = self.clients.resolve(subscribers)
+        if tr is not None:
+            tr.span("resolve", t0, tracer.clock())
+        return found
 
     def _fan_out_shared(self, shared, pairs, packet: Packet) -> None:
         """$share: pick one member per (group, filter), merging per

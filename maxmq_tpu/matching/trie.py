@@ -82,6 +82,7 @@ def merge_subscription(base: Subscription | None, new: Subscription,
     if new.identifier:
         merged.identifiers[filter_] = new.identifier
     if base is not None:
+        merged.folded = base.folded + 1
         merged.identifiers.update(base.identifiers)
         if base.qos > merged.qos:
             merged.qos = base.qos
@@ -96,7 +97,7 @@ def _copy_subscription(s: Subscription) -> Subscription:
                         retain_as_published=s.retain_as_published,
                         retain_handling=s.retain_handling,
                         identifier=s.identifier,
-                        identifiers=dict(s.identifiers))
+                        identifiers=dict(s.identifiers), folded=s.folded)
 
 
 class SubscriberSet:

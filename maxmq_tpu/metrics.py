@@ -991,6 +991,12 @@ def _register_fanout_metrics(registry: Registry, broker) -> None:
         "Most resolved entries one match result held since start: the "
         "widest fan-out one publish was paid for (fanout_resolved_total "
         "is their sum)", lambda: over.fanout_widest)
+    registry.gauge_func(
+        "maxmq_broker_fanout_overlap_widest",
+        "Most matched entries folded into one receiver since start: a "
+        "session whose own filters overlap on a topic gets one delivery, "
+        "at the highest QoS among them; 1 where no session was matched "
+        "twice", lambda: over.fanout_overlap_widest)
     registry.counter_func(
         "maxmq_broker_fanout_acks_total",
         "Inbound PUBACKs handled: one a QoS 1 delivery, each an inflight "

@@ -50,6 +50,11 @@ class Subscription:
     identifier: int = 0  # v5 subscription identifier attached at subscribe time
     # Merged view when one client holds several overlapping matching filters.
     identifiers: dict[str, int] = field(default_factory=dict)
+    # How many of the client's matching filters this record stands for:
+    # 1 as subscribed, more on the merged view alone (merge_subscription).
+    # A count for the fan-out's books, not part of what two
+    # subscriptions are compared by.
+    folded: int = field(default=1, compare=False, repr=False)
 
     def options_byte(self) -> int:
         return ((self.qos & 0x3)

@@ -22,6 +22,12 @@ Stage model (see docs/adr/015-publish-tracing.md for the contract):
                    (group, filter) key to its last pick, the deliveries
                    not included (a child of ``fanout``, not critical;
                    only a publish that had a $share key has one)
+``resolve``        the match result's ``resolve`` against the client
+                   registry alone (ADR 007): every entry the result holds
+                   probed for a session, the $share keys cut to those
+                   with one; what is left of ``fanout`` without it is
+                   the picks and the deliveries (a child of ``fanout``,
+                   not critical)
 ``bridge``         cluster route consult + forward enqueue (ADR 013)
 ``journal_commit`` storage group-commit duration (writer thread,
                    histogram-only: not tied to one publish)
@@ -155,22 +161,23 @@ LEDGER_SNAP_NS = 50_000_000     # least time between two of its snapshots
 # canonical pipeline stages; CRITICAL_STAGES are the contiguous
 # publisher-path segments whose durations sum to ~e2e (drain happens
 # after the publisher's terminal stage, and so may the flush pass that
-# writes it; share_pick is a part of fanout, which is counted whole;
-# journal_commit/takeover/release are not tied to one
+# writes it; resolve and share_pick are parts of fanout, which is
+# counted whole; journal_commit/takeover/release are not tied to one
 # publish's critical path; bridge_in is critical
 # only on ADOPTED traces, where it IS the path's first local segment;
 # loop_lag is a probe of the loop beside the path, and so are the
 # ledger's loop_* spans, which are no interval of the publish at all)
+FANOUT_PARTS = ("resolve", "share_pick")    # children of fanout
 STAGES = ("decode", "admission", "match_queue", "match_device",
-          "pipeline_wait", "filter", "fanout", "share_pick", "bridge",
-          "bridge_in", "journal_commit", "barrier", "ack", "drain", "flush",
-          "takeover", "release", "aggregate", "loop_lag") + BATCH_PHASES \
-    + LOOP_STAGES
+          "pipeline_wait", "filter", "fanout") + FANOUT_PARTS + (
+    "bridge", "bridge_in", "journal_commit", "barrier", "ack", "drain",
+    "flush", "takeover", "release", "aggregate", "loop_lag") \
+    + BATCH_PHASES + LOOP_STAGES
 CRITICAL_STAGES = frozenset(
     s for s in STAGES
-    if s not in ("drain", "flush", "share_pick", "journal_commit",
-                 "takeover", "release", "aggregate", "loop_lag")
-    + BATCH_PHASES + LOOP_STAGES)
+    if s not in ("drain", "flush", "journal_commit", "takeover", "release",
+                 "aggregate", "loop_lag")
+    + FANOUT_PARTS + BATCH_PHASES + LOOP_STAGES)
 # 10us .. 1s: a phase of one micro-batch is tens of microseconds to a
 # few milliseconds, under the default ladder's first bound
 BATCH_PHASE_BUCKETS = (
@@ -956,7 +963,7 @@ class PipelineTracer:
         spans = [_span_dict(start, s, t0, dur, "", trace.batch, trace.via)
                  if s == "match_device" else
                  _span_dict(start, s, t0, dur,
-                            "fanout" if s == "share_pick" else "")
+                            "fanout" if s in FANOUT_PARTS else "")
                  for s, t0, dur in trace.spans]
         spans += [_span_dict(start, s, t0, dur, "match_device", batch,
                              "", shadow)

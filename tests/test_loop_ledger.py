@@ -541,8 +541,13 @@ def test_the_eleven_metrics_and_their_files_agree():
             assert entry["moves"] == "deliver_p50_ms"
             assert entry["workloads"] == ["fleet-1m.steady",
                                           "sparkplug-plant.steady"]
-    # what was there is there still, in its place: the new ones are last
-    assert [m["name"] for m in bench["per_layer"]][-11:] == list(entries)
+    # what was there is there still, in its place: the eleven came last,
+    # in one run and in this order (what later PRs add comes after them)
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(next(iter(entries)))
+    assert names[first:first + 11] == list(entries)
+    assert not any(n.startswith("loop_") and "_us." in n
+                   for n in names[first + 11:])
 
 
 def test_the_benchmarks_reader_reads_a_ledger_span_through_its_file():

@@ -17,7 +17,8 @@ a file and prints:
   seconds of that idle time each top-level ``maxmq.*`` name covers, its
   share of the idle time, and what no annotation covers (``maxmq.ack``,
   a subscriber's PUBACK handled inside a chunk's ``maxmq.read``,
-  ``maxmq.share``, the $share picks inside a ``maxmq.deliver``,
+  ``maxmq.share`` and ``maxmq.resolve``, the $share picks and the match
+  result's pass over the client registry inside a ``maxmq.deliver``,
   ``maxmq.flush``, a burst's ``writev`` inside a flush pass's
   ``maxmq.pass``, and a pass that runs inside another section are cut
   out of the span around them and given rows of their own: self time,
@@ -63,7 +64,8 @@ PREFIX = "maxmq."
 LOOP_MARKS = ("maxmq.read", "maxmq.deliver", "maxmq.settle")
 # annotations that get a row of their own wherever they nest: their time
 # is taken from the span around them
-CARVED = ("maxmq.ack", "maxmq.share", "maxmq.flush", "maxmq.pass")
+CARVED = ("maxmq.ack", "maxmq.share", "maxmq.resolve", "maxmq.flush",
+          "maxmq.pass")
 
 
 # -- interval arithmetic (nanoseconds; an interval is (start, end)) --------
