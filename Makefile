@@ -52,13 +52,15 @@ test-asan:
 	MAXMQ_NATIVE_DIR=$(CURDIR)/native/asan \
 	$(PY) -c "from maxmq_tpu import native; \
 	    assert native.available(), 'asan ctypes lib failed to load'; \
-	    assert native.decode_module(build=False), 'asan decode ext failed to load'"
+	    assert native.decode_module(build=False), 'asan decode ext failed to load'; \
+	    assert native.sender_module(), 'asan sender ext failed to load'"
 	LD_PRELOAD="$(ASAN_LIB) $(STDCXX_LIB)" \
 	ASAN_OPTIONS=detect_leaks=0:abort_on_error=1 \
 	MAXMQ_NATIVE_DIR=$(CURDIR)/native/asan \
 	JAX_PLATFORMS=cpu \
 	$(PY) -m pytest tests/test_sig_parity.py tests/test_churn_stress.py \
-	    tests/test_native.py tests/test_refdecode.py -x -q
+	    tests/test_native.py tests/test_refdecode.py \
+	    tests/test_native_sender.py -x -q
 
 hooks:
 	chmod +x scripts/githooks/*

@@ -12,6 +12,7 @@ import asyncio
 import time
 from collections import deque
 from dataclasses import dataclass
+from functools import partial
 
 from .. import faults
 from ..matching.trie import TopicAliases
@@ -98,12 +99,14 @@ class FlushScheduler:
     buffer only), so a delivery pays no task wake-up: ``direct``. What
     the owner may not finish goes to the writer task by completing the
     getter, as every wake did before: ``woken``, by reason
-    (``backpressure``: the transport still holds bytes, or the burst
-    cap left a rest; ``fault``: a ``client.write`` fault is armed and
-    may ask for an awaited stall; ``facade``: the writer shows no
-    transport to ask; ``stop``: the client is closing; ``error``: the
-    direct write raised, which ends that client's writer and nobody
-    else's). The task is what back-pressure needs, and nothing else.
+    (``backpressure``: the transport or the sender still holds bytes,
+    or the burst cap left a rest; ``fault``: a ``client.write`` fault
+    is armed and may ask for an awaited stall; ``facade``: the writer
+    shows no transport to ask; ``stop``: the client is closing;
+    ``error``: the direct write raised, which ends that client's writer
+    and nobody else's). The task is what back-pressure needs, and nothing else.
+    Where the broker has a sender (``sender.py``), a direct burst is
+    handed to its thread, kicked once at the end of the pass.
 
     The pass runs from ``loop.call_soon`` (the next iteration, first in
     line) or earlier, where a producer that queued many deliveries
@@ -113,13 +116,16 @@ class FlushScheduler:
     pass that writes a sampled publish's deliveries (``watch``) is that
     publish's ADR-015 ``flush`` stage: the whole pass, timed there."""
 
-    __slots__ = ("_pending", "_scheduled", "_traced", "tracer", "flushes",
-                 "deferred", "coalesced", "direct", "woken")
+    __slots__ = ("_pending", "_scheduled", "_traced", "tracer", "sender",
+                 "flushes", "deferred", "coalesced", "direct", "woken")
 
     def __init__(self, tracer) -> None:
         self._pending: list = []
         self._scheduled = False
         self.tracer = tracer        # ADR 015: the ``flush`` stage's clock
+        # the broker's SocketSender while it serves (``sender.py``): the
+        # pass's bursts are handed to it, and it is kicked once a pass
+        self.sender = None
         self._traced: list = []     # sampled publishes the next pass writes
         self.flushes = 0        # passes that found something parked
         self.deferred = 0       # wakes parked for a flush pass
@@ -206,6 +212,8 @@ class FlushScheduler:
             self.woken[reason] = self.woken.get(reason, 0) + 1
             if not g.done():
                 g.set_result(None)
+        if self.sender is not None:
+            self.sender.kick()
 
 
 class OutboundQueue:
@@ -370,6 +378,13 @@ class Client:
             scheduler=getattr(server, "flush_sched", None))
         self._writer_task: asyncio.Task | None = None
         self._reader_task: asyncio.Task | None = None
+        # ADR 019, who writes a socket: the broker's SocketSender and
+        # this socket's handle in it, set by ``start`` where the sender
+        # may write it; ``_sink`` is its submit for that handle, the
+        # pass's writelines (None: every byte goes through the writer)
+        self._sender = None
+        self._channel: int | None = None
+        self._sink = None
         # slow-consumer ledger (ADR 012): writer progress timestamp for
         # the stall detector, the first fatal writer error, and
         # per-client drop accounting surfaced via $SYS + /metrics
@@ -457,6 +472,12 @@ class Client:
                 except (AttributeError, RuntimeError):
                     pass
             self.write_progress = time.monotonic()
+            sender = getattr(self.server, "sender", None)
+            if sender is not None:
+                handle = sender.open(self)
+                if handle is not None:
+                    self._sender, self._channel = sender, handle
+                    self._sink = partial(sender.submit, handle)
             self.outbound._direct = self._write_direct
             self._writer_task = asyncio.get_running_loop().create_task(
                 self._write_loop(), name=f"mq-write-{self.id or id(self)}")
@@ -532,14 +553,16 @@ class Client:
     # of de-accounted inside the transport buffer (ADR 012)
     BURST_BYTES = 65536
 
-    def _flush_bufs(self, bufs: list) -> None:
+    def _flush_bufs(self, bufs: list, sink=None) -> None:
         """Hand one burst's collected wire buffers to the transport in
         a single writev-style call (ADR 019): shared template segments
         are joined once at the socket layer per burst, not copied once
         per subscriber at fan-out. Writer facades without writelines
         (WS / embedder stream shims expose only write) get the burst
-        as one joined write — same bytes, one frame."""
-        writelines = getattr(self.writer, "writelines", None)
+        as one joined write — same bytes, one frame. ``sink``, the
+        sender's submit for this socket, takes writelines' place: the
+        ``flush`` section then times the hand-over."""
+        writelines = sink or getattr(self.writer, "writelines", None)
         tracer = getattr(self.server, "tracer", None)
         if writelines is None:
             self.writer.write(b"".join(bufs))
@@ -577,6 +600,10 @@ class Client:
                     await asyncio.sleep(stall)
                 else:
                     await q.wait()
+                if self._sink is not None:
+                    # the task writes through the transport: only once
+                    # the sender holds nothing for this socket
+                    await self._sender.wait_idle(self._channel)
                 seq = q.removed
                 stall = self._write_burst(stalled=bool(stall),
                                           settle=False)
@@ -606,8 +633,8 @@ class Client:
         if self._drain_traces:
             self._settle_drain_traces(flushed)
 
-    def _write_burst(self, stalled: bool = False,
-                     settle: bool = True) -> float | None:
+    def _write_burst(self, stalled: bool = False, settle: bool = True,
+                     sink=None) -> float | None:
         """Hand one burst of the outbound queue to the transport, in
         queue order, synchronously: everything queued, bounded in bytes
         (``BURST_BYTES``). Wire buffers (``bytes`` / ``tuple`` items)
@@ -623,7 +650,9 @@ class Client:
         ADR-015 drain watchers are closed here, against the ``removed``
         count read right after the hand-over, so a delivery enqueued
         later waits for a later burst; the task passes False and closes
-        them after its ``drain()`` (``_flush_burst``)."""
+        them after its ``drain()`` (``_flush_burst``). ``sink`` takes
+        every byte of the burst in the transport's place (the pass's
+        hand-over to the sender)."""
         q = self.outbound
         info = self.server.info
         armed = faults.REGISTRY.any_armed()
@@ -662,13 +691,13 @@ class Client:
                     info.messages_sent += 1
             else:
                 if bufs:                   # keep the wire in order
-                    self._flush_bufs(bufs)
-                self._write_packet(packet)
+                    self._flush_bufs(bufs, sink)
+                self._write_packet(packet, sink)
                 burst += _estimate_wire(packet)
             if burst >= self.BURST_BYTES:
                 break
         if bufs:
-            self._flush_bufs(bufs)
+            self._flush_bufs(bufs, sink)
         if burst:
             self.write_progress = time.monotonic()
             if settle and self._drain_traces:
@@ -685,8 +714,11 @@ class Client:
 
         Only into an empty transport buffer, and one burst at most: a
         wedged consumer's backlog stays in the accounted queue, never
-        in the transport (ADR 012). A write that raises ends this
-        client's writer as the task's own ``except`` does, recorded in
+        in the transport (ADR 012). Where the sender writes this
+        socket, the burst goes to it, and only while it holds nothing
+        for this socket: so it holds one burst at most, as the
+        transport's high-water mark keeps it. A write that raises ends
+        this client's writer as the task's own ``except`` does, recorded in
         ``write_error``; nothing reaches the caller, who is the publish
         pipeline's consumer or a loop callback."""
         transport = getattr(self.writer, "transport", None)
@@ -699,7 +731,10 @@ class Client:
         try:
             if transport.get_write_buffer_size():
                 return "backpressure"
-            if self._write_burst() is None:
+            sink = self._sink
+            if sink is not None and not self._sender.idle(self._channel):
+                return "backpressure"
+            if self._write_burst(sink=sink) is None:
                 return "stop"
         except Exception as exc:
             self.write_error = self.write_error or repr(exc)
@@ -707,7 +742,7 @@ class Client:
             return "error"                 # nothing is left to wake
         return "backpressure" if self.outbound.qsize() else None
 
-    def _write_packet(self, packet: Packet) -> None:
+    def _write_packet(self, packet: Packet, sink=None) -> None:
         packet = self.server.hooks.modify("on_packet_encode", packet, self)
         # oversize outbound packets first shed their optional problem-
         # info properties [MQTT-3.2.2-19/20]; still-oversize ones drop
@@ -717,7 +752,10 @@ class Client:
             self.server.info.messages_dropped += 1
             return
         assert self.writer is not None
-        self.writer.write(wire)
+        if sink is not None:
+            sink((wire,))
+        else:
+            self.writer.write(wire)
         self.server.info.bytes_sent += len(wire)
         self.server.info.packets_sent += 1
         if packet.type == PT.PUBLISH:
@@ -732,6 +770,8 @@ class Client:
 
     async def _drain(self) -> None:
         if self.writer is not None:
+            if self._sink is not None:
+                await self._sender.wait_idle(self._channel)
             try:
                 await self.writer.drain()
             except (ConnectionError, OSError) as exc:
@@ -869,9 +909,28 @@ class Client:
             return False
 
     def send_now(self, packet: Packet) -> None:
-        """Write synchronously, bypassing the queue (CONNACK, shutdown)."""
+        """Write synchronously, bypassing the queue (CONNACK, shutdown):
+        behind what the sender still holds for this socket, in its FIFO,
+        else through the transport."""
         if self.writer is not None:
-            self._write_packet(packet)
+            sink = self._sink
+            if sink is not None and not self._sender.idle(self._channel):
+                self._write_packet(packet, sink)
+                self._sender.kick()
+            else:
+                self._write_packet(packet)
+
+    def sender_failed(self, exc: Exception) -> None:
+        """The sender could not write this socket (or hand its bytes
+        back): as a failed direct write, the error is recorded and this
+        client's writer ends; the transport is closed as asyncio closes
+        it after a failed send, which ends the read loop."""
+        self.write_error = self.write_error or repr(exc)
+        if self._writer_task is not None:
+            self._writer_task.cancel()
+        transport = getattr(self.writer, "transport", None)
+        if transport is not None:
+            transport.abort()
 
     async def stop(self, cause: ProtocolError | None = None) -> None:
         """Terminate the network connection (the session may persist)."""
@@ -889,6 +948,11 @@ class Client:
                 await asyncio.wait_for(self._writer_task, timeout=1.0)
             except (asyncio.TimeoutError, asyncio.CancelledError):
                 self._writer_task.cancel()
+        if self._sink is not None:
+            # the sender's dup closes after its last byte: the FIN
+            # cannot overtake it when the transport closes below
+            self._sink = None
+            self._sender.forget(self._channel)
         # settle the byte ledgers for anything never written: abandoned
         # bytes must not pin the global watermark in shedding forever
         self.outbound.release_all()

@@ -34,6 +34,7 @@ from .client import (Client, ClientRegistry, FlushScheduler,
                      PacketIDExhausted)
 from .listeners import Listener, Listeners
 from .overload import OverloadState, TokenBucket, top_offenders
+from .sender import SocketSender
 from .sys_info import SysInfo
 
 __version__ = "0.1.0"
@@ -242,6 +243,10 @@ class Broker:
         # enqueue wakes the task), the pre-019 behavior.
         self.flush_sched = (FlushScheduler(self.tracer)
                             if self.capabilities.flush_coalesce else None)
+        # ADR 019, who writes a socket: the native thread the pass hands
+        # its bursts to (sender.py), from serve() to close(); None
+        # without the native library or a flush pass
+        self.sender: SocketSender | None = None
         self._sys_trace_topics: set[str] = set()  # retained while sampling
         self._running = False
         self.loop: asyncio.AbstractEventLoop | None = None
@@ -295,6 +300,9 @@ class Broker:
         # ADR 015: the loop thread's own books; while the tracer samples,
         # a stock selector loop's select is timed (idle, poll) until close
         self.tracer.loop.attach(self.loop)
+        if self.flush_sched is not None:
+            self.sender = SocketSender.start(self.loop)
+            self.flush_sched.sender = self.sender
         # ADR 014: find the persistence hook (and its write-behind
         # journal, if it rides one) before restore — the durability
         # barrier and boot-epoch bump both hang off it
@@ -414,6 +422,10 @@ class Broker:
             self._pub_consumer = None
             self._pub_queue = None
         await self.listeners.close_all()
+        if self.sender is not None:
+            # every client is stopped: what the thread holds is written
+            self.flush_sched.sender = None
+            self.sender.close()
         self.tracer.loop.detach()
         self.hooks.notify("on_stopped")
         self.hooks.stop_all()

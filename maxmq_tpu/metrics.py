@@ -1066,6 +1066,28 @@ def _register_fanout_metrics(registry: Registry, broker) -> None:
             "(direct + woken) is the share of bursts that paid no wake-up",
             lambda: [({"reason": r}, n) for r, n in sched.woken.items()])
 
+    def sender_stat(key: str):
+        sender = getattr(broker, "sender", None)
+        return sender.stats()[key] if sender is not None else 0
+
+    for key, name, help_ in (
+            ("bursts", "bursts_total",
+             "Bursts the sender thread wrote whole (ADR 019, who writes "
+             "a socket); over fanout_flush_direct_total: the share of "
+             "the pass's bursts whose send left the event loop"),
+            ("spills", "spills_total",
+             "Bursts the sender handed back to the loop after a short "
+             "write, for the transport's own buffer to finish"),
+            ("errors", "errors_total",
+             "Sends the sender saw refused (a reset or closed peer); "
+             "each ends that client's writer"),
+            ("busy_seconds", "busy_seconds_total",
+             "The sender thread's time inside send()"),
+            ("wakes", "wakes_total",
+             "Times a flush pass woke the sleeping sender thread")):
+        registry.counter_func(f"maxmq_broker_sender_{name}", help_,
+                              lambda k=key: sender_stat(k))
+
 
 def _register_filter_metrics(registry: Registry, broker) -> None:
     """ADR-023 content plane: predicate-subscription registry size,

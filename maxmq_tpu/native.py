@@ -137,8 +137,9 @@ def available() -> bool:
     return _try_load() is not None
 
 
-_decode_mod = None
-_decode_attempted = False
+# CPython extensions by name: the module, or None once a build or load
+# failed (logged once); absent from the dict while still retriable
+_extensions: dict = {}
 
 
 def chain_params_in_effect(mod) -> tuple:
@@ -152,6 +153,35 @@ def chain_params_in_effect(mod) -> tuple:
     return getter() if getter is not None else (64, 1, 1)
 
 
+def _extension(name: str, build: bool):
+    """The CPython extension ``native/<name>.so``, or None. Built on
+    first use where a Makefile is (``build``), loaded by path."""
+    with _load_lock:
+        if name in _extensions:
+            return _extensions[name]
+        if os.environ.get("MAXMQ_NO_NATIVE"):
+            _extensions[name] = None
+            return None
+        path = os.path.join(_NATIVE_DIR, f"{name}.so")
+        if not os.path.exists(path):
+            if (not build or not os.path.exists(
+                    os.path.join(_NATIVE_DIR, "Makefile"))):
+                return None            # stay retriable for build=True
+            if not _build(f"{name}.so"):
+                _extensions[name] = None
+                return None
+        try:
+            import importlib.util
+            spec = importlib.util.spec_from_file_location(name, path)
+            mod = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(mod)
+        except Exception as exc:
+            _log.error("native extension %s did not load: %r", path, exc)
+            mod = None
+        _extensions[name] = mod
+        return mod
+
+
 def decode_module(build: bool = True):
     """The maxmq_decode CPython extension (candidate verify + subscriber
     union in C; see native/maxmq_decode.cpp), or None. A separate .so
@@ -161,33 +191,13 @@ def decode_module(build: bool = True):
     ``build=False`` only loads an already-built .so (import-time callers
     must not block on a compile); the device match path passes the
     default and compiles on demand."""
-    global _decode_mod, _decode_attempted
-    with _load_lock:
-        if _decode_attempted:
-            return _decode_mod
-        if os.environ.get("MAXMQ_NO_NATIVE"):
-            _decode_attempted = True
-            return None
-        path = os.path.join(_NATIVE_DIR, "maxmq_decode.so")
-        if not os.path.exists(path):
-            if (not build or not os.path.exists(
-                    os.path.join(_NATIVE_DIR, "Makefile"))):
-                return None            # stay retriable for build=True
-            if not _build("maxmq_decode.so"):
-                _decode_attempted = True
-                return None
-        _decode_attempted = True
-        try:
-            import importlib.util
-            spec = importlib.util.spec_from_file_location(
-                "maxmq_decode", path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            _decode_mod = mod
-        except Exception as exc:
-            _log.error("native decode %s did not load: %r", path, exc)
-            _decode_mod = None
-        return _decode_mod
+    return _extension("maxmq_decode", build)
+
+
+def sender_module():
+    """The maxmq_sender CPython extension (the flush pass's socket
+    writer thread; see native/maxmq_sender.cpp), or None."""
+    return _extension("maxmq_sender", True)
 
 
 class NativeVocab:
