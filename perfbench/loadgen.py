@@ -8,14 +8,18 @@ generator holds a slice of the cell's publishers and of its live
 subscribers. Publishers send open loop (every message has a due time
 from a Poisson schedule made from the seed, and is sent when it is due
 whether or not the broker keeps up) or closed loop (at most ``in_flight``
-messages beyond the oldest QoS 1 PUBLISH not yet PUBACKed). Every payload
+messages beyond the oldest QoS 1 PUBLISH not yet PUBACKed). A traffic file
+with ``churn`` adds clean-start clients that SUBSCRIBE and UNSUBSCRIBE
+inside every phase (``churn_schedule``). Every payload
 opens with ``<publisher>:<seq>:<due_ns>|``: ``time.monotonic_ns()`` is one
 clock for every process of the host, so a subscriber in another process
 can time a delivery from when the message was due. Subscribers work on
 frames: they stamp the arrival of a chunk, cut it into packets, PUBACK
 QoS 1 and keep ``(head, arrival_ns, qos)``. What was sent (with what the
 plain reference says it should reach) and what arrived go to one pickle
-per phase, which ``run.py`` joins and checks after the window.
+per phase, which ``run.py`` joins and checks after the window; with
+churn, so does every subscription change with the times it was sent and
+acknowledged.
 """
 
 from __future__ import annotations
@@ -38,6 +42,7 @@ import generators  # noqa: E402
 from reference import Reference  # noqa: E402
 
 FILLER = random.Random(7).randbytes(2048)
+ACK_WAIT_S = 30.0       # the longest a phase's end waits for its (UN)SUBACKs
 
 
 def varint(n: int) -> bytes:
@@ -55,6 +60,19 @@ def connect_packet(client_id: str, clean: bool) -> bytes:
     body = (b"\x00\x04MQTT\x04" + bytes([0x02 if clean else 0x00])
             + (3600).to_bytes(2, "big") + len(cid).to_bytes(2, "big") + cid)
     return b"\x10" + varint(len(body)) + body
+
+
+def subscribe_packet(pid: int, filt: str, qos: int) -> bytes:
+    f = filt.encode()
+    body = pid.to_bytes(2, "big") + len(f).to_bytes(2, "big") + f \
+        + bytes([qos])
+    return b"\x82" + varint(len(body)) + body
+
+
+def unsubscribe_packet(pid: int, filt: str) -> bytes:
+    f = filt.encode()
+    body = pid.to_bytes(2, "big") + len(f).to_bytes(2, "big") + f
+    return b"\xa2" + varint(len(body)) + body
 
 
 def publish_packet(topic: bytes, payload: bytes, qos: int, pid: int) -> bytes:
@@ -83,6 +101,10 @@ class Conn(asyncio.Protocol):
         self.pending: dict = {}         # pid -> seq, QoS 1 not PUBACKed
         self.next_pid = 0
         self.on_room = None             # closed loop: called on PUBACK
+        self.acked = None               # with churn: [(seq, PUBACK arrival)]
+        # churn side: pid -> the change's log row, (UN)SUBACK not yet in
+        self.changes: dict = {}
+        self.refused: list = []         # filters a SUBACK refused (0x80)
 
     def connection_made(self, transport) -> None:
         self.transport = transport
@@ -133,7 +155,9 @@ class Conn(asyncio.Protocol):
                 bar = buf.find(b"|", j, i + rem)
                 self.got.append((bytes(buf[j:bar]), now, qos | (b0 & 8)))
             elif ptype == 4:
-                self.pending.pop((buf[i] << 8) | buf[i + 1], None)
+                seq = self.pending.pop((buf[i] << 8) | buf[i + 1], None)
+                if self.acked is not None and seq is not None:
+                    self.acked.append((seq, now))
                 room = True
             elif ptype == 2:
                 self.session_present = bool(buf[i] & 1)
@@ -142,6 +166,12 @@ class Conn(asyncio.Protocol):
                 else:
                     self.connack.set_exception(
                         ConnectionError(f"CONNACK {buf[i + 1]}"))
+            elif ptype == 9 or ptype == 11:     # SUBACK, UNSUBACK
+                row = self.changes.pop((buf[i] << 8) | buf[i + 1], None)
+                if row is not None:
+                    row[5] = now
+                    if ptype == 9 and 0x80 in buf[i + 2:i + rem]:
+                        self.refused.append(row[1])
             pos = i + rem
         del buf[:pos]
         if acks:
@@ -156,6 +186,17 @@ class Conn(asyncio.Protocol):
             self.pending[pid] = seq
         self.transport.write(publish_packet(topic.encode(), payload, qos,
                                             pid))
+
+    def change(self, kind: str, filt: str, qos: int) -> list:
+        """One SUBSCRIBE (``kind`` "sub") or UNSUBSCRIBE of one filter;
+        returns its log row ``[cid, filter, qos, kind, sent_ns,
+        acked_ns]``, whose last field the (UN)SUBACK fills in."""
+        pid = self.next_pid = self.next_pid % 65535 + 1
+        row = [self.client_id, filt, qos, kind, time.monotonic_ns(), None]
+        self.changes[pid] = row
+        self.transport.write(subscribe_packet(pid, filt, qos) if kind == "sub"
+                             else unsubscribe_packet(pid, filt))
+        return row
 
 
 class Publisher:
@@ -190,6 +231,37 @@ class Publisher:
                           time.monotonic_ns()))
 
 
+def churn_schedule(churn: dict, pool: list, seed: int, phase: int,
+                   seconds: float) -> list:
+    """The churn clients' changes in one phase, the same list in every
+    generator process: ``(offset_ns, client k, "sub" | "unsub", filter)``
+    in time order. The ``initial`` SUBSCRIBEs at offset 0, dealt round the
+    clients; then a swap every ``1 / rate`` s (a fixed schedule: every
+    seed makes the same number of changes), an UNSUBSCRIBE of a filter the
+    client holds and a SUBSCRIBE of one it does not hold. Which client and
+    which filters come from the seed and the phase's number."""
+    rng = random.Random(f"churn:{seed}:{phase}")
+    n = churn["clients"]
+    held: list[list] = [[] for _ in range(n)]
+    events = []
+    for j in range(churn["initial"]):
+        k = j % n
+        filt = rng.choice([f for f in pool if f not in held[k]])
+        held[k].append(filt)
+        events.append((0, k, "sub", filt))
+    rate = churn["rate"]
+    for i in range(int(rate * seconds)):
+        at = int((i + 0.5) / rate * 1e9)
+        k = rng.randrange(n)
+        out = held[k].pop(rng.randrange(len(held[k]))) if held[k] else None
+        if out is not None:
+            events.append((at, k, "unsub", out))
+        filt = rng.choice([f for f in pool if f not in held[k] and f != out])
+        held[k].append(filt)
+        events.append((at, k, "sub", filt))
+    return events
+
+
 class Generator:
     def __init__(self, args) -> None:
         self.args = args
@@ -204,6 +276,14 @@ class Generator:
         self.pubs: list[Publisher] = []
         self.subs: dict[str, Conn] = {}
         self.phase = 0
+        # clean-start clients that subscribe and unsubscribe in a phase
+        self.churn = self.traffic.get("churn")
+        self.churners: dict[str, Conn] = {}
+        self.churn_log: list = []       # this phase's changes, as sent
+        if self.churn:
+            recipe = self.churn["filters"]
+            self.churn_pool = generators.find(recipe["recipe"])(
+                args.seed, self.hits, **recipe.get("args", {}))
 
     async def connect(self) -> dict:
         loop = asyncio.get_running_loop()
@@ -223,16 +303,32 @@ class Generator:
                     lambda cid=cid: Conn(cid, False), "127.0.0.1", a.port)
                 self.subs[cid] = conn
                 conns.append(conn)
+        if self.churn:
+            # clean start, and no filter held between phases
+            for k in range(a.index, self.churn["clients"], a.of):
+                _, conn = await loop.create_connection(
+                    lambda k=k: Conn(f"load-c{k}", True), "127.0.0.1",
+                    a.port)
+                self.churners[conn.client_id] = conn
+                conns.append(conn)
         await asyncio.wait_for(asyncio.gather(*(c.connack for c in conns)),
                                60)
-        return {"publishers": len(self.pubs), "subscribers": len(self.subs),
-                "sessions_resumed": sum(c.session_present
-                                        for c in self.subs.values())}
+        reply = {"publishers": len(self.pubs), "subscribers": len(self.subs),
+                 "sessions_resumed": sum(c.session_present
+                                         for c in self.subs.values())}
+        if self.churn:
+            reply["churn_clients"] = len(self.churners)
+        return reply
+
+    def receivers(self) -> dict:
+        """Every connection that receives: live subscribers, churn
+        clients."""
+        return {**self.subs, **self.churners}
 
     def lost(self) -> list:
         return [f"{c.client_id}: {c.lost}"
-                for c in [p.conn for p in self.pubs] + list(self.subs.values())
-                if c.lost]
+                for c in [p.conn for p in self.pubs]
+                + list(self.receivers().values()) if c.lost]
 
     # -- the two loops ------------------------------------------------------
 
@@ -288,6 +384,61 @@ class Generator:
                 "gen_late_p99_ms": arith.percentile(late, 99) / 1e6,
                 "gen_late_max_ms": max(late) / 1e6} if late else {}
 
+    # -- churn --------------------------------------------------------------
+
+    async def churn_loop(self, t0: int, seconds: float) -> None:
+        """This process's share of the phase's changes, each sent when
+        it is due."""
+        a = self.args
+        for offset, k, kind, filt in churn_schedule(
+                self.churn, self.churn_pool, a.seed, self.phase, seconds):
+            if k % a.of != a.index:
+                continue
+            wait = t0 + offset - time.monotonic_ns()
+            if wait > 0:
+                await asyncio.sleep(wait / 1e9)
+            self.churn_log.append(
+                self.churners[f"load-c{k}"].change(kind, filt, k % 2))
+
+    async def churn_leave(self) -> None:
+        """UNSUBSCRIBE every filter a churn client still holds, then wait
+        (up to ACK_WAIT_S) for every SUBACK and UNSUBACK of the phase."""
+        last: dict = {}
+        for row in self.churn_log:
+            last[(row[0], row[1])] = row
+        for (cid, filt), row in last.items():
+            if row[3] == "sub":
+                self.churn_log.append(
+                    self.churners[cid].change("unsub", filt, row[2]))
+        end = time.monotonic() + ACK_WAIT_S
+        while (any(c.changes for c in self.churners.values())
+               and time.monotonic() < end):
+            await asyncio.sleep(0.05)
+
+    def churn_stats(self) -> dict:
+        """What the phase's reply says of the churn: changes sent, SUBACK
+        times, the publishers' PUBACK times (ms)."""
+        log = self.churn_log
+        subs = sum(1 for r in log if r[3] == "sub")
+        out = {"subscribes": subs, "unsubscribes": len(log) - subs}
+        for name, lat in (
+                ("suback", [r[5] - r[4] for r in log
+                            if r[3] == "sub" and r[5] is not None]),
+                ("puback", self.puback_ns())):
+            if lat:
+                out[f"{name}_p50_ms"] = arith.percentile(lat, 50) / 1e6
+                out[f"{name}_max_ms"] = max(lat) / 1e6
+        return out
+
+    def puback_ns(self) -> list:
+        """PUBACK arrival - sent, ns, for every QoS 1 PUBLISH acked."""
+        out = []
+        for pub in self.pubs:
+            sent = {r[1]: r[5] for r in pub.sent if r[3]}
+            out += [at - sent[seq] for seq, at in pub.conn.acked
+                    if seq in sent]
+        return out
+
     # -- one phase ------------------------------------------------------------
 
     async def run_phase(self, t0: int, seconds: float, out: str) -> dict:
@@ -296,14 +447,23 @@ class Generator:
         receivers the reference expects) and what arrived to ``out``."""
         self.phase += 1
         t1 = t0 + int(seconds * 1e9)
+        receivers = self.receivers()
         for pub in self.pubs:
             pub.sent = []
-        for conn in self.subs.values():
+            if self.churn:
+                pub.conn.acked = []
+        for conn in receivers.values():
             conn.got = []
+            conn.refused = []
+        self.churn_log = []
         cpu0, wall0 = time.process_time(), time.monotonic()
         loop_fn = (self.open_loop if self.traffic["loop"] == "open"
                    else self.closed_loop)
-        stats = await loop_fn(t0, t1)
+        if self.churn:
+            stats, _ = await asyncio.gather(loop_fn(t0, t1),
+                                            self.churn_loop(t0, seconds))
+        else:
+            stats = await loop_fn(t0, t1)
         stats["gen_cpu_share"] = ((time.process_time() - cpu0)
                                   / (time.monotonic() - wall0))
         # drain: every PUBACK in, and no delivery for `quiet` seconds
@@ -311,27 +471,37 @@ class Generator:
         end = time.monotonic() + grace
         seen, since = -1, time.monotonic()
         while time.monotonic() < end:
-            n = sum(len(c.got) for c in self.subs.values())
+            n = sum(len(c.got) for c in receivers.values())
             if n != seen:
                 seen, since = n, time.monotonic()
             if (time.monotonic() - since >= quiet
                     and not any(p.conn.pending for p in self.pubs)):
                 break
             await asyncio.sleep(0.05)
+        drain_end = time.monotonic_ns()
+        if self.churn:
+            await self.churn_leave()
         sent = []
         for pub in self.pubs:
             for rec in pub.sent:
                 plain, shared = self.ref.receivers(rec[2])
                 sent.append(rec + (plain, shared))
+        dump = {"sent": sent,
+                "got": {cid: c.got for cid, c in receivers.items()},
+                "unacked": {p.index: sorted(p.conn.pending.values())
+                            for p in self.pubs if p.conn.pending}}
+        if self.churn:
+            dump.update(churn=[tuple(r) for r in self.churn_log],
+                        drain_end_ns=drain_end)
+            stats.update(self.churn_stats())
         with open(out, "wb") as fh:
-            pickle.dump({"sent": sent,
-                         "got": {cid: c.got for cid, c in self.subs.items()},
-                         "unacked": {p.index: sorted(p.conn.pending.values())
-                                     for p in self.pubs if p.conn.pending}},
-                        fh, protocol=pickle.HIGHEST_PROTOCOL)
+            pickle.dump(dump, fh, protocol=pickle.HIGHEST_PROTOCOL)
+        refused = [f"SUBACK refused {c.client_id} {f}"
+                   for c in receivers.values() for f in c.refused]
         stats.update(sent=len(sent),
-                     received=sum(len(c.got) for c in self.subs.values()),
-                     failures=[f"lost connection: {x}" for x in self.lost()])
+                     received=sum(len(c.got) for c in receivers.values()),
+                     failures=[f"lost connection: {x}" for x in self.lost()]
+                     + refused)
         return stats
 
     async def main(self) -> None:
@@ -359,7 +529,8 @@ class Generator:
             else:
                 reply = {"failures": [f"unknown command {line!r}"]}
             print(json.dumps(reply), flush=True)
-        for conn in [p.conn for p in self.pubs] + list(self.subs.values()):
+        for conn in ([p.conn for p in self.pubs]
+                     + list(self.receivers().values())):
             if not conn.lost:
                 conn.transport.write(b"\xe0\x00")
                 conn.transport.close()

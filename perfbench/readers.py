@@ -46,6 +46,14 @@ def boot_seconds(run: dict, key: str):
     return run["boot_seconds"].get(key)
 
 
+def run_number(run: dict, key: str, scale: float = 1.0):
+    """One of the run's own top-level numbers (``window_programs``,
+    ``table_versions``, ``check_s``); None where the run has no such
+    number."""
+    value = run.get(key)
+    return value * scale if isinstance(value, (int, float)) else None
+
+
 def generator_field(run: dict, field: str, scale: float = 1.0):
     """The largest value any generator process reported for the field."""
     vals = [g[field] for g in run["generators"] if field in g]

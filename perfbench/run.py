@@ -12,7 +12,11 @@ from ``--seed``, compiles it for the device at boot and serves its TCP
 listener. The load comes from ``loadgen.py`` children that never import
 JAX. After a warm-up (counted as set-up) the window runs for
 ``--seconds``; what was delivered is then checked against the plain
-reference and one JSON object is printed as the last line. Without a TPU
+reference and one JSON object is printed as the last line. Nothing may
+be built and the table may not move inside the window, unless the
+traffic file declares ``churn`` (clients that subscribe and unsubscribe
+inside it): then programs built and table versions are counted instead.
+Without a TPU
 the run exits non-zero before it prints a result; ``--rehearse`` lets the
 CPU through, with the table size of ``perfbench/rehearse/<config>.json``,
 for the sandbox only.
@@ -400,11 +404,17 @@ async def measure(args, cell: dict, device: dict, watch: CompileWatch,
         await served.boot()
         await served.connect()
         broker, engine = served.broker, served.engine
+        churn = "churn" in cell["traffic"]
         say(f"warm-up: {WARM_SECONDS} s of the cell's own traffic")
         await served.phase("warm", WARM_SECONDS)
         # nothing may be building when the window opens
-        await asyncio.get_running_loop().run_in_executor(
-            None, engine.close, 400.0)
+        loop = asyncio.get_running_loop()
+        await loop.run_in_executor(None, engine.close, 400.0)
+        if churn and engine._stale():
+            # the churn clients left no filter behind, but the table's
+            # version moved: the rotation the next match would set off
+            engine.refresh_soon()
+            await loop.run_in_executor(None, engine.close, 400.0)
         served.fail(not engine._stale() and not engine.compiling,
                     "tables stale or compiling after the warm-up")
         version = engine.index.sub_version
@@ -426,35 +436,48 @@ async def measure(args, cell: dict, device: dict, watch: CompileWatch,
                     max(0.0, (edge - time.monotonic_ns()) / 1e9))
                 ends.append((counters(broker), broker.tracer.clock()))
             built = watch.programs - programs
-            return ends, built, await tracing if tracing else 0.0
+            moved = engine.index.sub_version - version
+            return ends, built, moved, await tracing if tracing else 0.0
 
-        t0, replies, dumps, (ends, built, slice_s) = await served.phase(
-            "window", args.seconds, during=beside)
+        t0, replies, dumps, (ends, built, moved, slice_s) = \
+            await served.phase("window", args.seconds, during=beside)
         t1 = t0 + int(args.seconds * 1e9)
         after = counters(broker)
         say(f"window done; generators: {replies}")
         say(f"counters over the window: "
             f"{ {k: ends[1][0][k] - ends[0][0][k] for k in after} }")
-        served.fail(built == 0,
-                    f"{built} programs built inside the window")
-        served.fail(engine.index.sub_version == version
-                    and not engine._stale(),
-                    "the table changed during the window")
+        say(f"inside the window: {built} programs built, the table's "
+            f"version moved {moved} times")
+        if not churn:
+            served.fail(built == 0,
+                        f"{built} programs built inside the window")
+            served.fail(engine.index.sub_version == version
+                        and not engine._stale(),
+                        "the table changed during the window")
         for k in NEVER:
             served.fail(after[k] == 0, f"{k} = {after[k]} over the run")
         stats = [d.memory_stats() or {} for d in jax.devices()]
         device["memory_peak_bytes"] = max(
             (s.get("peak_bytes_in_use") or 0) for s in stats)
-        joined = check.join(dumps, served.groups, t0, t1)
+        log = None
+        if churn:
+            log = {"log": [r for d in dumps for r in d["churn"]],
+                   "drain_end_ns": max(d["drain_end_ns"] for d in dumps)}
+        c0 = time.monotonic()
+        joined = check.join(dumps, served.groups, t0, t1, log)
+        check_s = time.monotonic() - c0
         failures += joined["failures"]
         say(f"checked {joined['messages']} messages, "
             f"{joined['deliveries']} right deliveries "
             f"({joined['in_window']} inside the window), attempted "
             f"{joined['attempted']}, failed {joined['failed']}; latency "
-            f"samples {len(joined['latencies_ns'])}")
+            f"samples {len(joined['latencies_ns'])}; {check_s:.2f} s"
+            + (f"; churn deliveries due {joined['churn']}" if churn else ""))
         served.fail(joined["deliveries"] > 0, "nothing was delivered")
         result.update(attempted=joined["attempted"], failed=joined["failed"])
         run = {"seconds": args.seconds, "joined": joined,
+               "window_programs": built, "table_versions": moved,
+               "check_s": check_s,
                "window_opens_s": t0 / 1e9 - T_START,
                "counters": (ends[0][0], ends[1][0]),
                "boot_seconds": dict(
