@@ -1079,8 +1079,9 @@ def _native_fused(tables):
 def _native_hash_probe(tables):
     """NativeProbe over the '#'-groups in depth->= mode (the C twin of
     host_hash_rows), or None. Cached per compiled-table snapshot. Only
-    the device-free path runs it — the device still owns '#'-matching
-    for batched dispatches."""
+    the device-free paths run it (bound into _native_host, and the
+    sharded engine's) — the device still owns '#'-matching for batched
+    dispatches."""
     probe = tables.__dict__.get("_native_hash_probe", False)
     if probe is not False:
         return probe
@@ -1279,6 +1280,52 @@ def _native_decode(tables):
     return nd
 
 
+# host_batch's result forms (native/maxmq_decode.cpp)
+_FORM_SET, _FORM_INTENTS, _FORM_PAIRS = 0, 1, 2
+# its three parts, as a batch record names them (ADR 015)
+_HOST_PARTS = ("match_prep", "match_probe", "match_decode")
+
+
+def _part_marker(rec):
+    """What host_batch calls as each of its parts begins while a batch
+    record is active: the record's phases, with their profiler
+    annotations, bounded where the parts are."""
+    def begin(k: int) -> None:
+        if k:
+            rec.end()
+        rec.begin(_HOST_PARTS[k])
+    return begin
+
+
+def _native_host(tables):
+    """(maxmq_decode.host_batch, table capsule, probe capsule) for the
+    one-call device-free match, or None. Binds the snapshot's native
+    vocab, exact/'+' probe and '#' probe to the decode table once per
+    compiled snapshot (cached); None when either native library is
+    missing, and the numpy path answers."""
+    nh = tables.__dict__.get("_native_host", False)
+    if nh is not False:
+        return nh
+    nh = None
+    try:
+        nd = _native_decode(tables)
+        fused = _native_fused(tables)
+        hp = _native_hash_probe(tables)
+        if (nd is not None and fused is not None
+                and (hp is not None or tables.host_hash is None)):
+            from ..native import bind_host_probe
+            mod, cap = nd
+            dtype, pad = _compact_dtype(tables)
+            hcap = bind_host_probe(mod, *fused, hp,
+                                   max(tables.probe_depth, 1),
+                                   np.dtype(dtype).itemsize, int(pad))
+            nh = (mod.host_batch, cap, hcap)
+    except Exception:
+        nh = None
+    tables.__dict__["_native_host"] = nh
+    return nh
+
+
 def _pairs_with_host(batch: int, ti_dev, rw_dev, hostrows, fall, tables):
     """Concatenate device pairs with the host-probe hits and drop
     fallback topics / out-of-table row ids (group-padded layouts emit
@@ -1357,11 +1404,12 @@ def _union_pairs(out, ti, rw, tables) -> None:
 
 
 def _union_pairs_removed(out, ti, rw, tables, removed) -> None:
-    """Union loop for the overlay case: (client, filter) pairs the host
-    overlay has removed are filtered out row by row."""
+    """Union loop for the overlay case over verified (topic, row) id
+    lists: (client, filter) pairs the host overlay has removed are
+    filtered out row by row."""
     entries = tables.entries
     row_entries = tables.row_entries
-    for t, r in zip(ti.tolist(), rw.tolist()):
+    for t, r in zip(ti, rw):
         result = out[t]
         for b in row_entries[r]:
             entry = entries[b]
@@ -1439,8 +1487,8 @@ class OverlayedEngine:
     ``SigEngine`` alone has more, and its callers probe for it: the
     fixed-slot surface (``subscribers_fixed_batch``, and the
     ``dispatch_fixed`` / ``collect_fixed`` split with the ADR-008 router
-    ``_routes_to_trie`` that pipelining needs), ``trie_routed`` and
-    ``kernel_plan``. A mesh engine's ``subscribers_batch`` is its
+    ``_routes_to_trie`` that pipelining needs), ``trie_routed``,
+    ``host_fused_batches`` and ``kernel_plan``. A mesh engine's ``subscribers_batch`` is its
     fixed-slot path.
 
     Subclasses provide ``index``, ``_state``, a ``_refresh_lock`` and
@@ -1706,6 +1754,9 @@ class SigEngine(OverlayedEngine):
         self.fallbacks = 0
         self.matches = 0
         self.host_matches = 0     # topics served by the device-free path
+        # device-free batches answered by the one native call
+        # (maxmq_decode.host_batch)
+        self.host_fused_batches = 0
         # rows-count hint for the stream prefetch (see dispatch_fixed)
         self._stream_rows_hint = _STREAM_CHUNK
         # host seconds of the newest table compile (compile_sig, then
@@ -2192,49 +2243,76 @@ class SigEngine(OverlayedEngine):
 
     def subscribers_host_batch(self, topics: list[str]
                                ) -> list[SubscriberSet]:
-        """Device-free full match: fused tokenize + exact/'+' probes,
-        the '#'-group host probe (host_hash_rows), then the same batch
-        verify + union decode — no dispatch, no device round trip.
+        """Device-free full match: tokenize + exact/'+' probes, the
+        '#'-group host probe, then the same batch verify + union decode
+        — no dispatch, no device round trip.
 
         Together the three probes cover every compiled group, so the
         result is exactly subscribers_fixed_batch's (same caching, same
         immutable-result contract) at a per-topic cost of a handful of
-        hashed binary searches — the batcher's low-occupancy bypass
-        serves from here instead of walking the CPU trie (~10x cheaper
-        at 100K subs). Overflow topics and router/declined corpora fall
-        back to the trie exactly like the device path."""
+        hashed look-ups — the batcher's low-occupancy bypass serves
+        from here instead of walking the CPU trie. With the native
+        libraries built the whole batch is ONE call
+        (``maxmq_decode.host_batch``): a batch of one pays no per-step
+        glue. Overflow topics and router/declined corpora fall back to
+        the trie exactly like the device path."""
         cpu = self._trie_batch(topics)
         if cpu is not None:
             return cpu
         tables = self._state[0]
-        batch = len(topics)
+        overlay = self.overlay_for(tables.version)
+        if overlay == "resync":
+            return self._resync_batch(topics)
+        nh = _native_host(tables)
+        if nh is None:
+            return self._host_batch_numpy(topics, tables)
+        host_batch, cap, hcap = nh
         rec = active_batch()            # ADR 015, as in dispatch_fixed
+        form = (_FORM_PAIRS if overlay is not None
+                else _FORM_INTENTS if self.emit_intents else _FORM_SET)
+        out, fall = host_batch(cap, hcap, topics, form,
+                               None if rec is None else _part_marker(rec))
+        batch = len(topics)
+        self.host_fused_batches += 1
+        self.matches += batch
+        # overflow topics are served by the trie fallback pass and
+        # counted under fallbacks — not host matches
+        self.host_matches += batch - len(fall)
+        if overlay is not None:
+            # an overlay window unions in the set form, dropping the
+            # subscriptions the overlay removed (merge_delta adds its)
+            ti, rw = out
+            out = [SubscriberSet() for _ in range(batch)]
+            _union_pairs_removed(out, ti, rw, tables, overlay.removed)
+        if rec is not None:
+            rec.end()
+        if fall or overlay is not None:
+            out = self._overlay_fallback_pass(topics, out, fall, overlay)
+        return out
+
+    def _host_batch_numpy(self, topics: list[str], tables
+                          ) -> list[SubscriberSet]:
+        """subscribers_host_batch where the native libraries are not
+        built: the numpy probes and the python decode."""
+        batch = len(topics)
+        rec = active_batch()
         if rec is not None:
             rec.begin("match_prep")
         toks, lens_enc, hostrows = prepare_batch(tables, topics)
         lengths = np.abs(lens_enc.astype(np.int32))
         fall = lengths >= 127
-        # overflow topics are served by the trie fallback pass and
-        # counted under fallbacks — not host matches
         self.host_matches += batch - int(fall.sum())
         if rec is not None:
             rec.end()
             rec.begin("match_probe")
         # the '#' hits ride _pairs_with_host's device-pair slot
-        # (hostrows may be the fused path's CSR, which _scatter_hits
-        # cannot append into). The C probe keeps the per-call cost in
-        # the microseconds — small batches are the whole point here —
-        # with host_hash_rows as the numpy fallback.
-        hp = _native_hash_probe(tables)
-        if hp is not None:
-            ti_h, rw_h = hp.run(np.ascontiguousarray(toks), lens_enc)
-            rw_h = rw_h.astype(np.int64)
-        else:
-            hh = host_hash_rows(tables, toks, lengths, lens_enc < 0)
-            ti_h = np.repeat(np.arange(batch), [len(h) for h in hh])
-            rw_h = (np.concatenate([np.asarray(h) for h in hh])
-                    .astype(np.int64) if len(ti_h)
-                    else np.empty(0, dtype=np.int64))
+        # (hostrows may be the fused tokenizer's CSR, which
+        # _scatter_hits cannot append into)
+        hh = host_hash_rows(tables, toks, lengths, lens_enc < 0)
+        ti_h = np.repeat(np.arange(batch), [len(h) for h in hh])
+        rw_h = (np.concatenate([np.asarray(h) for h in hh])
+                .astype(np.int64) if len(ti_h)
+                else np.empty(0, dtype=np.int64))
         if rec is not None:
             rec.end()
             rec.begin("match_decode")
@@ -2339,7 +2417,8 @@ class SigEngine(OverlayedEngine):
         else:
             out = self._decode_python(tables, toks8, lens_enc, batch,
                                       ti, rw, removed)
-        return self._overlay_fallback_pass(topics, out, fall, overlay)
+        return self._overlay_fallback_pass(
+            topics, out, np.flatnonzero(fall).tolist(), overlay)
 
     def _decode_native(self, nd, tables, toks8, lens_enc, batch, ti, rw,
                        overlay):
@@ -2376,22 +2455,22 @@ class SigEngine(OverlayedEngine):
         if removed is None:
             _union_pairs(out, ti, rw, tables)
         else:
-            _union_pairs_removed(out, ti, rw, tables, removed)
+            _union_pairs_removed(out, ti.tolist(), rw.tolist(), tables,
+                                 removed)
         return out
 
     def _overlay_fallback_pass(self, topics, out, fall, overlay):
-        """Overlay/fallback post-pass; the overwhelmingly common case
-        (fresh tables, no overflow) returns the union output as-is."""
-        any_fall = bool(fall.any())
+        """Overlay/fallback post-pass (``fall``: the indices of the
+        topics the trie answers); the overwhelmingly common case (fresh
+        tables, no overflow) returns the union output as-is."""
         if overlay is not None:
-            fl = fall.tolist() if any_fall else None
+            skip = set(fall)              # fall slots get replaced
             for i, topic in enumerate(topics):
-                if fl is None or not fl[i]:   # fall slots get replaced
+                if i not in skip:
                     out[i] = self.merge_delta(topic, out[i], overlay)
-        if any_fall:
-            for i in np.nonzero(fall)[0].tolist():
-                self.fallbacks += 1
-                out[i] = self.index.subscribers(topics[i])
+        for i in fall:
+            self.fallbacks += 1
+            out[i] = self.index.subscribers(topics[i])
         return out
 
     def _resync_batch(self, topics: list[str]) -> list[SubscriberSet]:

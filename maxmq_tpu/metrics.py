@@ -1206,6 +1206,12 @@ def _register_matcher_metrics(registry: Registry, broker) -> None:
                 "Topics matched by the device-free host sig path "
                 "(bypass + single-topic surface, ADR 008)",
                 lambda: eng.host_matches)
+        if hasattr(eng, "host_fused_batches"):
+            registry.counter_func(
+                "maxmq_matcher_host_fused_batches_total",
+                "Device-free host batches answered by one native call "
+                "(tokenize, probes, verify and union together)",
+                lambda: eng.host_fused_batches)
         # the ADR-008 router and the ADR-010 kernel plan are SigEngine's:
         # a ShardedSigEngine has neither
         if hasattr(eng, "trie_routed"):

@@ -358,6 +358,23 @@ def tokenize_probe(vocab: "NativeVocab", probe: "NativeProbe",
         cap = int(total)
 
 
+def bind_host_probe(decode_mod, vocab: "NativeVocab", probe: "NativeProbe",
+                    hash_probe: "NativeProbe | None", window: int,
+                    tok_mode: int, pad: int):
+    """The decode extension's handle on one snapshot's host probes
+    (``maxmq_decode.host_new``): ``mq_tokenize_probe`` and
+    ``mq_probe_run`` by address, with the vocab, the exact/'+' probe and
+    the '#' probe (None: the table has no '#' group) they run on. The
+    handle holds the three objects, so they outlive its calls."""
+    lib = vocab._lib
+    return decode_mod.host_new(
+        ctypes.cast(lib.mq_tokenize_probe, ctypes.c_void_p).value,
+        ctypes.cast(lib.mq_probe_run, ctypes.c_void_p).value,
+        vocab._handle.value, probe._handle.value,
+        0 if hash_probe is None else hash_probe._handle.value,
+        window, tok_mode, pad, (vocab, probe, hash_probe))
+
+
 class MalformedFrame(ValueError):
     """The buffer contains an invalid fixed header (reserved type 0 or a
     variable-byte integer longer than 4 bytes, MQTT-1.5.5)."""

@@ -96,6 +96,11 @@ phases are copied onto the batch's sampled publishes as CHILD spans of
 ``match_hop``      result ready on the executor thread -> the loop
                    running its continuation
 
+On the bypass path the engine answers in one native call
+(``SigEngine.subscribers_host_batch``), which hands the record each part's
+start (``match_prep``, ``match_probe``, ``match_decode``) only while a
+record is active.
+
 Host work is also wrapped in ``jax.profiler.TraceAnnotation``
 (``maxmq.*``, :func:`host_span`), so a profiler capture holds it on the
 device trace's clock; ``tools/trace_gaps.py`` reads such a capture. The

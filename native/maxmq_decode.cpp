@@ -30,6 +30,11 @@
 //            [MQTT-4.8.2-4]; pre-built (group, filter) key tuples
 // Verification itself mirrors sig.py:verify_pairs (window compare,
 // depth rule, '$'-exclusion, valid bit) on the same arrays.
+//
+// The device-free path (sig.py:SigEngine.subscribers_host_batch) enters
+// here once a batch: host_batch tokenizes and probes through the ctypes
+// runtime's entry points (by address, on its handles) and then verifies
+// and unions in the same call, so no Python runs between the steps.
 
 #define PY_SSIZE_T_CLEAN
 #include <Python.h>
@@ -46,6 +51,7 @@
 #include <cstddef>
 #include <cstdint>
 #include <cstring>
+#include <string>
 #include <unordered_map>
 
 // write-intent prefetch (the union read-modify-writes its slot);
@@ -2197,74 +2203,22 @@ PyObject *empty_intents_for(DecodeTable *t, PyObject *cap) {
   return Py_NewRef(t->empty_intents);
 }
 
-// decode_batch(table, toks, mode, pad, lens_enc, B, ti, rw)
-//   -> list[SubscriberSet] of length B (every slot populated)
-//
-// toks: [B, Wt] tokens in the compact dtype (mode 1/2/4 = u8/u16/i32),
-// pad: that dtype's pad value. ti/rw: int64 UNVERIFIED candidate pair
-// arrays (fallback topics and out-of-table rows already dropped by
-// _candidate_pairs). Unverified pairs are discarded; verified rows'
-// action streams are applied.
-PyObject *decode_batch_impl(PyObject *args, const bool intents) {
-  PyObject *cap, *toks_o, *lens_o, *ti_o, *rw_o;
-  int mode;
-  long pad_l;
-  Py_ssize_t B;
-  if (!PyArg_ParseTuple(args, "OOilOnOO", &cap, &toks_o, &mode, &pad_l,
-                        &lens_o, &B, &ti_o, &rw_o))
-    return nullptr;
-  auto *t = static_cast<DecodeTable *>(
-      PyCapsule_GetPointer(cap, "maxmq_decode.table"));
-  if (!t) return nullptr;
-
-  Py_buffer bufs[4];
-  PyObject *objs[4] = {toks_o, lens_o, ti_o, rw_o};
-  int n_buf = 0;
-  struct Rel {
-    Py_buffer *b;
-    int *n;
-    ~Rel() {
-      for (int i = 0; i < *n; i++) PyBuffer_Release(&b[i]);
-    }
-  } rel{bufs, &n_buf};
-  while (n_buf < 4) {
-    if (PyObject_GetBuffer(objs[n_buf], &bufs[n_buf], PyBUF_SIMPLE) < 0)
-      return nullptr;
-    n_buf++;
-  }
-  const Py_buffer &toks = bufs[0], &lens = bufs[1];
-  const Py_buffer &ti_b = bufs[2], &rw_b = bufs[3];
-
-  const Py_ssize_t N = ti_b.len / 8;
-  const Py_ssize_t Wt = B ? toks.len / (B * mode) : 0;
+// pass 1 of a batch decode — verify (pure C): each candidate (topic,
+// row) pair's token window against the row's verify array; survivors
+// are appended to (v_tp, v_rw). toks: [B, Wt] tokens in the compact
+// dtype (mode 1/2/4 = u8/u16/i32), pad: that dtype's pad value. Topics
+// deeper than the tokenizer keeps (|len| 127) are the CPU trie's in
+// every path, so none of their pairs survive.
+template <typename Row>
+void verify_pairs(const DecodeTable *t, const void *toks, int mode,
+                  int32_t pad, const int8_t *lens_enc, Py_ssize_t B,
+                  Py_ssize_t Wt, const int64_t *ti, const Row *rw,
+                  Py_ssize_t N, std::vector<int64_t> &v_tp,
+                  std::vector<int32_t> &v_rw) {
   const Py_ssize_t W = t->W < Wt ? t->W : Wt;
-  if ((Py_ssize_t)rw_b.len / 8 < N || (Py_ssize_t)lens.len < B) {
-    PyErr_SetString(PyExc_ValueError, "batch array lengths disagree");
-    return nullptr;
-  }
-  const auto *ti = static_cast<const int64_t *>(ti_b.buf);
-  const auto *rw = static_cast<const int64_t *>(rw_b.buf);
-  const auto *lens_enc = static_cast<const int8_t *>(lens.buf);
   const auto *tok = static_cast<const int32_t *>(t->tok.buf);
   const auto *md = static_cast<const int32_t *>(t->min_depth.buf);
   const auto *fl = static_cast<const uint8_t *>(t->flags.buf);
-  const int32_t pad = static_cast<int32_t>(pad_l);
-
-  PyObject *out = PyList_New(B);
-  if (!out) return nullptr;
-  for (Py_ssize_t i = 0; i < B; i++)
-    PyList_SET_ITEM(out, i, Py_NewRef(Py_None));
-  auto bail = [&]() -> PyObject * {
-    Py_DECREF(out);
-    return nullptr;
-  };
-
-  // pass 1 — verify (pure C): token windows against the row's verify
-  // array; survivors keep their (topic, row) pair
-  std::vector<int64_t> v_tp;
-  std::vector<int32_t> v_rw;
-  v_tp.reserve(N);
-  v_rw.reserve(N);
   for (Py_ssize_t k = 0; k < N; k++) {
     const int64_t tp = ti[k], r = rw[k];
     if (tp < 0 || tp >= B || r < 0 || r >= t->R) continue;
@@ -2272,6 +2226,7 @@ PyObject *decode_batch_impl(PyObject *args, const bool intents) {
     if (!(f & FLAG_VALID)) continue;
     const int8_t le = lens_enc[tp];
     const int32_t ln = le < 0 ? -static_cast<int32_t>(le) : le;
+    if (ln >= 127) continue;
     const int32_t m = md[r];
     if ((f & FLAG_EXACT) ? (ln != m) : (ln < m)) continue;
     if (le < 0 && (f & FLAG_WILDF)) continue;
@@ -2280,7 +2235,7 @@ PyObject *decode_batch_impl(PyObject *args, const bool intents) {
     for (Py_ssize_t i = 0; i < W; i++) {
       const int32_t rv = rt[i];
       if (rv == VER_ANY || rv == VER_PLUS) continue;
-      if (rv != topic_tok(toks.buf, mode, pad, tp, Wt, i)) {
+      if (rv != topic_tok(toks, mode, pad, tp, Wt, i)) {
         ok = false;
         break;
       }
@@ -2295,12 +2250,27 @@ PyObject *decode_batch_impl(PyObject *args, const bool intents) {
     v_tp.push_back(tp);
     v_rw.push_back(static_cast<int32_t>(r));
   }
+}
 
-  // pass 2 — counting-sort the survivors by topic (pairs may interleave
-  // device and host-probe streams), then resolve each topic's row SET
-  // through the table's result cache: topics overwhelmingly repeat a
-  // small number of row sets (shallow-'#' buckets), so the expensive
-  // union runs once per distinct set, not once per topic.
+// pass 2 — counting-sort the verified pairs by topic (pairs may
+// interleave device and host-probe streams), then resolve each topic's
+// row SET through the table's result cache: topics overwhelmingly
+// repeat a small number of row sets (shallow-'#' buckets), so the
+// expensive union runs once per distinct set, not once per topic.
+// Returns list[SubscriberSet | DeliveryIntents] of length B, every
+// slot populated.
+PyObject *union_verified(DecodeTable *t, PyObject *cap, Py_ssize_t B,
+                         const std::vector<int64_t> &v_tp,
+                         const std::vector<int32_t> &v_rw,
+                         const bool intents) {
+  PyObject *out = PyList_New(B);
+  if (!out) return nullptr;
+  for (Py_ssize_t i = 0; i < B; i++)
+    PyList_SET_ITEM(out, i, Py_NewRef(Py_None));
+  auto bail = [&]() -> PyObject * {
+    Py_DECREF(out);
+    return nullptr;
+  };
   const Py_ssize_t M = (Py_ssize_t)v_tp.size();
   std::vector<int64_t> t_cnt(B + 1, 0);
   for (Py_ssize_t k = 0; k < M; k++) t_cnt[v_tp[k] + 1]++;
@@ -2344,6 +2314,59 @@ PyObject *decode_batch_impl(PyObject *args, const bool intents) {
     PyList_SetItem(out, i, n);
   }
   return out;
+}
+
+// decode_batch(table, toks, mode, pad, lens_enc, B, ti, rw)
+//   -> list[SubscriberSet] of length B (every slot populated)
+//
+// ti/rw: int64 UNVERIFIED candidate pair arrays (fallback topics and
+// out-of-table rows already dropped by _candidate_pairs). Unverified
+// pairs are discarded; verified rows' action streams are applied.
+PyObject *decode_batch_impl(PyObject *args, const bool intents) {
+  PyObject *cap, *toks_o, *lens_o, *ti_o, *rw_o;
+  int mode;
+  long pad_l;
+  Py_ssize_t B;
+  if (!PyArg_ParseTuple(args, "OOilOnOO", &cap, &toks_o, &mode, &pad_l,
+                        &lens_o, &B, &ti_o, &rw_o))
+    return nullptr;
+  auto *t = static_cast<DecodeTable *>(
+      PyCapsule_GetPointer(cap, "maxmq_decode.table"));
+  if (!t) return nullptr;
+
+  Py_buffer bufs[4];
+  PyObject *objs[4] = {toks_o, lens_o, ti_o, rw_o};
+  int n_buf = 0;
+  struct Rel {
+    Py_buffer *b;
+    int *n;
+    ~Rel() {
+      for (int i = 0; i < *n; i++) PyBuffer_Release(&b[i]);
+    }
+  } rel{bufs, &n_buf};
+  while (n_buf < 4) {
+    if (PyObject_GetBuffer(objs[n_buf], &bufs[n_buf], PyBUF_SIMPLE) < 0)
+      return nullptr;
+    n_buf++;
+  }
+  const Py_buffer &toks = bufs[0], &lens = bufs[1];
+  const Py_buffer &ti_b = bufs[2], &rw_b = bufs[3];
+
+  const Py_ssize_t N = ti_b.len / 8;
+  const Py_ssize_t Wt = B ? toks.len / (B * mode) : 0;
+  if ((Py_ssize_t)rw_b.len / 8 < N || (Py_ssize_t)lens.len < B) {
+    PyErr_SetString(PyExc_ValueError, "batch array lengths disagree");
+    return nullptr;
+  }
+  std::vector<int64_t> v_tp;
+  std::vector<int32_t> v_rw;
+  v_tp.reserve(N);
+  v_rw.reserve(N);
+  verify_pairs(t, toks.buf, mode, static_cast<int32_t>(pad_l),
+               static_cast<const int8_t *>(lens.buf), B, Wt,
+               static_cast<const int64_t *>(ti_b.buf),
+               static_cast<const int64_t *>(rw_b.buf), N, v_tp, v_rw);
+  return union_verified(t, cap, B, v_tp, v_rw, intents);
 }
 
 PyObject *decode_batch(PyObject *, PyObject *args) {
@@ -2405,6 +2428,236 @@ PyObject *prewarm_bases(PyObject *, PyObject *args) {
 
 PyObject *decode_batch_intents(PyObject *, PyObject *args) {
   return decode_batch_impl(args, true);
+}
+
+// ----------------------------------------------------------------- //
+//  Device-free host match: one call per batch                       //
+// ----------------------------------------------------------------- //
+
+// The host probes live in the ctypes runtime (native/maxmq_native.cpp);
+// this extension calls its C entry points through the addresses
+// Python resolved once per compiled snapshot, on the handles that
+// runtime built for the snapshot.
+using TokenizeProbeFn = int64_t (*)(void *, void *, const char *,
+                                    int64_t, int64_t, int64_t, int32_t,
+                                    void *, int8_t *, int64_t *,
+                                    int32_t *, int64_t);
+using ProbeRunFn = int64_t (*)(void *, const void *, int32_t,
+                               const int8_t *, int64_t, int64_t,
+                               int64_t *, int32_t *, int64_t, int32_t);
+
+struct HostProbe {
+  TokenizeProbeFn tokenize_probe;  // mq_tokenize_probe
+  ProbeRunFn probe_run;            // mq_probe_run
+  void *vocab;                     // the snapshot's Vocab
+  void *probe;                     // exact + '+' groups
+  void *hash;                      // '#' groups (depth >=); null if none
+  int64_t window;                  // token window (the verify width)
+  int32_t mode, pad;               // compact token dtype: 1/2/4, pad
+  PyObject *owners;                // the handles' Python owners
+};
+
+void host_destroy(PyObject *capsule) {
+  auto *h = static_cast<HostProbe *>(
+      PyCapsule_GetPointer(capsule, "maxmq_decode.host"));
+  if (!h) return;
+  Py_XDECREF(h->owners);
+  delete h;
+}
+
+// host_new(tokenize_probe, probe_run, vocab, probe, hash, window, mode,
+//          pad, owners) -> capsule. The first five are addresses (hash
+// may be 0); owners is held for the capsule's life, so the handles it
+// owns outlive every call.
+PyObject *host_new(PyObject *, PyObject *args) {
+  PyObject *fn_tp, *fn_pr, *vocab, *probe, *hash, *owners;
+  Py_ssize_t window;
+  int mode, pad;
+  if (!PyArg_ParseTuple(args, "OOOOOniiO", &fn_tp, &fn_pr, &vocab, &probe,
+                        &hash, &window, &mode, &pad, &owners))
+    return nullptr;
+  void *ptr[5];
+  PyObject *objs[5] = {fn_tp, fn_pr, vocab, probe, hash};
+  for (int i = 0; i < 5; i++) {
+    ptr[i] = PyLong_AsVoidPtr(objs[i]);
+    if (!ptr[i] && PyErr_Occurred()) return nullptr;
+  }
+  if (!ptr[0] || !ptr[1] || !ptr[2] || !ptr[3] || window < 1 ||
+      (mode != 1 && mode != 2 && mode != 4)) {
+    PyErr_SetString(PyExc_ValueError, "host probe handles incomplete");
+    return nullptr;
+  }
+  auto *h = new HostProbe{reinterpret_cast<TokenizeProbeFn>(ptr[0]),
+                          reinterpret_cast<ProbeRunFn>(ptr[1]),
+                          ptr[2], ptr[3], ptr[4], window, mode, pad,
+                          Py_NewRef(owners)};
+  PyObject *cap = PyCapsule_New(h, "maxmq_decode.host", host_destroy);
+  if (!cap) {
+    Py_DECREF(h->owners);
+    delete h;
+  }
+  return cap;
+}
+
+// a probe entry point's (topic, row) hits, grown until they fit: the
+// entry points return the total and write at most `cap` pairs
+template <typename Run>
+int64_t probe_hits(std::vector<int64_t> &ti, std::vector<int32_t> &rw,
+                   int64_t cap, Run &&run) {
+  for (;;) {
+    ti.resize(cap);
+    rw.resize(cap);
+    const int64_t total = run(ti.data(), rw.data(), cap);
+    if (total <= cap) return total;
+    cap = total;
+  }
+}
+
+constexpr long HOST_FORM_SET = 0;      // list[SubscriberSet]
+constexpr long HOST_FORM_INTENTS = 1;  // list[DeliveryIntents]
+constexpr long HOST_FORM_PAIRS = 2;    // (topic ids, row ids), verified
+
+// host_batch(table, host, topics, form, mark) -> (results, fallbacks)
+//
+// The whole device-free match of one batch in one call: tokenize +
+// exact/'+' probe, the '#'-group probe, then pair assembly + verify +
+// the entry union of decode_batch(_intents). `fallbacks` is the tuple
+// of topic indices too deep for the tokenizer (answered by the CPU
+// trie; their slots hold empty results). The PAIRS form returns the
+// verified pairs as two lists instead of unioning them (an overlay
+// window's union filters removed subscriptions in Python). `mark` is
+// None, or a callable that is called with 0, 1 and 2 as each of the
+// three parts begins (the caller's batch record times them); the
+// caller ends the last.
+PyObject *host_batch(PyObject *, PyObject *const *args, Py_ssize_t nargs) {
+  if (nargs != 5) {
+    PyErr_SetString(PyExc_TypeError, "host_batch takes 5 arguments");
+    return nullptr;
+  }
+  PyObject *cap = args[0];
+  auto *t = static_cast<DecodeTable *>(
+      PyCapsule_GetPointer(cap, "maxmq_decode.table"));
+  if (!t) return nullptr;
+  auto *h = static_cast<HostProbe *>(
+      PyCapsule_GetPointer(args[1], "maxmq_decode.host"));
+  if (!h) return nullptr;
+  PyObject *topics = args[2];
+  if (!PyList_Check(topics)) {
+    PyErr_SetString(PyExc_TypeError, "topics must be a list");
+    return nullptr;
+  }
+  const long form = PyLong_AsLong(args[3]);
+  if (form == -1 && PyErr_Occurred()) return nullptr;
+  PyObject *mark = args[4] == Py_None ? nullptr : args[4];
+  auto begin_part = [mark](long k) -> bool {
+    if (!mark) return true;
+    PyObject *n = PyLong_FromLong(k);
+    if (!n) return false;
+    PyObject *r = PyObject_CallOneArg(mark, n);
+    Py_DECREF(n);
+    Py_XDECREF(r);
+    return r != nullptr;
+  };
+  if (!begin_part(0)) return nullptr;
+
+  // the topics' utf-8 bytes, '\0'-joined as the tokenizer splits them
+  // (U+0000 cannot appear in a topic name [MQTT-1.5.4-2])
+  const Py_ssize_t B = PyList_GET_SIZE(topics);
+  std::string buf;
+  for (Py_ssize_t i = 0; i < B; i++) {
+    PyObject *s = PyList_GET_ITEM(topics, i);
+    if (!PyUnicode_Check(s)) {
+      PyErr_SetString(PyExc_TypeError, "topics must be str");
+      return nullptr;
+    }
+    Py_ssize_t n;
+    const char *u = PyUnicode_AsUTF8AndSize(s, &n);
+    if (!u) return nullptr;
+    if (i) buf.push_back('\0');
+    buf.append(u, n);
+  }
+
+  const int64_t W = h->window;
+  std::vector<uint8_t> toks(static_cast<size_t>(B * W * h->mode));
+  std::vector<int8_t> lens(B);
+  std::vector<int64_t> ti, ti_h;
+  std::vector<int32_t> rw, rw_h;
+  const int64_t first_cap = 4 * B + 16;
+  const int64_t n_hits =
+      B ? probe_hits(ti, rw, first_cap,
+                     [&](int64_t *o_ti, int32_t *o_rw, int64_t c) {
+                       return h->tokenize_probe(
+                           h->vocab, h->probe, buf.data(),
+                           static_cast<int64_t>(buf.size()), B, W,
+                           h->mode, toks.data(), lens.data(), o_ti, o_rw,
+                           c);
+                     })
+        : 0;
+  if (!begin_part(1)) return nullptr;
+  const int64_t n_hash =
+      B && h->hash
+          ? probe_hits(ti_h, rw_h, first_cap,
+                       [&](int64_t *o_ti, int32_t *o_rw, int64_t c) {
+                         return h->probe_run(h->hash, toks.data(),
+                                             h->mode, lens.data(), B, W,
+                                             o_ti, o_rw, c, 0);
+                       })
+          : 0;
+  if (!begin_part(2)) return nullptr;
+
+  Py_ssize_t n_fall = 0;
+  for (Py_ssize_t i = 0; i < B; i++)
+    if (lens[i] == 127 || lens[i] == -127) n_fall++;
+  PyObject *fall = PyTuple_New(n_fall);
+  if (!fall) return nullptr;
+  for (Py_ssize_t i = 0, k = 0; k < n_fall; i++) {
+    if (lens[i] != 127 && lens[i] != -127) continue;
+    PyObject *v = PyLong_FromSsize_t(i);
+    if (!v) {
+      Py_DECREF(fall);
+      return nullptr;
+    }
+    PyTuple_SET_ITEM(fall, k++, v);
+  }
+
+  std::vector<int64_t> v_tp;
+  std::vector<int32_t> v_rw;
+  v_tp.reserve(n_hits + n_hash);
+  v_rw.reserve(n_hits + n_hash);
+  verify_pairs(t, toks.data(), h->mode, h->pad, lens.data(), B, W,
+               ti.data(), rw.data(), n_hits, v_tp, v_rw);
+  verify_pairs(t, toks.data(), h->mode, h->pad, lens.data(), B, W,
+               ti_h.data(), rw_h.data(), n_hash, v_tp, v_rw);
+  PyObject *out;
+  if (form == HOST_FORM_PAIRS) {
+    const Py_ssize_t M = static_cast<Py_ssize_t>(v_tp.size());
+    PyObject *tl = PyList_New(M), *rl = PyList_New(M);
+    out = tl && rl ? PyTuple_Pack(2, tl, rl) : nullptr;
+    for (Py_ssize_t k = 0; out && k < M; k++) {
+      PyObject *a = PyLong_FromLongLong(v_tp[k]);
+      PyObject *b = PyLong_FromLong(v_rw[k]);
+      if (!a || !b) {
+        Py_XDECREF(a);
+        Py_XDECREF(b);
+        Py_CLEAR(out);
+        break;
+      }
+      PyList_SET_ITEM(tl, k, a);
+      PyList_SET_ITEM(rl, k, b);
+    }
+    Py_XDECREF(tl);
+    Py_XDECREF(rl);
+  } else {
+    out = union_verified(t, cap, B, v_tp, v_rw, form == HOST_FORM_INTENTS);
+  }
+  if (!out) {
+    Py_DECREF(fall);
+    return nullptr;
+  }
+  PyObject *ret = PyTuple_Pack(2, out, fall);
+  Py_DECREF(out);
+  Py_DECREF(fall);
+  return ret;
 }
 
 PyObject *set_slot_map_cap(PyObject *, PyObject *arg) {
@@ -2508,6 +2761,13 @@ PyMethodDef methods[] = {
     {"decode_batch_intents", decode_batch_intents, METH_VARARGS,
      "Verify candidate pairs and union their subscriber entries into "
      "per-topic DeliveryIntents (the fan-out hot-path form)."},
+    {"host_new", host_new, METH_VARARGS,
+     "Bind a compiled snapshot's host probe handles (the ctypes "
+     "runtime's entry points and tables); returns a capsule."},
+    {"host_batch", reinterpret_cast<PyCFunction>(host_batch),
+     METH_FASTCALL,
+     "The device-free match of one batch: tokenize, the exact/'+' and "
+     "'#' probes, verify and union, in one call."},
     {"table_release", table_release, METH_O,
      "Drop a snapshot table's caches, breaking the intents->capsule "
      "reference cycle (call when the snapshot is dropped)."},

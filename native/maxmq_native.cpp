@@ -423,12 +423,14 @@ int64_t mq_probe_run(void* h, const void* toks, int32_t tok_mode,
                      int64_t* out_ti, int32_t* out_row, int64_t cap,
                      int32_t n_threads) {
   const auto* set = static_cast<ProbeSet*>(h);
+  // a small batch never asks how many cores there are (a system call
+  // per probe, on the publish path's batch of one)
+  if (n < 4096) n_threads = 1;
   if (n_threads <= 0) {
     n_threads = static_cast<int32_t>(std::thread::hardware_concurrency());
     if (n_threads <= 0) n_threads = 1;
     if (n_threads > 8) n_threads = 8;
   }
-  if (n < 4096) n_threads = 1;
 
   std::vector<std::vector<int64_t>> ti(n_threads);
   std::vector<std::vector<int32_t>> rw(n_threads);
@@ -593,11 +595,12 @@ int64_t mq_tokenize_probe(void* v, void* h, const char* buf, int64_t buf_len,
     if (buf[e] == '\0') tstarts[++idx] = e + 1;
   tstarts[n_topics] = buf_len + 1;
 
-  int32_t n_threads =
-      static_cast<int32_t>(std::thread::hardware_concurrency());
-  if (n_threads <= 0) n_threads = 1;
-  if (n_threads > 8) n_threads = 8;
-  if (n_topics < 16384) n_threads = 1;
+  int32_t n_threads = 1;       // as mq_probe_run: no core count asked
+  if (n_topics >= 16384) {      // for a small batch
+    n_threads = static_cast<int32_t>(std::thread::hardware_concurrency());
+    if (n_threads <= 0) n_threads = 1;
+    if (n_threads > 8) n_threads = 8;
+  }
 
   if (n_threads == 1) {
     // publish hot path: write hits straight into the caller's buffers

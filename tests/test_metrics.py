@@ -209,6 +209,7 @@ def test_matcher_metrics_series_render():
     mb.bypasses = 3
     mb._device_rtt = 0.012   # seed the EWMA the property exposes
     eng.trie_routed = 5
+    eng.host_matches, eng.host_fused_batches = 7, 4
     reg = Registry()
     register_broker_metrics(reg, broker)
     text = reg.expose()
@@ -216,6 +217,8 @@ def test_matcher_metrics_series_render():
     assert "maxmq_matcher_bypassed_topics_total 3" in text
     assert "maxmq_matcher_device_rtt_seconds 0.012" in text
     assert "maxmq_matcher_trie_routed_total 5" in text
+    assert "maxmq_matcher_host_matches_total 7" in text
+    assert "maxmq_matcher_host_fused_batches_total 4" in text
 
 
 def test_round_trip_gauges_are_named_apart_and_phases_exposed():

@@ -886,10 +886,40 @@ def test_table_release_breaks_cycle_on_rotation():
 # C decode, no device dispatch at all.
 
 
-@pytest.mark.parametrize("seed", [31, 32, 33])
-def test_host_batch_parity_randomized(seed):
-    """The host-only path (host_hash_rows completing the probe set)
-    matches the trie exactly, in both result forms."""
+def _native_host_built() -> bool:
+    from maxmq_tpu.native import available, decode_module
+    mod = decode_module()
+    return available() and mod is not None and hasattr(mod, "host_batch")
+
+
+# a topic deeper than the tokenizer keeps: the trie answers it
+_DEEP_TOPIC = "/".join(["t1"] * 130)
+# one client's overlapping filters (the tree's shape), folded by
+# merge_subscription, and the topics that reach them
+_FOLD_FILTERS = ("t1/#", "t1/+/t2", "t1/t3/t2", "+/t3/#")
+_HOST_EXTRAS = [_DEEP_TOPIC, "t1/t3/t2", "$t1/t3/t2", "t1/t3"]
+
+# (seed, batch size: None = the corpus's own topics, overlay window)
+_HOST_CASES = [
+    pytest.param(31, None, False, id="31"),
+    pytest.param(32, None, False, id="32"),
+    pytest.param(33, None, False, id="33"),
+    pytest.param(34, 0, False, id="batch0"),
+    pytest.param(35, 1, False, id="batch1"),
+    pytest.param(36, 2, False, id="batch2"),
+    pytest.param(37, 256, False, id="batch256"),
+    pytest.param(38, 512, False, id="batch512"),    # the bypass's cap
+    pytest.param(39, None, True, id="overlay"),
+]
+
+
+@pytest.mark.parametrize("seed, batch, overlay", _HOST_CASES)
+def test_host_batch_parity_randomized(seed, batch, overlay):
+    """The host-only path (one native call where the libraries are
+    built) matches the trie and the device path's set form exactly, in
+    both result forms: '$' topics, a topic too deep for the tokenizer
+    (a fallback), $share maps, one client's overlapping filters, and an
+    open overlay window (set form + merge_delta)."""
     rng = random.Random(seed)
     idx = TopicIndex()
     filters, topics = rand_corpus(rng, n_filters=150, n_clients=40)
@@ -900,15 +930,99 @@ def test_host_batch_parity_randomized(seed):
         idx.subscribe(f"c{i % 40}",
                       Subscription(filter=f, qos=rng.randint(0, 2),
                                    identifier=rng.randint(0, 5)))
+    for k, f in enumerate(_FOLD_FILTERS):
+        idx.subscribe("fold", Subscription(filter=f, qos=k % 3,
+                                           identifier=k + 1))
+    pool = topics + _HOST_EXTRAS
+    topics = (pool if batch is None else
+              (_HOST_EXTRAS + [rng.choice(pool) for _ in range(batch)])
+              [:batch])
+    fused = _native_host_built()
     for emit in (False, True):
-        eng = SigEngine(idx)
+        eng = SigEngine(idx, auto_refresh=not overlay)
         eng.emit_intents = emit
+        if overlay:
+            # newer than the compiled tables: an add, a replace, and
+            # removals, shared-group members among them
+            idx.subscribe("late", Subscription(filter="t1/+/t2", qos=1))
+            idx.subscribe("fold", Subscription(filter="t1/#", qos=2))
+            idx.unsubscribe("fold", "+/t3/#")
+            idx.subscribe("c7", Subscription(filter="$share/g1/t1/#"))
+            for f in filters[:20]:
+                idx.unsubscribe(f"c{filters.index(f) % 40}", f)
+            assert eng.overlay_for(eng.tables.version) not in (None,
+                                                               "resync")
         got = eng.subscribers_host_batch(topics)
-        for topic, result in zip(topics, got):
-            want = idx.subscribers(topic)
-            assert normalize(_as_set(result)) == normalize(want), \
-                (topic, emit)
-        assert eng.host_matches == len(topics)
+        assert eng.host_fused_batches == (1 if fused else 0)
+        assert len(got) == len(topics)
+        device = eng.subscribers_fixed_batch(topics) if topics else []
+        for topic, result, dev in zip(topics, got, device):
+            want = normalize(idx.subscribers(topic))
+            assert normalize(_as_set(result)) == want, (topic, emit)
+            assert normalize(_as_set(dev)) == want, (topic, emit)
+        deep = topics.count(_DEEP_TOPIC)
+        assert eng.host_matches == len(topics) - deep
+        assert eng.fallbacks == 2 * deep       # the host and the device
+        if overlay:
+            # put the index back for the other result form
+            idx.unsubscribe("late", "t1/+/t2")
+            idx.subscribe("fold", Subscription(filter="t1/#", qos=0,
+                                               identifier=1))
+            idx.subscribe("fold", Subscription(filter="+/t3/#", qos=0,
+                                               identifier=4))
+            idx.unsubscribe("c7", "$share/g1/t1/#")
+            for f in filters[:20]:
+                if valid_filter(f):
+                    idx.subscribe(f"c{filters.index(f) % 40}",
+                                  Subscription(filter=f))
+
+
+def test_host_batch_phases_and_the_numpy_path(monkeypatch):
+    """Under a batch record the one native call writes match_prep /
+    match_probe / match_decode, in order and inside match_host; with
+    the native libraries missing the numpy path answers the same, and
+    the fused counter does not move."""
+    if not _native_host_built():
+        pytest.skip("native libraries unavailable")
+    from maxmq_tpu import native
+    from maxmq_tpu.trace import BatchRecord, PipelineTracer
+
+    rng = random.Random(40)
+    idx = TopicIndex()
+    filters, topics = rand_corpus(rng, n_filters=150, n_clients=40)
+    from maxmq_tpu.matching.topics import valid_filter
+    for i, f in enumerate(filters):
+        if valid_filter(f):
+            idx.subscribe(f"c{i % 40}", Subscription(filter=f, qos=i % 3))
+    topics = topics + _HOST_EXTRAS
+    tracer = PipelineTracer(sample_n=1)
+    eng = SigEngine(idx)
+    rec = BatchRecord(tracer, 0, len(topics))
+    h0 = tracer.clock()
+    got = rec.run(eng.subscribers_host_batch, topics)
+    rec.phase("match_host", h0, tracer.clock())
+    assert eng.host_fused_batches == 1
+    ph = {name: (t0, t1) for name, t0, t1, _on in rec.phases}
+    assert set(ph) == {"match_host", "match_prep", "match_probe",
+                       "match_decode"}
+    (p0, p1), (q0, q1), (d0, d1) = (ph["match_prep"], ph["match_probe"],
+                                    ph["match_decode"])
+    assert h0 <= p0 <= p1 <= q0 <= q1 <= d0 <= d1 <= ph["match_host"][1]
+    # untraced: nothing is timed, nothing is written
+    assert eng.subscribers_host_batch(topics[:3]) is not None
+    assert eng.host_fused_batches == 2
+
+    monkeypatch.setattr(native, "_try_load", lambda: None)
+    monkeypatch.setattr(native, "decode_module", lambda build=True: None)
+    plain = SigEngine(idx)
+    rec = BatchRecord(tracer, 1, len(topics))
+    again = rec.run(plain.subscribers_host_batch, topics)
+    assert plain.host_fused_batches == 0
+    assert {name for name, *_ in rec.phases} == {
+        "match_prep", "match_probe", "match_decode"}
+    for topic, a, b in zip(topics, got, again):
+        assert normalize(_as_set(a)) == normalize(_as_set(b)) == \
+            normalize(idx.subscribers(topic)), topic
 
 
 def test_host_batch_never_touches_device(monkeypatch):
